@@ -56,6 +56,8 @@ def main() -> int:
     artifact = wrap_artifact(
         cmd=f"env {env} python bench.py", rc=int(rc), env=env, tail=tail,
         parsed=parsed, segments_incomplete=incomplete,
+        # the platform bench.py observed and printed, or none
+        backend=(parsed.get("device") or {}).get("platform"),
     )
     with open(out_path, "w") as f:
         json.dump(artifact, f, indent=1)

@@ -49,6 +49,7 @@ from tigerbeetle_tpu.benchmark import (
     _transfers_body,
     free_port,
     kill_process_group,
+    require_one_process_per_chip,
 )
 from tigerbeetle_tpu.constants import ConfigCluster
 from tigerbeetle_tpu.inspect import inspect_live, send_mark
@@ -228,6 +229,9 @@ def run_prodday(
                TB_PARENT_WATCHDOG="1")
     if jax_platform:
         env["TB_JAX_PLATFORM"] = jax_platform
+    require_one_process_per_chip(
+        "prodday", backend, replica_count, jax_platform
+    )
 
     paths = []
     for i in range(replica_count):
@@ -762,7 +766,9 @@ def main() -> int:
             tail="",
             parsed=parsed,
             segments_incomplete=segments_incomplete,
-            backend=args.backend,
+            # this harness pins its servers to the CPU (see run_prodday):
+            # the platform asked for by name is the one observed
+            backend="cpu",
         )
         with open(args.artifact, "w") as f:
             json.dump(artifact, f, indent=1, default=str)

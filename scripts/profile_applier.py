@@ -73,6 +73,9 @@ def main() -> int:
     from tigerbeetle_tpu.tracer import JsonTracer
     from tigerbeetle_tpu.types import Operation
 
+    from tigerbeetle_tpu.cli import announce_device
+
+    device = announce_device()  # names the device; refuses an unasked CPU
     metrics = Metrics()
     tracer = JsonTracer(metrics=metrics)
     led = DualLedger(12, 14, follower=True, warm_kernels=True)
@@ -128,6 +131,7 @@ def main() -> int:
     totals = device_leg_totals(snap)
     leg, share = dominant_leg(snap_before, totals)
     report = {
+        "device": device,
         "verified": report_ok.get("verified"),
         "device_subleg_totals_us": {k: round(v["total_us"], 1)
                                     for k, v in totals.items()},

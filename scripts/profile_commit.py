@@ -1,4 +1,5 @@
-"""Per-stage profiling of the fast-tier commit kernel on the real TPU.
+"""Per-stage profiling of the fast-tier commit kernel on the device JAX
+gives this process (printed first; an unasked CPU is refused).
 
 Explains the bench's bimodal batch latency (p25 ~1.7ms vs p50 ~7ms) by timing
 (a) back-to-back commits, (b) isolated sub-kernels: account-table lookup,
@@ -38,6 +39,9 @@ def timeit(fn, n=20, warmup=3):
 
 
 def main():
+    from tigerbeetle_tpu.cli import announce_device
+
+    announce_device()  # names the device; refuses an unasked CPU
     process = ConfigProcess(account_slots_log2=16, transfer_slots_log2=25)
     ledger = DeviceLedger(process=process, mode="auto")
     ledger.pad_to = BATCH_PAD

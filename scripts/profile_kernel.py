@@ -27,6 +27,9 @@ BATCH = 8190
 
 
 def main():
+    from tigerbeetle_tpu.cli import announce_device
+
+    announce_device()  # names the device; refuses an unasked CPU
     probe = jax.jit(lambda x: x + 1)
     xp = jnp.ones(16384, jnp.uint32)
 

@@ -42,6 +42,9 @@ def main():
     args = ap.parse_args()
 
     from bench import BATCH, N_ACCOUNTS, build_accounts, build_transfers
+    from tigerbeetle_tpu.cli import announce_device
+
+    announce_device()  # names the device; refuses an unasked CPU
     from tigerbeetle_tpu.constants import BATCH_PAD, TEST_CLUSTER, ConfigProcess
     from tigerbeetle_tpu.io.storage import MemoryStorage, ZoneLayout
     from tigerbeetle_tpu.lsm.grid import Grid

@@ -2,11 +2,11 @@
 (`BENCH_r0N.json`, `PRODDAY_r0N.json`) must carry so no number can be
 mistaken for a rig number and no two emitters can drift.
 
-The driver's artifacts (r01-r05) ran on the TPU v5e rig; everything
-produced in-session runs on the CPU sandbox, so each artifact stamps:
+Each artifact stamps:
 
-- the platform block (backend, machine, python, an explicit
-  not-rig-comparable note),
+- the platform block (the platform JAX reported to the run — observed,
+  never defaulted — machine, python, and whether the numbers were
+  measured on the chip),
 - segment health (`segments_incomplete`: a null in the summary must
   read as "segment failed", never "measured zero"),
 - the compile-cache story (`.jax_cache` size at run start / run end /
@@ -25,14 +25,15 @@ from __future__ import annotations
 import os
 import platform as _platform
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+def jax_cache_bytes() -> int:
+    """Current on-disk size of the persistent compilation cache — the
+    directory the package resolved (JAX_COMPILATION_CACHE_DIR when given
+    from outside, else <checkout>/.jax_cache)."""
+    import jax
 
-def jax_cache_bytes(repo: str | None = None) -> int:
-    """Current on-disk size of the persistent compilation cache."""
-    cache = os.path.join(repo or _REPO, ".jax_cache")
     total = 0
-    for root, _dirs, files in os.walk(cache):
+    for root, _dirs, files in os.walk(jax.config.jax_compilation_cache_dir):
         for f in files:
             try:
                 total += os.path.getsize(os.path.join(root, f))
@@ -41,17 +42,21 @@ def jax_cache_bytes(repo: str | None = None) -> int:
     return total
 
 
-def platform_block(backend: str = "cpu",
-                   note: str = "in-session CPU sandbox run; "
-                               "not rig-comparable") -> dict:
-    """Off-rig provenance: absolute tps from a sandbox run is NOT
-    comparable to the rig rounds; same-run ratios, spreads, parity
-    booleans and pass/fail verdicts are the quotable signals."""
+def platform_block(backend: str | None = None) -> dict:
+    """Where the run's device work happened: `backend` is the platform JAX
+    REPORTED for it (`jax.devices()[0].platform`, as the run itself printed
+    it) or None when the run did not observe one — never a default. Only a
+    `tpu` backend makes absolute rates comparable to chip rounds; from any
+    other run the quotable signals are same-run ratios, spreads, parity
+    booleans and pass/fail verdicts."""
     return {
         "backend": backend,
         "machine": _platform.machine(),
         "python": _platform.python_version(),
-        "note": note,
+        "note": (
+            "measured on the chip" if backend == "tpu"
+            else "not measured on the chip; not comparable to chip rounds"
+        ),
     }
 
 
@@ -69,7 +74,7 @@ def jax_cache_block(parsed: dict) -> dict:
 
 def wrap_artifact(cmd: str, rc: int, env: str, tail: str, parsed: dict,
                   segments_incomplete: list[str], n: int = 1,
-                  backend: str = "cpu") -> dict:
+                  backend: str | None = None) -> dict:
     """The common driver-shaped wrapper {n, cmd, rc, platform, env,
     tail, segments_incomplete, jax_cache, parsed}."""
     return {

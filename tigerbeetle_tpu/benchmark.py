@@ -57,6 +57,84 @@ def kill_process_group(proc) -> None:
         pass
 
 
+# A cold `start --backend dual` at the CLI's default geometry compiles about
+# ten 8192-batch programs before it prints `listening` (one to two minutes
+# on an empty compile cache); the deadline covers that several times over.
+BOOT_DEADLINE_S = 900.0
+
+
+def wait_listening(proc, what: str, log=None,
+                   deadline_s: float = BOOT_DEADLINE_S) -> list[str]:
+    """Read a spawned server's output up to its `listening` line and
+    return the lines read (the `[device]` line is among them). A server
+    that dies first, or is still booting at the deadline (then killed),
+    raises with its exit code and its last output in the message — the
+    child's own words are the diagnosis."""
+    from collections import deque
+
+    tail: deque = deque(maxlen=40)
+    expired = threading.Event()
+
+    def _expire() -> None:
+        expired.set()
+        kill_process_group(proc)  # unblocks the readline below with EOF
+
+    timer = threading.Timer(deadline_s, _expire)
+    timer.daemon = True
+    timer.start()
+    try:
+        while True:
+            line = proc.stdout.readline()
+            if not line:
+                try:
+                    rc = proc.wait(timeout=10)
+                except subprocess.TimeoutExpired:
+                    rc = None
+                why = (
+                    f"did not reach `listening` within {deadline_s:.0f}s"
+                    if expired.is_set() else "died before `listening`"
+                )
+                raise RuntimeError(
+                    f"{what} server {why} (exit code {rc}); its last "
+                    "output:\n" + "".join(tail)
+                )
+            tail.append(line)
+            if "listening" in line:
+                return list(tail)
+            if log is not None:
+                log(line.rstrip())
+    finally:
+        timer.cancel()
+
+
+def require_one_process_per_chip(what: str, backend: str, n_servers: int,
+                                 jax_platform: str | None) -> None:
+    """A chip belongs to ONE process at a time. A launcher that starts
+    several device-backed servers at once (a 3-replica cluster, two
+    federation regions) cannot give each of them the chip: the second
+    would fail, or hang waiting for a chip its sibling holds. Such a
+    launcher must pin the servers to another platform by name, or fail
+    here, early, instead."""
+    if backend == "native" or n_servers <= 1:
+        return
+    # the servers' platform, by cli.asked_platforms' rule (not imported:
+    # this module stays out of the CLI's import closure): the launcher's
+    # pin, else what they inherit; the first name is the one that serves
+    asked = (
+        jax_platform
+        or os.environ.get("TB_JAX_PLATFORM")
+        or os.environ.get("JAX_PLATFORMS")
+        or ""
+    ).lower().split(",")[0].strip()
+    if asked in ("", "tpu"):
+        raise RuntimeError(
+            f"{what}: {n_servers} `--backend {backend}` servers at once "
+            "would all claim the chip, and a chip belongs to one process. "
+            "Run them with jax_platform='cpu' (TB_JAX_PLATFORM=cpu) or "
+            "with --backend native."
+        )
+
+
 def _accounts_body(start_id: int, count: int) -> bytes:
     arr = np.zeros(count, dtype=ACCOUNT_DTYPE)
     arr["id_lo"] = np.arange(start_id, start_id + count, dtype=np.uint64)
@@ -213,13 +291,7 @@ def run_e2e(
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
     )
     try:
-        while True:  # skip [boot] trace lines until ready (TPU init)
-            line = proc.stdout.readline()
-            if "listening" in line:
-                break
-            if not line:
-                raise RuntimeError("bench server died before listening")
-            log(line.rstrip())
+        wait_listening(proc, "bench", log)
         log(f"server up on :{port} (slots 2^{slots_log2})")
 
         # Keep draining server output: an unread pipe fills and BLOCKS the
@@ -770,13 +842,7 @@ def run_ingress_sessions(
     )
     buses: list[TCPMessageBus] = []
     try:
-        while True:
-            line = proc.stdout.readline()
-            if "listening" in line:
-                break
-            if not line:
-                raise RuntimeError("ingress server died before listening")
-            log(line.rstrip())
+        wait_listening(proc, "ingress", log)
         log(f"server up on :{port} ({n_sessions} sessions over {conns} conns)")
         server_stats: dict = {}
 
@@ -1163,13 +1229,7 @@ def run_frontier(
     )
     buses: list[TCPMessageBus] = []
     try:
-        while True:
-            line = proc.stdout.readline()
-            if "listening" in line:
-                break
-            if not line:
-                raise RuntimeError("frontier server died before listening")
-            log(line.rstrip())
+        wait_listening(proc, "frontier", log)
         log(f"server up on :{port} backend={backend} ladder={list(steps)}")
         server_stats: dict = {}
 

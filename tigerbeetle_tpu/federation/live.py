@@ -244,7 +244,12 @@ def run_federation_chaos(
     before and half after the mid-run region kill."""
     import tempfile
 
-    from tigerbeetle_tpu.benchmark import REPO, free_port, kill_process_group
+    from tigerbeetle_tpu.benchmark import (
+        REPO,
+        free_port,
+        kill_process_group,
+        require_one_process_per_chip,
+    )
     from tigerbeetle_tpu.inspect import inspect_live, verify_commitment_stream
     from tigerbeetle_tpu.metrics import Metrics
     from tigerbeetle_tpu.state_machine import decode_accounts, encode_ids
@@ -266,6 +271,9 @@ def run_federation_chaos(
                TB_PARENT_WATCHDOG="1")
     if jax_platform:
         env["TB_JAX_PLATFORM"] = jax_platform
+    require_one_process_per_chip(
+        "federation", backend, regions * replica_count, jax_platform
+    )
 
     region_ports: list[list[int]] = []
     servers: list[list[ChaosServer]] = []

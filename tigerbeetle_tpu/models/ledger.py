@@ -138,7 +138,29 @@ class CompileSentinel:
         self.post_warmup = 0
         self.per_name: dict[str, int] = {}
         self.events: deque = deque(maxlen=self._EVENTS_MAX)
+        # persistent-cache story (jax's own monitoring events): of the
+        # compile requests that consulted the cache, how many it SERVED
+        # (hits) and how many the backend compiled and STORED (misses:
+        # programs over the persistence threshold — the ones a warm
+        # cache turns into hits; `total` cannot show that, it counts
+        # traces, and every process traces anew). The rest of the
+        # requests are programs too small to be worth storing.
+        self.cache_requests = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
         self._bind(NULL_METRICS)
+        jax.monitoring.register_event_listener(self._on_jax_event)
+
+    def _on_jax_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            with self._lock:
+                self.cache_requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            with self._lock:
+                self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            with self._lock:
+                self.cache_misses += 1
 
     def _bind(self, m) -> None:
         self._c_total = m.counter("device.compiles")
@@ -188,6 +210,9 @@ class CompileSentinel:
                 "total": self.total,
                 "post_warmup": self.post_warmup,
                 "warm": self.warm,
+                "cache_requests": self.cache_requests,
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses,
                 "per_fn": dict(self.per_name),
                 "events": list(self.events),
             }

@@ -22,17 +22,26 @@ _lib: ctypes.CDLL | None = None
 
 
 def _build() -> None:
-    srcs = [
-        os.path.join(_NATIVE_DIR, s)
-        for s in ("aegis.cc", "storage.cc", "tb_client.cc", "ledger.cc")
-    ]
-    if os.path.exists(_LIB_PATH) and all(
-        os.path.getmtime(_LIB_PATH) >= os.path.getmtime(s) for s in srcs
-    ):
-        return
-    subprocess.run(
-        ["make", "-s", "libtb_native.so"], cwd=_NATIVE_DIR, check=True
-    )
+    """Build the library when it is MISSING (a copy does not preserve
+    mtimes, so staleness is not judged here: after editing a .cc run
+    `make -C native`). The build runs under a lock file: a fresh checkout
+    starts a server and a load generator together, and whichever comes
+    second must wait for the finished library, not load a half-written
+    one."""
+    import fcntl
+
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(_LIB_PATH):
+            return
+        done = subprocess.run(
+            ["make", "-s", "libtb_native.so"], cwd=_NATIVE_DIR,
+            capture_output=True, text=True,
+        )
+        if done.returncode != 0:
+            raise RuntimeError(
+                f"building {_LIB_PATH} failed:\n{done.stdout}{done.stderr}"
+            )
 
 
 def lib() -> ctypes.CDLL:

@@ -41,19 +41,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 moved shard_map out of experimental (kwarg: check_vma)
-    from jax import shard_map as _shard_map
-
-    def shard_map(fn, **kw):
-        return _shard_map(fn, **kw)
-except ImportError:  # pragma: no cover — older jax (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(fn, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _shard_map_old(fn, **kw)
 
 from tigerbeetle_tpu.constants import ConfigProcess
 from tigerbeetle_tpu.models import validate
@@ -127,33 +116,45 @@ def owner_of_ids_np(id_lo: np.ndarray, id_hi: np.ndarray, n_shards: int) -> np.n
     return (x % np.uint64(n_shards)).astype(np.int64)
 
 
-def init_sharded_state(mesh: Mesh, process: ConfigProcess) -> dict:
-    """Tables of [n_shards, local_rows, 32] sharded over mesh axis "shard".
-    local capacity = 2^account_slots_log2 etc. PER SHARD."""
+def sharded_state_program(mesh: Mesh, process: ConfigProcess):
+    """The jitted allocator of the sharded state: tables of [n_shards,
+    local_rows, 32] sharded over mesh axis "shard", local capacity =
+    2^account_slots_log2 etc. PER SHARD. Allocated SHARDED from the start
+    (out_shardings): every device fills only its own slice. Building each
+    table whole on the default device and then device_put-ing it across
+    the mesh would land n_shards x the per-shard state on chip 0 first."""
     n = mesh.devices.size
     a_rows = (1 << process.account_slots_log2) + 1
     t_rows = (1 << process.transfer_slots_log2) + 1
     sh = NamedSharding(mesh, P("shard"))
     sc = NamedSharding(mesh, P())
 
-    def put(x, s):
-        return jax.device_put(x, s)
+    def build():
+        return {
+            "acct_rows": jnp.zeros((n, a_rows, ROW_WORDS), dtype=U32),
+            "xfer_rows": jnp.zeros((n, t_rows, ROW_WORDS), dtype=U32),
+            "fulfill": jnp.zeros((n, t_rows), dtype=U32),
+            "acct_claim": jnp.full((n, a_rows), ht.CLAIM_FREE, dtype=U32),
+            "xfer_claim": jnp.full((n, t_rows), ht.CLAIM_FREE, dtype=U32),
+            "bal_acc": jnp.zeros((n, a_rows, ROW_WORDS), dtype=U32),
+            # per-shard ever-applied insert counters (device load guard)
+            "acct_used_slots": jnp.zeros((n,), dtype=jnp.uint64),
+            "xfer_used_slots": jnp.zeros((n,), dtype=jnp.uint64),
+            "commit_ts": jnp.uint64(0),
+            "acct_count": jnp.uint64(0),
+            "xfer_count": jnp.uint64(0),
+            "fault": jnp.uint32(0),
+        }
 
-    return {
-        "acct_rows": put(jnp.zeros((n, a_rows, ROW_WORDS), dtype=U32), sh),
-        "xfer_rows": put(jnp.zeros((n, t_rows, ROW_WORDS), dtype=U32), sh),
-        "fulfill": put(jnp.zeros((n, t_rows), dtype=U32), sh),
-        "acct_claim": put(jnp.full((n, a_rows), ht.CLAIM_FREE, dtype=U32), sh),
-        "xfer_claim": put(jnp.full((n, t_rows), ht.CLAIM_FREE, dtype=U32), sh),
-        "bal_acc": put(jnp.zeros((n, a_rows, ROW_WORDS), dtype=U32), sh),
-        # per-shard ever-applied insert counters (device load guard)
-        "acct_used_slots": put(jnp.zeros((n,), dtype=jnp.uint64), sh),
-        "xfer_used_slots": put(jnp.zeros((n,), dtype=jnp.uint64), sh),
-        "commit_ts": put(jnp.uint64(0), sc),
-        "acct_count": put(jnp.uint64(0), sc),
-        "xfer_count": put(jnp.uint64(0), sc),
-        "fault": put(jnp.uint32(0), sc),
+    replicated = ("commit_ts", "acct_count", "xfer_count", "fault")
+    shardings = {
+        k: sc if k in replicated else sh for k in jax.eval_shape(build)
     }
+    return jax.jit(build, out_shardings=shardings)
+
+
+def init_sharded_state(mesh: Mesh, process: ConfigProcess) -> dict:
+    return sharded_state_program(mesh, process)()
 
 
 class ShardedLedgerKernels:
@@ -897,6 +898,8 @@ class ShardedLedger(HostLedgerBase):
             mode = self.mode
             if mode == "auto":
                 mode = "serial" if self.hazards.transfers_hazard(arr) else "fast"
+            # the [stats] `split` surface: which tier each batch took
+            self.hazards.plan_stats[mode] += 1
             fn = (
                 self.kernels.commit_transfers_fast
                 if mode == "fast"
@@ -1037,19 +1040,21 @@ class ShardedLedger(HostLedgerBase):
                 f"{self.n_shards} @ 2^{self.process.account_slots_log2}/"
                 f"2^{self.process.transfer_slots_log2}"
             )
-        fresh = init_sharded_state(self.mesh, self.process)
+        # Each leaf goes host -> its own shards directly (device_put of the
+        # numpy array with the leaf's sharding): no whole-table staging on
+        # the default device. The outgoing state is released FIRST — only
+        # its metadata is kept — so a restore never holds two full states.
+        layout = {
+            k: (v.dtype, v.shape, v.sharding) for k, v in self.state.items()
+        }
+        self.state = {}
         off = 4 + hn
         names = self._SNAP_SHARDED + self._SNAP_REPLICATED
         for name, size in zip(names, head["sizes"]):
-            ref = fresh[name]
-            # .dtype/.shape are metadata — never np.asarray(ref) here (a
-            # full d2h gather per leaf, twice, on the degrading transport)
-            host = np.frombuffer(
-                raw[off : off + size], dtype=ref.dtype
-            ).reshape(ref.shape)
-            fresh[name] = jax.device_put(jnp.asarray(host), ref.sharding)
+            dtype, shape, sharding = layout[name]
+            host = np.frombuffer(raw[off : off + size], dtype=dtype)
+            self.state[name] = jax.device_put(host.reshape(shape), sharding)
             off += size
-        self.state = fresh
         self._acct_used = np.array(head["acct_used"], dtype=np.int64)
         self._xfer_used = np.array(head["xfer_used"], dtype=np.int64)
         h = self.hazards

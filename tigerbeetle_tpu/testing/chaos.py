@@ -58,6 +58,7 @@ from tigerbeetle_tpu.benchmark import (
     _transfers_body,
     free_port,
     kill_process_group,
+    require_one_process_per_chip,
 )
 from tigerbeetle_tpu.constants import ConfigCluster
 from tigerbeetle_tpu.io.storage import Zone, ZoneLayout
@@ -492,6 +493,9 @@ def run_chaos(
                TB_PARENT_WATCHDOG="1")
     if jax_platform:
         env["TB_JAX_PLATFORM"] = jax_platform
+    require_one_process_per_chip(
+        "chaos", backend, replica_count, jax_platform
+    )
 
     # ledger slots sized to the workload (the server defaults allocate
     # 2^24 transfer slots — three dual-backend replicas on one box would
