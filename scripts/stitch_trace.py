@@ -10,21 +10,13 @@ one leg of an op in the merged file draws its whole causal tree across
 processes: ingress -> fuse/quorum -> journal write -> commit -> reply ->
 CDC emit -> device apply.
 
-The XLA trace bridge: `--device-trace <dir>` additionally merges a
-bounded device-trace window captured on the applier thread
-(`start --device-trace <dir>`, or scripts/profile_applier.py). The
-jax.profiler dump under `<dir>/plugins/profile/*/` carries device/host
-timelines on its own pids with its own timebase; the sidecar
-`device_trace_meta.json` written at capture start anchors that window to
-the span dumps' clock (perf_counter microseconds), so XLA kernel slices
-land at the right offset under the applier's `device.*` sub-leg spans.
-Device pids are re-numbered AFTER the span-dump pids — the device
-timeline appears as its own process group in the stitched file.
+The device's timeline is not stitched in here: a server that holds a
+chip writes these same spans (as `tb.*`) into the profiler's own trace,
+beside the kernels, on one clock (`start --device-trace <dir>`).
 
 Usage:
     python scripts/stitch_trace.py --out cluster.json \
-        r0.trace.json r1.trace.json r2.trace.json \
-        [--device-trace /tmp/devtrace]
+        r0.trace.json r1.trace.json r2.trace.json
 
 The output is canonical JSON (sorted keys, fixed separators): stitching
 byte-identical inputs — e.g. two same-seed simulator replays — yields
@@ -33,8 +25,6 @@ deterministic artifact.
 """
 
 import argparse
-import glob
-import gzip
 import json
 import os
 import sys
@@ -42,66 +32,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tigerbeetle_tpu.tracer import stitch  # noqa: E402
-
-
-def load_device_trace(trace_dir: str, pid_base: int) -> list[dict]:
-    """Load a jax.profiler capture directory and return its trace events
-    aligned to the span dumps' clock and re-pid'd starting at `pid_base`.
-
-    Alignment: the profiler's Chrome-trace timestamps are microseconds on
-    the profiler's own timebase whose zero is (approximately) the
-    start_trace call; `device_trace_meta.json` records perf_counter_ns at
-    that same moment, so shifting the window's earliest event onto the
-    anchor puts device slices on the span dumps' microsecond axis. The
-    residual error is the start_trace latency (sub-millisecond) — fine
-    for eyeballing which XLA op fills a device_busy span, and flagged in
-    the stitched metadata so nobody reads it as nanosecond-exact.
-    """
-    meta_path = os.path.join(trace_dir, "device_trace_meta.json")
-    anchor_us = None
-    if os.path.exists(meta_path):
-        with open(meta_path) as f:
-            anchor_us = json.load(f).get("anchor_perf_ns", 0) / 1000.0
-    paths = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz"
-    )))
-    # uncompressed fallback (tests + older plugin versions)
-    paths += sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json"
-    )))
-    events: list[dict] = []
-    for p in paths:
-        opener = gzip.open if p.endswith(".gz") else open
-        with opener(p, "rt") as f:
-            doc = json.load(f)
-        events.extend(
-            doc["traceEvents"] if isinstance(doc, dict) else doc
-        )
-    if not events:
-        return []
-    ts_vals = [e["ts"] for e in events
-               if "ts" in e and e.get("ph") != "M"]
-    shift = (anchor_us - min(ts_vals)
-             if anchor_us is not None and ts_vals else 0.0)
-    pid_map: dict = {}
-    out: list[dict] = []
-    for e in events:
-        e = dict(e)
-        pid = e.get("pid", 0)
-        if pid not in pid_map:
-            pid_map[pid] = pid_base + len(pid_map)
-        e["pid"] = pid_map[pid]
-        if "ts" in e and e.get("ph") != "M":
-            e["ts"] = e["ts"] + shift
-        out.append(e)
-    out.append({
-        "ph": "M", "name": "process_name", "pid": pid_base, "tid": 0,
-        "ts": 0, "args": {
-            "name": f"xla:{os.path.basename(trace_dir.rstrip('/'))} "
-                    f"(clock-aligned, +-start_trace latency)"
-        },
-    })
-    return out
 
 
 def main() -> int:
@@ -112,11 +42,6 @@ def main() -> int:
     ap.add_argument("inputs", nargs="+",
                     help="trace dumps, one per process (pid = input order)")
     ap.add_argument("--out", required=True, help="merged output path")
-    ap.add_argument("--device-trace", action="append", default=[],
-                    metavar="DIR",
-                    help="jax.profiler capture dir (start --device-trace); "
-                    "its device timeline is clock-aligned and merged as "
-                    "its own pid group")
     args = ap.parse_args()
 
     event_lists = []
@@ -128,26 +53,14 @@ def main() -> int:
         event_lists.append(events)
         labels.append(os.path.basename(path))
     merged = stitch(event_lists, labels=labels)
-    dev_count = 0
-    pid_base = len(event_lists)
-    for trace_dir in args.device_trace:
-        dev = load_device_trace(trace_dir, pid_base)
-        if not dev:
-            print(f"[stitch] no profiler dump under {trace_dir} "
-                  "(plugins/profile/*/)", file=sys.stderr)
-            continue
-        pid_base = 1 + max(e.get("pid", 0) for e in dev)
-        dev_count += len(dev)
-        merged.extend(dev)
     with open(args.out, "w") as f:
         json.dump({"traceEvents": merged}, f, sort_keys=True,
                   separators=(",", ":"))
     flows = sum(1 for e in merged if e.get("ph") in ("s", "t", "f"))
     ids = len({e["id"] for e in merged if e.get("ph") in ("s", "t", "f")})
-    dev_note = f", {dev_count} device events" if dev_count else ""
     print(
         f"stitched {len(args.inputs)} dump(s): {len(merged)} events, "
-        f"{flows} flow legs across {ids} op trace id(s){dev_note} "
+        f"{flows} flow legs across {ids} op trace id(s) "
         f"-> {args.out}",
         file=sys.stderr,
     )

@@ -1,5 +1,5 @@
 """Device anatomy (tigerbeetle_tpu/latency.py DeviceAnatomy + the
-models/ledger.py compile sentinel + the stitch_trace XLA bridge).
+models/ledger.py compile sentinel).
 
 Contracts under test:
 
@@ -14,15 +14,10 @@ Contracts under test:
   (drift guard, same contract as latency.*/cdc.*/ingress.*);
 - the compile sentinel counts cold compiles, stays silent on cache
   hits, and flags a compile after mark_warm() as a post-warmup event;
-- the XLA trace bridge clock-aligns a jax.profiler dump onto the span
-  clock via the device_trace_meta.json anchor and re-pids device
-  events after the span-dump pids;
 - device stamping is observability only: two same-seed follower runs
   with every op sampled produce identical device code-stream digests.
 """
 
-import gzip
-import json
 from time import perf_counter_ns
 
 import numpy as np
@@ -131,7 +126,6 @@ def test_device_metric_names_cataloged():
         ("device.compiles", "counter"),
         ("device.compiles_post_warmup", "counter"),
         ("device.compile_ms", "histogram"),
-        ("device.trace_windows", "counter"),
     ):
         assert name in CATALOG, name
         kind, unit, help_ = CATALOG[name]
@@ -331,82 +325,3 @@ def test_same_seed_follower_device_digests_identical_with_stamping():
         assert report["verified"] is True, report
         digests.append(report["code_stream_digest"]["device"])
     assert digests[0] == digests[1]
-
-
-# -- XLA trace bridge (stitch_trace --device-trace) --------------------
-
-
-def _fake_profiler_dump(root, anchor_perf_ns: int):
-    prof = root / "plugins" / "profile" / "2026_08_07_00_00_00"
-    prof.mkdir(parents=True)
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 5, "tid": 0,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "X", "name": "fused_fold", "pid": 5, "tid": 1,
-         "ts": 1000.0, "dur": 50.0},
-        {"ph": "X", "name": "copy_h2d", "pid": 9, "tid": 0,
-         "ts": 1200.0, "dur": 10.0},
-    ]
-    with gzip.open(prof / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    (root / "device_trace_meta.json").write_text(json.dumps({
-        "anchor_perf_ns": anchor_perf_ns,
-        "anchor_unix_s": 0.0,
-        "window_s": 1.0,
-    }))
-
-
-def test_stitch_load_device_trace_aligns_clock_and_repids(tmp_path):
-    import sys as _sys
-
-    _sys.path.insert(0, "/root/repo")
-    from scripts.stitch_trace import load_device_trace
-
-    _fake_profiler_dump(tmp_path, anchor_perf_ns=2_000_000_000)
-    out = load_device_trace(str(tmp_path), pid_base=3)
-    xs = [e for e in out if e.get("ph") == "X"]
-    assert len(xs) == 2
-    # earliest device ts lands ON the anchor (2e9 ns -> 2e6 us); the
-    # second event keeps its relative offset
-    by_name = {e["name"]: e for e in xs}
-    assert by_name["fused_fold"]["ts"] == 2_000_000.0
-    assert by_name["copy_h2d"]["ts"] == 2_000_200.0
-    # device pids re-based after the span-dump pids, order-stable
-    assert by_name["fused_fold"]["pid"] == 3
-    assert by_name["copy_h2d"]["pid"] == 4
-    # the profiler's own process_name metadata rode along, re-pid'd
-    metas = [e for e in out if e.get("ph") == "M"]
-    assert any(e["pid"] == 3 and e["args"]["name"] == "/device:TPU:0"
-               for e in metas)
-    # and the bridge stamped its own clock-caveat process label
-    assert any("clock-aligned" in e["args"]["name"] for e in metas)
-
-
-def test_stitch_device_trace_merges_with_span_dump(tmp_path):
-    import sys as _sys
-
-    _sys.path.insert(0, "/root/repo")
-    from scripts.stitch_trace import load_device_trace
-    from tigerbeetle_tpu.tracer import stitch
-
-    _fake_profiler_dump(tmp_path, anchor_perf_ns=5_000_000_000)
-    spans = [{"name": "shadow.upload", "ph": "X", "ts": 4_999_000.0,
-              "dur": 3000.0, "pid": 0, "tid": 0, "args": {"trace": 7}}]
-    merged = stitch([spans], labels=["applier"])
-    dev = load_device_trace(str(tmp_path), pid_base=1)
-    merged.extend(dev)
-    pids = {e["pid"] for e in merged}
-    assert 0 in pids and 1 in pids  # spans pid 0, device group after
-    # device events sit inside the applier span's window after alignment
-    span = next(e for e in merged if e.get("name") == "shadow.upload")
-    fold = next(e for e in merged if e.get("name") == "fused_fold")
-    assert span["ts"] <= fold["ts"] <= span["ts"] + span["dur"]
-
-
-def test_load_device_trace_empty_dir_returns_nothing(tmp_path):
-    import sys as _sys
-
-    _sys.path.insert(0, "/root/repo")
-    from scripts.stitch_trace import load_device_trace
-
-    assert load_device_trace(str(tmp_path), pid_base=1) == []

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import sys
+import threading
 import time
 
 from tigerbeetle_tpu import flags
@@ -149,12 +150,10 @@ class StartArgs:
     # histogram percentiles), ring of ~180 entries served through the
     # [stats] wire command (`inspect live --watch`). 0 disables.
     flight_interval_s: float = 1.0
-    # XLA trace bridge (dual/native+device backends): capture a bounded
-    # jax.profiler window on the device-applier thread into this
-    # directory, starting at the applier's first dequeue after serving
-    # begins. scripts/stitch_trace.py --device-trace merges the captured
-    # device timeline into the stitched Perfetto file, clock-aligned to
-    # our spans (the directory also gets device_trace_meta.json).
+    # One bounded jax.profiler window (every backend that holds a chip)
+    # into this directory, opened at the first request after serving
+    # begins: the xplane holds the device's kernels AND the program's
+    # own spans (`tb.*`, tracer.ProfilerTracer) on one clock.
     device_trace: str = ""
     device_trace_s: float = 3.0  # window length (seconds)
     # Checkpoint state commitments (federation/commitment.py): fold the
@@ -421,6 +420,50 @@ def device_memory(devices) -> dict:
     }
 
 
+class _ProfilerWindow:
+    """`start --device-trace <dir>`: ONE bounded jax.profiler window,
+    opened by the serve loop at the first request and closed from a
+    thread of its own (stop_trace writes the xplane for seconds: the
+    loop must not wait for it)."""
+
+    def __init__(self, out_dir: str, seconds: float):
+        self.out_dir = out_dir
+        self.seconds = seconds
+        self._thread = None
+        self._stop = threading.Event()
+
+    def open_once(self) -> None:
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._run, name="profiler-window", daemon=True
+            )
+            self._thread.start()
+
+    def _run(self) -> None:
+        import jax
+
+        try:
+            options = jax.profiler.ProfileOptions()
+            # no Python tracer: it logs every call of the event loop and
+            # slows the server it is looking at
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.out_dir, profiler_options=options)
+            self._stop.wait(self.seconds)
+            jax.profiler.stop_trace()
+            print(f"[device-trace] window written under {self.out_dir}",
+                  flush=True)
+        except Exception as e:  # profiling must never take the server down
+            print(f"[device-trace] failed: {type(e).__name__}: {e}",
+                  flush=True)
+
+    def close(self, timeout: float = 120.0) -> None:
+        """Shutdown: end a window that is still open, and wait for its
+        file."""
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(timeout=timeout)
+
+
 def cmd_start(args) -> int:
     import faulthandler
     import os
@@ -452,7 +495,7 @@ def cmd_start(args) -> int:
     from tigerbeetle_tpu.io.time import RealTime
     from tigerbeetle_tpu.metrics import Metrics
     from tigerbeetle_tpu.statsd import StatsD, StatsDEmitter, parse_addr
-    from tigerbeetle_tpu.tracer import JsonTracer, Tracer
+    from tigerbeetle_tpu.tracer import JsonTracer, ProfilerTracer, Tracer
     from tigerbeetle_tpu.vsr.replica import Replica
 
     # ONE registry + tracer for the whole process: the replica, bus,
@@ -460,7 +503,14 @@ def cmd_start(args) -> int:
     # line / --statsd emission / --trace dump read from it (the reference
     # wires tracer.zig + statsd.zig through the same stages).
     metrics = Metrics()
-    tracer = JsonTracer(metrics=metrics) if args.trace else Tracer()
+    if args.trace:
+        tracer = JsonTracer(metrics=metrics)
+    elif args.backend != "native":
+        # the process holds a chip: spans go to whatever profiler session
+        # is open (none: a no-op in the runtime), beside the kernels
+        tracer = ProfilerTracer()
+    else:
+        tracer = Tracer()
 
     addresses = _parse_addresses(args.addresses)
     cluster_cfg = ConfigCluster(
@@ -664,12 +714,20 @@ def cmd_start(args) -> int:
         gateway.install()
         boot("ingress gateway installed")
     device_report = None  # () -> the [device]/[stats].device dict
+    launch_clock = None
     if args.backend != "native":
         import json as _json
 
         # the devices the ledger state actually lives on (dual: the
         # follower's DeviceLedger), not merely what JAX can see
         dev_ledger = getattr(replica.ledger, "device", replica.ledger)
+        if hasattr(dev_ledger, "launch_clock"):
+            from tigerbeetle_tpu.metrics import LaunchClock
+
+            # device time per commit launch over the whole run, booked
+            # from a completion thread (this process only: harnesses that
+            # construct a DeviceLedger never get one)
+            launch_clock = dev_ledger.launch_clock = LaunchClock(metrics)
         state_devices = sorted(
             dev_ledger.state["acct_rows"].devices(), key=lambda d: d.id
         )
@@ -693,16 +751,14 @@ def cmd_start(args) -> int:
         from tigerbeetle_tpu.models.ledger import COMPILE_SENTINEL
 
         COMPILE_SENTINEL.mark_warm()
+    trace_window = None
     if args.device_trace:
-        if hasattr(replica.ledger, "start_device_trace"):
-            replica.ledger.start_device_trace(
-                args.device_trace, args.device_trace_s
-            )
+        if args.backend == "native":
+            print("--device-trace ignored: the native backend holds no chip",
+                  flush=True)
         else:
-            print(
-                f"--device-trace ignored: backend {args.backend!r} has "
-                "no device-applier thread (use dual or native+device)",
-                flush=True,
+            trace_window = _ProfilerWindow(
+                args.device_trace, args.device_trace_s
             )
     profile_path = os.environ.get("TB_PROFILE")
     prof = None
@@ -744,6 +800,13 @@ def cmd_start(args) -> int:
                     "verified": False,
                     "error": f"{type(e).__name__}: {e}",
                 }
+        if launch_clock is not None:
+            # book the launches still in flight before the registry is
+            # read (dual: the applier has drained; device: what the loop
+            # had not fetched yet)
+            launch_clock.close()
+        if trace_window is not None:
+            trace_window.close()
         hz = getattr(replica.ledger, "hazards", None)
         stats = {
             "group": dict(replica.group_stats),
@@ -872,7 +935,7 @@ def cmd_start(args) -> int:
             f"wanted={sorted(replica._repair_wanted)}\n"
         )
         faulthandler.dump_traceback(file=sys.stderr)
-        if tracer.enabled:
+        if args.trace:
             open_spans = [
                 e for e in tracer.events_ordered() if e["ph"] == "B"
             ]
@@ -945,6 +1008,8 @@ def cmd_start(args) -> int:
         busy = bool(replica._inflight) or replica._fuse_started is not None
         t0 = time.monotonic()
         n = bus.pump(timeout=0.0 if busy else tick_s)
+        if n and trace_window is not None:
+            trace_window.open_once()
         # every turn (not only n > 0): same-turn arrivals fuse into a
         # group, and an expired fuse window must dispatch promptly
         replica.pump_commits()
@@ -972,7 +1037,8 @@ def cmd_start(args) -> int:
         now = time.monotonic()
         if now - last_tick >= tick_s:
             last_tick = now
-            replica.tick()
+            with tracer.span("loop.tick"):
+                replica.tick()
             # registry updates are unconditional — the [stats] snapshot
             # and bench server_metrics carry them with or without statsd
             if replica.commit_min != last_commit:

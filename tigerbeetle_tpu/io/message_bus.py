@@ -64,6 +64,8 @@ from tigerbeetle_tpu.tracer import NULL_TRACER
 from tigerbeetle_tpu.vsr.header import HEADER_SIZE, Command, Header, trace_id
 
 MESSAGE_SIZE_MAX_DEFAULT = 1 << 20
+# bus.frame_recv_us times only frames that cannot arrive in one read
+FRAME_RECV_MIN = 1 << 16
 
 
 class MessagePool:
@@ -97,6 +99,7 @@ class _Conn:
     __slots__ = (
         "sock", "peer", "connected", "rbuf", "roff", "wbuf",
         "sessions", "strikes", "pending_traces", "pending_lat",
+        "rx_first_ns", "rx_last_ns",
     )
 
     def __init__(self, sock: socket.socket, peer: Address | None = None,
@@ -121,6 +124,11 @@ class _Conn:
         # flush that writes this conn finishes their records (the
         # reply_egress leg ends at the first socket write)
         self.pending_lat: list[int] = []
+        # bus.frame_recv_us: when the first byte of the frame at the head
+        # of rbuf was read (0 = rbuf holds no unconsumed byte), and when
+        # this connection was last read (perf_counter_ns; 0 = metrics off)
+        self.rx_first_ns = 0
+        self.rx_last_ns = 0
 
 
 class TCPMessageBus(Network):
@@ -155,6 +163,7 @@ class TCPMessageBus(Network):
         self._c_frames = m.counter("bus.frames")
         self._c_reconnects = m.counter("bus.reconnects")
         self._c_dial_failures = m.counter("bus.dial_failures")
+        self._h_frame_recv = m.histogram("bus.frame_recv_us")
 
     def __init__(
         self,
@@ -494,7 +503,15 @@ class TCPMessageBus(Network):
             hot, self._hot = self._hot, {}
             for conn in hot:
                 dispatched += self._drain(conn)
-        for key, mask in self.sel.select(timeout):
+        if timeout > 0:
+            # the one place the serving loop sleeps: a pump that found
+            # nothing to read (zero-timeout polls of a busy loop turn
+            # tens of thousands of times a second and get no span)
+            with self.tracer.span("loop.poll"):
+                ready = self.sel.select(timeout)
+        else:
+            ready = self.sel.select(timeout)
+        for key, mask in ready:
             kind, conn = key.data
             if kind == "accept":
                 self._accept()
@@ -523,6 +540,7 @@ class TCPMessageBus(Network):
             # error, buffered frames STILL dispatch before the close —
             # a one-shot client may send its request and close.
             closing = False
+            t_read = _time.perf_counter_ns() if t0 else 0
             for _ in range(64):
                 try:
                     chunk = conn.sock.recv(1 << 18)
@@ -536,6 +554,10 @@ class TCPMessageBus(Network):
                 conn.rbuf += chunk
                 if len(chunk) < (1 << 18):
                     break
+            if t_read and len(conn.rbuf) > conn.roff:
+                conn.rx_last_ns = t_read
+                if not conn.rx_first_ns:
+                    conn.rx_first_ns = t_read
             dispatched += self._drain(conn)
             if closing:
                 self._close(conn)
@@ -596,6 +618,19 @@ class TCPMessageBus(Network):
                     break
                 frame = bytes(mv[conn.roff : conn.roff + size])
                 conn.roff += size
+                if conn.rx_first_ns:
+                    if size > FRAME_RECV_MIN:
+                        # a 1 MiB create takes several reads, and the
+                        # request's own clock (latency.e2e_us) starts
+                        # only once its handler runs
+                        self._h_frame_recv.observe(
+                            (_time.perf_counter_ns() - conn.rx_first_ns)
+                            / 1000.0
+                        )
+                    # what is left in rbuf came with the latest read
+                    conn.rx_first_ns = (
+                        conn.rx_last_ns if len(buf) > conn.roff else 0
+                    )
                 if conn.peer is None:
                     # first frame identifies the peer (hello or any
                     # message: the client field for clients, replica for

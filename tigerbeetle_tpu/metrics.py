@@ -28,6 +28,7 @@ Batched StatsD emission over this registry lives in statsd.StatsDEmitter
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 from collections.abc import Mapping
@@ -396,6 +397,73 @@ class FlightRecorder:
         return out[-last:] if last else out
 
 
+# -- device time per commit launch --------------------------------------
+
+
+class LaunchClock:
+    """What a commit launch cost the chip, over the WHOLE run and without
+    a profiler: every launch's result handle goes, with its dispatch time
+    and batch count, to one completion thread that waits for the handles
+    in launch order (the device runs them in that order) and books
+
+        t_ready - max(t_dispatch, previous t_ready)
+
+    into `device.commit_busy_s` / `device.launch_busy_us`, and the
+    launch's batches into `device.commit_batches_done` AT THE SAME
+    INSTANT — so busy seconds over batches done is exact over any
+    interval of the flight recorder, whichever side of its edge a launch
+    completes on. A launch dispatched behind a running one is booked from
+    the moment its predecessor finished: the time it spends waiting for
+    its own upload counts as busy (the profiler's trace tells the two
+    apart; this clock cannot).
+
+    Never blocks a launcher (one queue put a launch) and keeps only the
+    small result array alive. Started by the serving process alone
+    (cli.cmd_start); harnesses that must stay single-threaded never
+    construct it."""
+
+    def __init__(self, metrics: "Metrics"):
+        self._c_busy = metrics.counter("device.commit_busy_s")
+        self._c_done = metrics.counter("device.commit_batches_done")
+        self._h_busy = metrics.histogram("device.launch_busy_us")
+        # the queue IS the cross-thread handoff
+        self._q: queue.SimpleQueue = queue.SimpleQueue()  # vet: handoff
+        self._thread = threading.Thread(
+            target=self._run, name="launch-clock", daemon=True
+        )
+        self._thread.start()
+
+    def launched(self, handle, t_dispatch_ns: int, batches: int) -> None:
+        self._q.put((handle, t_dispatch_ns, batches))
+
+    def _run(self) -> None:
+        prev_ready = 0
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            handle, t_dispatch, batches = item
+            try:
+                handle.block_until_ready()
+            except Exception:
+                # a launch that failed on the device surfaces where its
+                # results are read; it cost no time worth booking
+                continue
+            t_ready = time.perf_counter_ns()
+            busy_ns = t_ready - max(t_dispatch, prev_ready)
+            prev_ready = t_ready
+            self._h_busy.observe(busy_ns / 1e3)
+            self._c_busy.add(busy_ns / 1e9)
+            self._c_done.add(batches)
+
+    def close(self, timeout: float = 60.0) -> bool:
+        """Book every launch still in flight, then stop. False if the
+        device did not finish them in time."""
+        self._q.put(None)
+        self._thread.join(timeout=timeout)
+        return not self._thread.is_alive()
+
+
 # -- the zero-allocation no-op backend ---------------------------------
 
 
@@ -528,6 +596,9 @@ CATALOG = {
     "bus.tx_bytes": ("counter", "bytes", "bytes written to sockets"),
     "bus.flushes": ("counter", "", "deferred-send flush passes"),
     "bus.pump_us": ("histogram", "us", "event-loop pump turns that dispatched frames"),
+    "bus.frame_recv_us": (
+        "histogram", "us", "frames over 64 KiB: first byte read -> complete and handed on"
+    ),
     "bus.reconnects": ("counter", "conns", "successful re-dials to a previously reached replica"),
     "bus.dial_failures": ("counter", "", "dials refused/errored (arms the reconnect backoff)"),
     # client runtime (vsr/client.py tick state machine)
@@ -550,6 +621,9 @@ CATALOG = {
     # server event loop (cli.py)
     "loop.busy_s": ("counter", "s", "event-loop busy wall time (pump+commit+flush)"),
     "loop.turns": ("counter", "", "busy event-loop turns"),
+    "loop.fetch_s": (
+        "counter", "s", "seconds blocked fetching commit replies from the device"
+    ),
     "server.ops_committed": ("counter", "ops", "ops committed since boot"),
     "server.commit_min": ("gauge", "op", "highest committed op"),
     # LSM
@@ -714,8 +788,23 @@ CATALOG = {
         "counter", "", "compiles landing AFTER warmup — hot-path recompile events"
     ),
     "device.compile_ms": ("histogram", "ms", "wall time of one observed XLA compile"),
-    # XLA trace bridge (--device-trace profiler window on the applier)
-    "device.trace_windows": ("counter", "", "bounded jax.profiler windows captured"),
+    # commit launches, counted where both backends make them
+    # (models/ledger.py try_execute_group_async / execute_async)
+    "device.commit_launches": ("counter", "", "commit launches (a fused group or one solo batch)"),
+    "device.commit_batches": ("counter", "", "batches those launches carried"),
+    "device.commit_slots": (
+        "counter", "", "batch slots those launches ran (a group's capacity; solo = 1)"
+    ),
+    # the serving process's completion thread (metrics.py LaunchClock)
+    "device.commit_busy_s": (
+        "counter", "s", "device seconds booked to commit launches, in launch order"
+    ),
+    "device.commit_batches_done": (
+        "counter", "", "batches of the launches booked into device.commit_busy_s"
+    ),
+    "device.launch_busy_us": (
+        "histogram", "us", "one launch: ready - max(dispatch, previous ready)"
+    ),
     # time-series flight recorder (metrics.py FlightRecorder)
     "flight.records": ("counter", "", "flight-recorder snapshots taken"),
     "flight.marks": ("counter", "", "phase-marker transitions stamped (prodday `mark`)"),

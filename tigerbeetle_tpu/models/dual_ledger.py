@@ -351,12 +351,6 @@ class DualLedger:
                 "latency.device_apply_lag_us"
             )
             self.device_anatomy = DeviceAnatomy(self.metrics)
-        # --device-trace: a bounded jax.profiler window started/stopped
-        # by the APPLY thread (so it brackets real apply work); armed by
-        # the event loop — a GIL-atomic flag flip polled once per run
-        self._trace_armed = False  # vet: handoff
-        self._trace_dir = ""  # vet: handoff
-        self._trace_window_s = 3.0  # vet: handoff
         # device cannot follow a snapshot restore without an install path
         # (shadow mode, or a follower whose snapshot exceeds the device
         # geometry). Set on the event loop, polled by the apply loop: a
@@ -561,16 +555,13 @@ class DualLedger:
                 chk_nat = fold_reply_codes_np(chk_nat, codes)
                 self._op_ring[op2 % APPLY_RING] = (op2, prep, chk_nat)
 
-        trace_until = 0.0  # active --device-trace window deadline
         while not stop:
             t_wait = _time.perf_counter()
-            run = [self._q.get()]
+            with self.tracer.span("applier.wait_work"):
+                run = [self._q.get()]
             self.shadow_stats.add("idle_s", _time.perf_counter() - t_wait)
             if run[0] is _STOP:
                 break
-            if self._trace_armed:
-                self._trace_armed = False
-                trace_until = self._start_trace_window()
             if isinstance(run[0][0], str):  # control item
                 kind = run[0][0]
                 if kind == _INSTALL:
@@ -753,7 +744,8 @@ class DualLedger:
                                 if h2d_ns:
                                     anat.stamp(t, DLEG_H2D, h2d_ns)
                                 anat.stamp(t, DLEG_DISPATCH, t_disp)
-                            jax.block_until_ready(chk)
+                            with self.tracer.span("applier.fence"):
+                                jax.block_until_ready(chk)
                             t_busy = _time.perf_counter_ns()
                             for t in stretch_toks:
                                 anat.stamp(t, DLEG_BUSY, t_busy)
@@ -801,7 +793,8 @@ class DualLedger:
                             t_disp = _time.perf_counter_ns()
                             for t in stretch_toks:
                                 anat.stamp(t, DLEG_DISPATCH, t_disp)
-                            jax.block_until_ready(chk)
+                            with self.tracer.span("applier.fence"):
+                                jax.block_until_ready(chk)
                             t_busy = _time.perf_counter_ns()
                             for t in stretch_toks:
                                 anat.stamp(t, DLEG_BUSY, t_busy)
@@ -847,11 +840,6 @@ class DualLedger:
                 self._consumed_seq += 1
             with self._apply_cond:
                 self._apply_cond.notify_all()
-            if trace_until and _time.monotonic() >= trace_until:
-                trace_until = 0.0
-                self._stop_trace_window()
-        if trace_until:
-            self._stop_trace_window()
         # written once at apply-loop exit; finalize() joins before reading
         self._chk_device_scalar = chk  # vet: handoff
         self._chk_native_thread = chk_nat
@@ -996,59 +984,6 @@ class DualLedger:
         assert self.follower
         self._put_seq += 1
         self._q.put((_PROBE, op, fp_host))
-
-    # -- XLA trace bridge (--device-trace) ---------------------------------
-
-    def start_device_trace(self, out_dir: str, window_s: float = 3.0) -> None:
-        """Arm a bounded jax.profiler window: the APPLY thread starts the
-        capture at its next dequeue (so the window brackets real apply
-        work, not idle), runs it for ~window_s, and stops it after the
-        run that crosses the deadline. The profile lands under
-        `out_dir/plugins/profile/<ts>/` (gzipped Chrome trace) next to a
-        `device_trace_meta.json` clock anchor — scripts/stitch_trace.py
-        merges it into the stitched Perfetto file with that anchor."""
-        self._trace_dir = out_dir
-        self._trace_window_s = float(window_s)
-        self._trace_armed = True
-
-    def _start_trace_window(self) -> float:
-        """APPLY thread: begin the capture + write the clock anchor.
-        Returns the monotonic deadline (0.0 on failure)."""
-        import json
-        import os
-        import time as _time
-
-        import jax
-
-        try:
-            os.makedirs(self._trace_dir, exist_ok=True)
-            jax.profiler.start_trace(self._trace_dir)
-            anchor_ns = _time.perf_counter_ns()
-            meta = {
-                # perf_counter_ns at profiler start: our spans' clock at
-                # the device timeline's t~0 (alignment is ~ms-accurate —
-                # good enough to line kernels up under their spans)
-                "anchor_perf_ns": anchor_ns,
-                "anchor_unix_s": round(_time.time(), 6),
-                "window_s": self._trace_window_s,
-            }
-            with open(
-                os.path.join(self._trace_dir, "device_trace_meta.json"), "w"
-            ) as f:
-                json.dump(meta, f, indent=1)
-            self.metrics.counter("device.trace_windows").add()
-            return _time.monotonic() + self._trace_window_s
-        except Exception as e:  # profiling must never take the applier down
-            self._trace_dir = f"<failed: {e}>"
-            return 0.0
-
-    def _stop_trace_window(self) -> None:
-        import jax
-
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
 
     def apply_lag_ops(self) -> int:
         """Committed-but-not-yet-device-applied CREATE ops (enqueued

@@ -2045,14 +2045,14 @@ class PendingGroup:
         self.summary = summary
         self.host_summary = None
 
-    def fetch(self):
+    def fetch(self, read=np.asarray):
         if self.host is None:
-            self.host = np.asarray(self.results)
+            self.host = read(self.results)
         return self.host
 
-    def fetch_summary(self):
+    def fetch_summary(self, read=np.asarray):
         if self.host_summary is None:
-            self.host_summary = np.asarray(self.summary)
+            self.host_summary = read(self.summary)
         return self.host_summary
 
 
@@ -2110,9 +2110,38 @@ class DeviceLedger(HostLedgerBase):
         # the compile sentinel rides the same registry rebind (warm-up
         # totals carry over; see CompileSentinel.instrument)
         COMPILE_SENTINEL.instrument(metrics)
-        self._c_h2d = metrics.counter("device.h2d_bytes")
+        self._bind_counters(metrics)
         if getattr(self, "spill", None) is not None:
             self.spill.instrument(metrics, tracer)
+
+    def _bind_counters(self, metrics) -> None:
+        self._c_h2d = metrics.counter("device.h2d_bytes")
+        # the ONE place both backends launch commits through (the dual
+        # applier and the device backend's replica): what a launch
+        # carried, counted where it is made
+        self._c_launches = metrics.counter("device.commit_launches")
+        self._c_batches = metrics.counter("device.commit_batches")
+        self._c_slots = metrics.counter("device.commit_slots")
+        self._c_fetch = metrics.counter("loop.fetch_s")
+
+    def _note_launch(self, handle, t_launch_ns: int, batches: int,
+                     slots: int) -> None:
+        self._c_launches.add()
+        self._c_batches.add(batches)
+        self._c_slots.add(slots)
+        clock = self.launch_clock
+        if clock is not None:
+            clock.launched(handle, t_launch_ns, batches)
+
+    def _fetch(self, dev) -> np.ndarray:
+        """The blocking device->host read of a commit's reply words: the
+        one site where whoever drains (the device backend's event loop)
+        waits for the chip."""
+        t0 = perf_counter_ns()
+        with self.tracer.span("ledger.fetch_replies"):
+            host = np.asarray(dev)
+        self._c_fetch.add((perf_counter_ns() - t0) / 1e9)
+        return host
 
     def __init__(
         self,
@@ -2164,7 +2193,12 @@ class DeviceLedger(HostLedgerBase):
         # dispatch call and its return — never concurrently.
         # vet: owner=device-shadow
         self.last_h2d_done_ns = 0
-        self._c_h2d = self.metrics.counter("device.h2d_bytes")
+        self._bind_counters(self.metrics)
+        # metrics.LaunchClock, installed by the serving process only
+        # (cli.cmd_start): books each launch's device time from a
+        # completion thread. None everywhere else — the simulator's
+        # seeded runs stay single-threaded.
+        self.launch_clock = None
         # Start each batch's device->host result copy AT DISPATCH so a
         # reply-serving driver (the VSR replica) drains landed buffers
         # instead of paying sync round trips. OPT-IN: on transports where
@@ -2190,6 +2224,11 @@ class DeviceLedger(HostLedgerBase):
         ever-applied count. An async driver that never drains keeps the
         conservative estimate — safe (guard can only fire early, never
         late)."""
+        with self.tracer.span("ledger.solo_launch", events=len(events),
+                              xfer_used=self._xfer_used):
+            return self._solo_launch(operation, timestamp, events)
+
+    def _solo_launch(self, operation, timestamp: int, events) -> PendingBatch:
         n = len(events)
         n_pad = self._pad_for(n)
         assert n <= n_pad
@@ -2213,11 +2252,13 @@ class DeviceLedger(HostLedgerBase):
                 decision, wave_plan = self.mode, None
             self.hazards.note_pending(arr)
             if decision == "waves":
+                t_launch = perf_counter_ns()  # the waves upload their own rows
                 results = self._execute_waves(
                     arr, n, n_pad, nn, ts, timestamp, wave_plan
                 )
             else:
                 batch = transfers_to_batch(arr, n_pad)
+                t_launch = perf_counter_ns()  # rows on their way: kernel next
                 self.state, results = self.kernels.commit_transfers(
                     self.state, batch, nn, ts, mode=decision
                 )
@@ -2238,6 +2279,7 @@ class DeviceLedger(HostLedgerBase):
                 mode = "serial" if self.hazards.accounts_hazard(arr) else "fast"
             self.hazards.note_limit_accounts(arr)
             batch = accounts_to_batch(arr, n_pad)
+            t_launch = perf_counter_ns()
             self.state, results = self.kernels.commit_accounts(
                 self.state, batch, nn, ts, mode=mode
             )
@@ -2258,6 +2300,7 @@ class DeviceLedger(HostLedgerBase):
                 summary.copy_to_host_async()
             except (AttributeError, RuntimeError):
                 pass  # no async copy: drain pays the sync cost
+        self._note_launch(results, t_launch, 1, 1)
         return PendingBatch(
             operation, n, results, flags=arr["flags"].copy(),
             epoch=self._occupancy_epoch, summary=summary, plan=plan_info,
@@ -2474,57 +2517,59 @@ class DeviceLedger(HostLedgerBase):
             self.hazards.plan_stats = stats_before
             return None
         k = next(g for g in reversed(self.GROUP_KS) if g >= len(items))
-        n_pad = self._pad_for(max(len(arr) for _, arr in items))
-        slot = self._group_staging_slot(k, n_pad)
-        if slot["fence"] is not None:
-            # Double-buffer fence: this buffer last fed the group dispatched
-            # TWO groups ago — wait for that kernel before mutating it (on
-            # backends where device_put aliases host memory, e.g. CPU,
-            # reuse mid-flight would corrupt the in-flight rows). In steady
-            # state the fence is long retired and this is free; when the
-            # device is more than two groups behind, it is exactly the
-            # backpressure we want.
-            with self.tracer.span("ledger.staging_wait"), \
-                    self.metrics.histogram("ledger.staging_wait_us").time():
-                jax.block_until_ready(slot["fence"])
-            slot["fence"] = None
-        rows = slot["rows"]
-        used = slot["used"]
-        ns = np.zeros(k, dtype=np.int32)  # padding slots: n=0 -> no-ops
-        tss = np.zeros(k, dtype=np.uint64)
-        for i, (ts, arr) in enumerate(items):
-            na = len(arr)
-            rows[i, :na] = arr.view(np.uint32).reshape(na, ROW_WORDS)
-            if used[i] > na:
-                rows[i, na : used[i]] = 0  # zero only the stale tail
-            used[i] = na
-            ns[i] = na
-            tss[i] = ts
-        for i in range(len(items), k):
-            if used[i]:
-                rows[i, : used[i]] = 0
-                used[i] = 0
-        dev_rows = jax.device_put(rows)
-        # upload-issued boundary for the device anatomy's h2d_stage
-        # sub-leg (device_put returns once the transfer is initiated; on
-        # aliasing backends it is the staging copy itself)
-        self.last_h2d_done_ns = perf_counter_ns()
-        self._c_h2d.add(rows.nbytes)
-        try:
-            state, flat, summary = self._group_stepper(k, n_pad)(
-                self.state, dev_rows, jnp.asarray(ns),
-                jnp.asarray(tss),
-            )
-        except Exception:
-            # A broken/flaky (remote) compile must not take the server
-            # down: fall back to per-batch dispatch. But the stepper
-            # donates self.state — a RUNTIME failure after donation leaves
-            # deleted buffers, and no fallback is sound; re-raise then.
-            for buf in self.state.values():
-                if getattr(buf, "is_deleted", lambda: False)():
-                    raise
-            self._group_disabled = True
-            return None
+        with self.tracer.span("ledger.group_launch", slots=k,
+                              batches=len(items), xfer_used=self._xfer_used):
+            n_pad = self._pad_for(max(len(arr) for _, arr in items))
+            slot = self._group_staging_slot(k, n_pad)
+            if slot["fence"] is not None:
+                # Double-buffer fence: this buffer last fed the group dispatched
+                # TWO groups ago — wait for that kernel before mutating it (on
+                # backends where device_put aliases host memory, e.g. CPU,
+                # reuse mid-flight would corrupt the in-flight rows). In steady
+                # state the fence is long retired and this is free; when the
+                # device is more than two groups behind, it is exactly the
+                # backpressure we want.
+                with self.tracer.span("ledger.staging_wait"), \
+                        self.metrics.histogram("ledger.staging_wait_us").time():
+                    jax.block_until_ready(slot["fence"])
+                slot["fence"] = None
+            rows = slot["rows"]
+            used = slot["used"]
+            ns = np.zeros(k, dtype=np.int32)  # padding slots: n=0 -> no-ops
+            tss = np.zeros(k, dtype=np.uint64)
+            for i, (ts, arr) in enumerate(items):
+                na = len(arr)
+                rows[i, :na] = arr.view(np.uint32).reshape(na, ROW_WORDS)
+                if used[i] > na:
+                    rows[i, na : used[i]] = 0  # zero only the stale tail
+                used[i] = na
+                ns[i] = na
+                tss[i] = ts
+            for i in range(len(items), k):
+                if used[i]:
+                    rows[i, : used[i]] = 0
+                    used[i] = 0
+            dev_rows = jax.device_put(rows)
+            # upload-issued boundary for the device anatomy's h2d_stage
+            # sub-leg (device_put returns once the transfer is initiated; on
+            # aliasing backends it is the staging copy itself)
+            self.last_h2d_done_ns = perf_counter_ns()
+            self._c_h2d.add(rows.nbytes)
+            try:
+                state, flat, summary = self._group_stepper(k, n_pad)(
+                    self.state, dev_rows, jnp.asarray(ns),
+                    jnp.asarray(tss),
+                )
+            except Exception:
+                # A broken/flaky (remote) compile must not take the server
+                # down: fall back to per-batch dispatch. But the stepper
+                # donates self.state — a RUNTIME failure after donation leaves
+                # deleted buffers, and no fallback is sound; re-raise then.
+                for buf in self.state.values():
+                    if getattr(buf, "is_deleted", lambda: False)():
+                        raise
+                self._group_disabled = True
+                return None
         slot["fence"] = flat  # this buffer is consumed once `flat` resolves
         self.state = state
         for _ts, arr in items:
@@ -2535,6 +2580,8 @@ class DeviceLedger(HostLedgerBase):
             except (AttributeError, RuntimeError):
                 pass
         self._xfer_used += total
+        # the kernel can start once the rows are on their way
+        self._note_launch(flat, self.last_h2d_done_ns, len(items), k)
         group = PendingGroup(flat, n_pad, k, summary=summary)
         return [
             PendingBatch(
@@ -2716,19 +2763,19 @@ class DeviceLedger(HostLedgerBase):
         if pending.group is not None:
             g = pending.group
             if g.summary is not None:
-                s = g.fetch_summary()  # [k counts..., fault]: a few words
+                s = g.fetch_summary(self._fetch)  # [k counts..., fault]
                 fault = int(s[-1])
                 if int(s[pending.group_idx]) == 0:
                     return self._drain_all_ok(pending, fault)
-            arr = g.fetch()  # one transfer for the whole group (cached)
+            arr = g.fetch(self._fetch)  # one transfer a group (cached)
             off = pending.group_idx * g.n_pad
             codes = arr[off : off + pending.n]
             return self._drain_from_host(pending, codes, int(arr[-1]))
         if pending.summary is not None:
-            s = np.asarray(pending.summary)  # [count, fault]
+            s = self._fetch(pending.summary)  # [count, fault]
             if int(s[0]) == 0:
                 return self._drain_all_ok(pending, int(s[1]))
-        arr = np.asarray(pending.results)  # one transfer: results + fault
+        arr = self._fetch(pending.results)  # one transfer: results + fault
         return self._drain_from_host(pending, arr[: pending.n], int(arr[-1]))
 
     def _drain_all_ok(self, pending: PendingBatch, fault: int) -> list[int]:

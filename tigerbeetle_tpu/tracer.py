@@ -16,6 +16,14 @@ Backends here:
   writes canonical JSON (sorted keys, fixed separators) — the same VOPR
   seed produces a byte-identical trace across runs, so two dumps can be
   diffed when a seed diverges.
+- `profiler` (ProfilerTracer; what `start` uses for the backends that
+  hold a chip when --trace is not given): each span is a
+  jax.profiler.TraceAnnotation named `tb.<name>`. With no profiler
+  session open that is a no-op inside the runtime; with one open
+  (`start --device-trace <dir>`, or any jax.profiler.start_trace in the
+  process) the spans land in the xplane's host plane ON THE DEVICE
+  TRACE'S OWN CLOCK, beside the kernels they launched — one timeline,
+  nothing to re-base afterwards.
 
 Spans nest; the commit path, message bus, journal, LSM, spill pipeline and
 the bench driver emit them. A JsonTracer constructed with `metrics=` also
@@ -158,6 +166,59 @@ class JsonTracer(Tracer):
         with open(path, "w") as f:
             json.dump({"traceEvents": self.events_ordered()}, f,
                       sort_keys=True, separators=(",", ":"))
+
+
+PROFILER_PREFIX = "tb."  # tells the program's spans from the runtime's
+
+
+class ProfilerTracer(Tracer):
+    """Spans as jax.profiler.TraceAnnotation events (`tb.<name>`, args
+    as the event's stats). Keeps no events of its own: the profiler
+    session that is open when a span closes owns it. `enabled` follows
+    the session, so call sites that compute trace ids only for a live
+    tracer pay nothing while no one is looking.
+
+    A reader that attributes a device idle gap to the host event
+    overlapping it most (benchmarks/harness/trace.py) is only as good as
+    the spans are PHASES: a span around a whole loop turn or a whole
+    thread would win every gap and name nothing."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._is_enabled = TraceAnnotation.is_enabled
+        # start()/stop() pairs (quorum waits, fuse holds, the spill
+        # prefetch worker) stay open across calls and threads; the token
+        # is the open annotation's id (dict get/set/pop are GIL-atomic)
+        self._open: dict = {}
+
+    @property
+    def enabled(self) -> bool:
+        return self._is_enabled()
+
+    def span(self, name: str, **args):
+        if not self._is_enabled():
+            return _NULL_SPAN
+        return self._annotation(PROFILER_PREFIX + name, **args)
+
+    def start(self, name: str, **args) -> int:
+        if not self._is_enabled():
+            return 0
+        a = self._annotation(PROFILER_PREFIX + name, **args)
+        a.__enter__()
+        self._open[id(a)] = a
+        return id(a)
+
+    def annotate(self, token: int, **args) -> None:
+        a = self._open.get(token)
+        if a is not None:
+            a.set_metadata(**args)
+
+    def stop(self, token: int) -> None:
+        a = self._open.pop(token, None)
+        if a is not None:
+            a.__exit__(None, None, None)
 
 
 class SimTracer(JsonTracer):
