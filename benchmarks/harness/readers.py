@@ -5,8 +5,8 @@ to read in this cell or this run: the harness leaves the metric out).
 ctx keys: cell, config, mix, kind ("sat" | "rate"), seconds, records (all),
 window (t0, t_end), stats0 / stats1 (the server's live [stats] snapshot at
 the window's ends, each with "t"), final (the [stats] line at SIGTERM),
-trace (the reduced profiler trace, see trace.py) and trace_span (the traced
-span's ends on the server's clock), device (the server's [device] line).
+trace (the reduced profiler trace, see trace.py) and trace_span (the stamps
+around the traced span on the server's clock), device (the server's [device] line).
 A quantity split by kind of cell (`<name>.sat`, `<name>.rate`) has one file
 and one reader here.
 """
@@ -146,10 +146,11 @@ def commit_kernels_roofline(ctx):
 
 
 def device_idle_share(ctx):
+    """1 - busy over the span the profiler collected: both from the trace."""
     trace = ctx.get("trace")
-    if not trace or not trace.get("window_s") or "busy_s" not in trace:
+    if not trace or not trace.get("collected_s") or "busy_s" not in trace:
         return None
-    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
+    return 100.0 * (1.0 - trace["busy_s"] / trace["collected_s"])
 
 
 def launches_per_batch(ctx):

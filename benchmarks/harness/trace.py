@@ -5,6 +5,14 @@ pinned to the CPU, so that the benchmark's own process stays off JAX. Prints one
 object. Checked on a small recorded trace in benchmarks/tests.
 
 Times are seconds; event times count from the start of the trace.
+
+The traced window is the span the profiler COLLECTED (`collected_s`: the
+earliest start to the latest end over every event of every plane), and a
+chip's busy time is a union of intervals inside it, so 0 < busy_s <=
+collected_s whatever the chip does. The stamps the control thread takes
+around start_trace/stop_trace are on another clock and around another span
+(the profiler goes on collecting a few ms after it is asked to stop): they
+only cross-check the collected span (`traced_window_s`).
 """
 
 from __future__ import annotations
@@ -17,6 +25,8 @@ import sys
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# a collected span this far (share) from the stamps' is a failed trace
+STAMPS_TOLERANCE = 0.05
 
 
 def union_s(intervals: list) -> tuple[float, list]:
@@ -52,16 +62,44 @@ def short_op(name: str) -> str:
     return f"{lhs} {max(shapes, key=elements)}" if shapes else lhs
 
 
+def plane_ends(planes: list) -> list:
+    """[plane, earliest start, latest end] of every plane that has events."""
+    out = []
+    for p in planes:
+        ev = [e for ln in p["lines"] for e in ln["events"]]
+        if ev:
+            out.append([p["name"], min(s for _n, s, _d in ev),
+                        max(s + d for _n, s, d in ev)])
+    return out
+
+
+def traced_window_s(reduced: dict, stamps_s: float) -> float:
+    """The traced window a run reports and divides by: the collected span.
+    The stamps only guard it: a profiler that dropped half its window must
+    not pass as a short one."""
+    collected = reduced["collected_s"]
+    if abs(collected - stamps_s) > STAMPS_TOLERANCE * stamps_s:
+        raise RuntimeError(
+            f"the profiler collected {collected:.6f} s where the stamps around "
+            f"it span {stamps_s:.6f} s: more than {STAMPS_TOLERANCE:.0%} apart; "
+            f"planes {reduced.get('plane_ends')}")
+    return collected
+
+
 def reduce_planes(planes: list, top: int = 10) -> dict:
     """planes: [{"name", "lines": [{"name", "events": [(name, start_s,
     dur_s), ...]}]}]. Device planes are the `/device:TPU:n` ones; a chip's
     busy time is the union of its `XLA Ops` intervals (or, where that line
-    is absent, of all its events)."""
+    is absent, of all its events). The collected span runs over the host
+    planes too: the program's `tb.*` spans are on them, so a chip that
+    idles at an edge of the span is idle inside it."""
     devices = [p for p in planes if p["name"].startswith("/device:TPU:")]
     hosts = [p for p in planes if p["name"].startswith("/host:")]
-    if not devices:
-        return {"error": "no /device:TPU plane in the trace",
+    ends = plane_ends(planes)
+    if not devices or not ends:
+        return {"error": "no /device:TPU plane, or no event, in the trace",
                 "planes": [p["name"] for p in planes]}
+    c0, c1 = min(a for _p, a, _b in ends), max(b for _p, _a, b in ends)
     busy, op_time, mod_time, mod_count = [], {}, {}, {}
     gaps: list = []
     first = last = None
@@ -104,8 +142,9 @@ def reduce_planes(planes: list, top: int = 10) -> dict:
     return {
         "devices": n,
         "busy_s": sum(busy) / n,
-        "span_s": (last - first) if busy and first is not None else 0.0,
-        "first_s": first, "last_s": last,
+        "collected_s": c1 - c0, "collected_first_s": c0, "collected_last_s": c1,
+        "first_s": first, "last_s": last,  # the devices' ops
+        "plane_ends": ends,
         "device_ops": ranked(op_time)[:top],
         "modules": [[k, v, mod_count[k] / n] for k, v in ranked(mod_time)],
         "launches": sum(mod_count.values()) / n,

@@ -41,7 +41,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-from benchmarks.harness import check, load, server as srv, traffic  # noqa: E402
+from benchmarks.harness import check, load, server as srv, trace, traffic  # noqa: E402
 from benchmarks.harness.named import named  # noqa: E402
 from benchmarks.harness.readers import percentile  # noqa: E402
 from benchmarks.reference.wire_types import Operation  # noqa: E402
@@ -238,10 +238,11 @@ def run_cell(args, rehearse: dict | None = None, fault=None):
             if not a or not a.get("ok"):
                 tracer["error"] = f"trace start: {a}"
                 return
-            # the traced window: from the moment the server was told to
-            # start to the moment it was told to stop (the server's
-            # monotonic clock, which is this machine's); the profiler
-            # collects a little less than that, never more
+            # the stamps: the moment the server was told to start and the
+            # moment it was told to stop (its monotonic clock, which is
+            # this machine's). They only cross-check the traced window,
+            # which is the span the profiler collected: it goes on
+            # collecting a few ms after it is told to stop
             span = {"t_a": a["asked_monotonic"]}
             time.sleep(span_s)
             b = server.trace("stop", timeout=240.0)
@@ -320,10 +321,17 @@ def run_cell(args, rehearse: dict | None = None, fault=None):
             if "error" in reduced and not rehearse:
                 raise RuntimeError(f"trace reduction: {reduced}")
             span = tracer["span"]
-            reduced["window_s"] = span["t_b"] - span["t_a"]
             ctx["trace"], ctx["trace_span"] = reduced, span
-            device_out["busy_s"] = reduced.get("busy_s")
-            device_out["window_s"] = reduced["window_s"]
+            if "error" not in reduced:
+                stamps_s = span["t_b"] - span["t_a"]
+                device_out["busy_s"] = reduced["busy_s"]
+                device_out["window_s"] = trace.traced_window_s(reduced, stamps_s)
+                log(f"traced window: the profiler collected {reduced['collected_s']:.6f}s "
+                    f"({reduced['collected_first_s']:.6f} .. {reduced['collected_last_s']:.6f}), "
+                    f"the stamps span {stamps_s:.6f}s, collected - stamps "
+                    f"{1e3 * (reduced['collected_s'] - stamps_s):+.3f} ms; busy "
+                    f"{reduced['busy_s']:.6f}s; planes (name, first, last event) "
+                    f"{json.dumps(reduced['plane_ends'])}")
             breakdown = {"device_ops": reduced.get("device_ops"),
                          "idle_gaps": reduced.get("idle_gaps")}
             log(f"trace: {reduced.get('xplane_bytes')} B reduced in "

@@ -5,6 +5,7 @@ take about half a minute each).
 
 import argparse
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -66,7 +67,7 @@ def test_trace_reduction_on_recorded_trace():
     red = trace.reduce_planes(recorded_planes())
     assert red["devices"] == 1
     assert red["busy_s"] == pytest.approx(0.022)
-    assert red["span_s"] == pytest.approx(0.026)
+    assert red["collected_s"] == pytest.approx(0.026)
     assert red["launches"] == 4
     assert red["device_ops"][0] == ["while.6", pytest.approx(0.012)]
     assert red["idle_gaps"][0][0] == "pjrt-tpu-tasks:XlaLinearize"
@@ -84,19 +85,20 @@ def test_trace_reduction_on_recorded_trace():
                transfer_slots_log2=24)
     records = [rec(operation=readers.CREATE, done=0.01 * (i + 1), events=8190,
                    phase="window", error=None) for i in range(2)]
-    red["window_s"] = 0.030
 
     def stats(**counters):
         return {"metrics": {"counters": counters}}
 
     ctx = {"trace": red, "records": records, "config": cfg, "kind": "rate",
-           "device": {"kind": "TPU v5 lite"}, "trace_span": {"t_a": 0.0, "t_b": 0.030},
+           "device": {"kind": "TPU v5 lite"}, "trace_span": {"t_a": 0.0, "t_b": 0.027},
            # the follower's applier: 7 batches in 7 solo launches
            "stats0": stats(**{"shadow.batches": 10, "shadow.groups": 1, "shadow.solo": 2}),
            "stats1": stats(**{"shadow.batches": 17, "shadow.groups": 1, "shadow.solo": 9})}
     assert readers.span_batches(ctx) == pytest.approx(2.0)
     assert readers.kernel_ms_per_batch(ctx) == pytest.approx(10.01)  # fold in, lookup out
-    assert readers.device_idle_share(ctx) == pytest.approx(100 * (1 - 0.022 / 0.030))
+    # the divisor is the reduction's own collected span, not the stamps'
+    assert trace.traced_window_s(red, 0.027) == red["collected_s"]
+    assert readers.device_idle_share(ctx) == pytest.approx(100 * (1 - 0.022 / 0.026))
     assert readers.launches_per_batch(ctx) == pytest.approx(1.5)  # 2 commits + 1 fold
     assert readers.fused_share(ctx) == pytest.approx(0.0)
     bytes_ = roofline.commit_bytes(2 * 8190, 10_000 / 2**20, 2 * 8190 / 2**24)
@@ -128,6 +130,64 @@ def test_trace_reduction_on_recorded_trace():
 
 def test_trace_without_a_device_plane_is_an_error():
     assert "error" in trace.reduce_planes(recorded_planes()[1:])
+
+
+def poll_line(first: float, last: float, every: float = 0.018) -> dict:
+    """The event loop's thread: a `tb.loop.poll` span every 18 ms."""
+    starts = [first + i * every for i in range(math.ceil((last - first) / every))]
+    return {"name": "python3", "events": [
+        ("tb.loop.poll", s, min(every, last - s)) for s in starts]}
+
+
+def test_a_chip_that_never_idles_is_busy_for_at_most_its_window():
+    """PR 27's case: ops back to back up to the last collected instant,
+    the host tracer and the stop stamp both short of it. The stamps' span
+    (here 2 ms shorter than what was collected) is not the divisor."""
+    ops = [("fusion.1", 0.045 + 0.001 * i, 0.001) for i in range(4055)]  # .. 4.100
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [("jit_step", 0.045, 4.055)]},
+            {"name": "XLA Ops", "events": ops}]},
+        {"name": "/host:CPU", "lines": [poll_line(0.046, 4.052)]},
+    ]
+    red = trace.reduce_planes(planes)
+    stamps_s = red["collected_s"] - 0.002
+    assert red["busy_s"] > stamps_s  # what the driver refused
+    window_s = trace.traced_window_s(red, stamps_s)
+    assert 0 < red["busy_s"] <= window_s
+    assert red["collected_first_s"] == pytest.approx(0.045)
+    assert red["collected_last_s"] == pytest.approx(4.100)
+    idle = readers.device_idle_share({"trace": red})
+    assert 0.0 <= idle < 1e-6
+
+
+def test_a_chip_idle_at_the_edges_of_the_span_is_idle_inside_it():
+    """The rate cell: the first kernel starts 0.1 s into the session and
+    the last ends 0.12 s before its end; the program's spans on the host
+    planes cover both edges, so the edge gaps count as idle."""
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": [
+                ("while.6", 0.150 + i / 6, 0.0628) for i in range(23)]}]},
+        {"name": "/host:CPU", "lines": [poll_line(0.050, 4.050)]},
+    ]
+    red = trace.reduce_planes(planes)
+    assert red["collected_s"] == pytest.approx(4.0)
+    assert red["last_s"] - red["first_s"] == pytest.approx(22 / 6 + 0.0628)
+    assert red["busy_s"] == pytest.approx(23 * 0.0628)
+    assert readers.device_idle_share({"trace": red}) == pytest.approx(
+        100 * (1 - 23 * 0.0628 / 4.0))
+    # the gaps between kernels are named as before; the edges are not gaps
+    assert red["idle_gap_total_s"] == pytest.approx(22 * (1 / 6 - 0.0628))
+
+
+def test_a_collected_span_far_from_the_stamps_is_a_failed_trace():
+    red = trace.reduce_planes(recorded_planes())  # collected 0.026 s
+    with pytest.raises(RuntimeError, match="collected"):
+        trace.traced_window_s(red, 0.026 / 0.9)  # 10 % short of the stamps
+    with pytest.raises(RuntimeError, match="collected"):
+        trace.traced_window_s(red, 0.026 / 1.1)  # or long
+    assert trace.traced_window_s(red, 0.026 / 0.97) == pytest.approx(0.026)
 
 
 def test_commit_bytes_against_a_hand_count():
