@@ -105,7 +105,7 @@ def _lower_single(name, a):
     if name == "group_stepper_16x8192":
         return ledger.DeviceLedger._group_stepper(stand_in, 16, N_PAD).fn.lower(
             a.state, a.sds((16, N_PAD, ledger.ROW_WORDS), jnp.uint32),
-            a.sds((16,), jnp.int32), a.sds((16,), jnp.uint64),
+            a.sds((16,), jnp.int32), a.sds((16,), jnp.uint64), a.n,
         )
     if name == "wave_stepper_2_fast":
         return ledger.DeviceLedger._wave_stepper(

@@ -8,9 +8,12 @@ ops/hashtable.py for the probe design and why u32 rows are the fast layout
 on TPU), and a whole prepare batch commits in one jitted step. Host batches
 upload as a single bitcast of the wire bytes.
 
-Two execution tiers, selected ON THE HOST before dispatch (the device
+Two execution tiers, selected ON THE HOST before dispatch (the commit
 kernels are straight-line programs — no lax.cond dispatch, no while_loops;
-see ops/hashtable.py for why data-dependent control flow is banned):
+see ops/hashtable.py for why data-dependent control flow is banned. The
+one loop whose trip count is not a constant sits AROUND the kernel: the
+group stepper runs it once per batch a launch carries, a count the host
+passes in — never a value read from the tables):
 
 - **Fast tier (vectorized)**: all lookups, validation, and application run
   data-parallel over the batch. Sound only when the batch is free of serial
@@ -2416,10 +2419,12 @@ class DeviceLedger(HostLedgerBase):
             )
         return results
 
-    # Fixed fused-group capacities: a lax.scan over K slots traces the
+    # Fixed fused-group capacities: a loop over the slots traces the
     # commit kernel ONCE regardless of K (an unrolled K multiplies the
-    # graph and has broken the remote compiler); smaller runs pad with
-    # zero-count slots. Two capacities bound the padded-upload waste.
+    # graph and has broken the remote compiler). A smaller run fills the
+    # first slots and the loop stops after them: an empty slot costs its
+    # share of the upload, nothing on the chip. Two capacities bound the
+    # padded-upload waste.
     GROUP_KS = (16, 4)
 
     def _group_staging_slot(self, k: int, n_pad: int) -> dict:
@@ -2450,10 +2455,14 @@ class DeviceLedger(HostLedgerBase):
         return slot
 
     def _group_stepper(self, k: int, n_pad: int):
-        """Jitted fused commit of k fast-tier batch slots in ONE launch
+        """Jitted fused commit of up to k fast-tier batches in ONE launch
         (group commit: the replica coalesces its pipeline the way the
-        flagship benchmark K-fuses device-generated batches). Returns
-        (state', flat results [k * n_pad + 1]; last word = fault)."""
+        flagship benchmark K-fuses device-generated batches). The loop
+        runs the `m` batches the group carries (slots 0..m-1), not the k
+        slots of its capacity: its trip count is an operand of the launch,
+        so one program serves every fill and an empty slot costs the chip
+        nothing. Returns (state', flat results [k * n_pad + 1]; last
+        word = fault, slots >= m all zero, summary [k + 1])."""
         cache = getattr(self.kernels, "_group_cache", None)
         if cache is None:
             cache = self.kernels._group_cache = {}
@@ -2461,21 +2470,33 @@ class DeviceLedger(HostLedgerBase):
         if fn is None:
             kernels = self.kernels
 
-            def step(state, rows, ns, tss):
-                def body(st, x):
-                    r, n, t = x
+            def step(state, rows, ns, tss, m):
+                lane = jnp.arange(n_pad, dtype=jnp.int32)
+
+                def body(i, carry):
+                    st, results, cnts = carry
+                    r, n, t = (
+                        jax.lax.dynamic_index_in_dim(x, i, keepdims=False)
+                        for x in (rows, ns, tss)
+                    )
                     st, res = kernels._commit_transfers(
                         st, {"rows": r}, n, t, mode="fast"
                     )
                     res = res.astype(jnp.uint32)
-                    lane = jnp.arange(res.shape[0], dtype=jnp.int32)
-                    cnt = jnp.sum(
-                        ((res != 0) & (lane < n)).astype(jnp.uint32)
+                    cnt = jnp.sum((res != 0) & (lane < n), dtype=U64)
+                    return (
+                        st,
+                        jax.lax.dynamic_update_index_in_dim(results, res, i, 0),
+                        jax.lax.dynamic_update_index_in_dim(cnts, cnt, i, 0),
                     )
-                    return st, (res, cnt)
 
-                state, (results, cnts) = jax.lax.scan(
-                    body, state, (rows, ns, tss)
+                state, results, cnts = jax.lax.fori_loop(
+                    0, m, body,
+                    (
+                        state,
+                        jnp.zeros((k, n_pad), dtype=jnp.uint32),
+                        jnp.zeros(k, dtype=U64),
+                    ),
                 )
                 fault = state["fault"].reshape(1).astype(jnp.uint32)
                 flat = jnp.concatenate([results.reshape(-1), fault])
@@ -2535,7 +2556,8 @@ class DeviceLedger(HostLedgerBase):
                 slot["fence"] = None
             rows = slot["rows"]
             used = slot["used"]
-            ns = np.zeros(k, dtype=np.int32)  # padding slots: n=0 -> no-ops
+            # batches fill slots 0..m-1; the stepper never runs the rest
+            ns = np.zeros(k, dtype=np.int32)
             tss = np.zeros(k, dtype=np.uint64)
             for i, (ts, arr) in enumerate(items):
                 na = len(arr)
@@ -2556,9 +2578,11 @@ class DeviceLedger(HostLedgerBase):
             self.last_h2d_done_ns = perf_counter_ns()
             self._c_h2d.add(rows.nbytes)
             try:
+                # the trip count is the batches carried, never `ns > 0`:
+                # an empty batch inside a group is a batch, not padding
                 state, flat, summary = self._group_stepper(k, n_pad)(
                     self.state, dev_rows, jnp.asarray(ns),
-                    jnp.asarray(tss),
+                    jnp.asarray(tss), np.int32(len(items)),
                 )
             except Exception:
                 # A broken/flaky (remote) compile must not take the server

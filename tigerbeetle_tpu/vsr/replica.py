@@ -1686,8 +1686,9 @@ class Replica:
                 self.latency.stamp(lt, LEG_QUORUM)
         self._maybe_commit_pipeline()
 
-    # Max prepares fused into one group commit (the ledger pads smaller
-    # runs into fixed-capacity scan kernels — see DeviceLedger.GROUP_KS).
+    # Max prepares fused into one group commit (the ledger packs smaller
+    # runs into the first slots of a fixed-capacity kernel that loops over
+    # the batches it carries — see DeviceLedger.GROUP_KS).
     GROUP_MAX = 16
 
     def _spill_prefetch_body(self, header: Header, body: bytes) -> None:
