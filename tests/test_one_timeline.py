@@ -460,7 +460,7 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     proc = subprocess.Popen(
         [sys.executable, "-m", "tigerbeetle_tpu", "start",
          "--addresses", f"127.0.0.1:{port}", "--backend", backend, *SMALL,
-         "--device-trace", trace_dir, "--device-trace-s", "2.0", path],
+         "--device-trace", trace_dir, "--device-trace-s", "600", path],
         cwd=REPO, env=env, start_new_session=True,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
     try:
@@ -471,8 +471,10 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
                                benchmark._accounts_body(1, 8))
         assert session.wait_reply()[1] == b""
         rng = np.random.default_rng(1)
-        # keep committing until well inside the window the first request
-        # opened (the profiler takes a moment to start)
+        # the window the first request opened stays open until SIGTERM
+        # closes it: a 2 s window could close behind the compiles the
+        # `device` backend runs behind its first requests (on a loaded
+        # machine they take longer than that), with no commit inside it
         t_end = time.monotonic() + 3.0
         sent = 0
         while time.monotonic() < t_end:
@@ -483,13 +485,14 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
             sent += 1
             time.sleep(0.05)
         session.bus.drop_connections()
-        # SIGTERM with the window possibly still open and launches in
-        # flight: the clock drains, the window closes, [stats] lands
+        # SIGTERM with the window still open and launches in flight:
+        # the clock drains, the window closes, [stats] lands
         proc.send_signal(signal.SIGTERM)
         out, _ = proc.communicate(timeout=300)
     finally:
         benchmark.kill_process_group(proc)
     assert proc.returncode == 0, out[-3000:]
+    assert "[device-trace] window written" in out, out[-3000:]
     stats = json.loads(next(
         ln for ln in out.splitlines() if ln.startswith("[stats] "))[8:])
     c = stats["metrics"]["counters"]
@@ -505,6 +508,7 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     names = {name for name, *_ in _tb_events(trace_dir)}
     assert "tb.replica.commit_dispatch" in names, names
     assert "tb.ledger.solo_launch" in names or "tb.ledger.group_launch" in names
+    assert "tb.ledger.plan" in names  # the planner, inside the launch span
     if backend == "dual":
         assert "tb.applier.wait_work" in names and "tb.shadow.upload" in names
     else:

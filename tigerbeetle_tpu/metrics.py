@@ -399,6 +399,10 @@ class FlightRecorder:
 
 # -- device time per commit launch --------------------------------------
 
+# the planner's tiers (models/ledger.py HazardTracker.plan): one counter a
+# tier in `ledger.tier.*` and in the launch clock's `device.tier_*`
+COMMIT_TIERS = ("fast", "fast_pv", "waves", "serial")
+
 
 class LaunchClock:
     """What a commit launch cost the chip, over the WHOLE run and without
@@ -412,7 +416,11 @@ class LaunchClock:
     launch's batches into `device.commit_batches_done` AT THE SAME
     INSTANT — so busy seconds over batches done is exact over any
     interval of the flight recorder, whichever side of its edge a launch
-    completes on. A launch dispatched behind a running one is booked from
+    completes on. A create_transfers launch is booked a second time under
+    the planner's tier it ran in (`device.tier_busy_s.<tier>` /
+    `device.tier_batches_done.<tier>`): the totals do not change, and
+    what a `fast_pv` batch costs the chip reads apart from a `fast` one.
+    A launch dispatched behind a running one is booked from
     the moment its predecessor finished: the time it spends waiting for
     its own upload counts as busy (the profiler's trace tells the two
     apart; this clock cannot).
@@ -426,6 +434,11 @@ class LaunchClock:
         self._c_busy = metrics.counter("device.commit_busy_s")
         self._c_done = metrics.counter("device.commit_batches_done")
         self._h_busy = metrics.histogram("device.launch_busy_us")
+        self._by_tier = {
+            tier: (metrics.counter(f"device.tier_busy_s.{tier}"),
+                   metrics.counter(f"device.tier_batches_done.{tier}"))
+            for tier in COMMIT_TIERS
+        }
         # the queue IS the cross-thread handoff
         self._q: queue.SimpleQueue = queue.SimpleQueue()  # vet: handoff
         self._thread = threading.Thread(
@@ -433,8 +446,9 @@ class LaunchClock:
         )
         self._thread.start()
 
-    def launched(self, handle, t_dispatch_ns: int, batches: int) -> None:
-        self._q.put((handle, t_dispatch_ns, batches))
+    def launched(self, handle, t_dispatch_ns: int, batches: int,
+                 tier: str | None = None) -> None:
+        self._q.put((handle, t_dispatch_ns, batches, tier))
 
     def _run(self) -> None:
         prev_ready = 0
@@ -442,7 +456,7 @@ class LaunchClock:
             item = self._q.get()
             if item is None:
                 return
-            handle, t_dispatch, batches = item
+            handle, t_dispatch, batches, tier = item
             try:
                 handle.block_until_ready()
             except Exception:
@@ -455,6 +469,10 @@ class LaunchClock:
             self._h_busy.observe(busy_ns / 1e3)
             self._c_busy.add(busy_ns / 1e9)
             self._c_done.add(batches)
+            if tier is not None:
+                c_busy, c_done = self._by_tier[tier]
+                c_busy.add(busy_ns / 1e9)
+                c_done.add(batches)
 
     def close(self, timeout: float = 60.0) -> bool:
         """Book every launch still in flight, then stop. False if the
@@ -658,6 +676,23 @@ CATALOG = {
     "shadow.drain_timeouts": ("counter", "", "applier drains that timed out (parity at risk)"),
     # device ledger
     "ledger.staging_wait_us": ("histogram", "us", "group staging double-buffer fence waits"),
+    # the planner, where it is called (models/ledger.py _solo_launch /
+    # try_execute_group_async)
+    "ledger.plan_us": (
+        "histogram", "us", "one plan + note_pending pair, or one fuse probe over a run"
+    ),
+    "ledger.plan_calls": (
+        "counter", "", "HazardTracker.plan calls, rolled-back fuse probes included"
+    ),
+    **{f"ledger.tier.{tier}": (
+        "counter", "", f"create_transfers batches launched in the {tier} tier"
+    ) for tier in COMMIT_TIERS},
+    "ledger.group_probe_rejected": (
+        "counter", "", "fuse attempts turned down because a batch did not plan fast"
+    ),
+    "ledger.pending_registry_rows": (
+        "gauge", "rows", "unresolved pending transfers the planner's registry holds"
+    ),
     # change-data-capture (tigerbeetle_tpu/cdc/pump.py)
     "cdc.ops": ("counter", "ops", "committed ops streamed (gap spans excluded)"),
     "cdc.records": ("counter", "records", "change records accepted by the sink"),
@@ -805,6 +840,12 @@ CATALOG = {
     "device.launch_busy_us": (
         "histogram", "us", "one launch: ready - max(dispatch, previous ready)"
     ),
+    **{f"device.tier_busy_s.{tier}": (
+        "counter", "s", f"the share of device.commit_busy_s booked to {tier} launches"
+    ) for tier in COMMIT_TIERS},
+    **{f"device.tier_batches_done.{tier}": (
+        "counter", "", f"batches of the {tier} launches booked into device.tier_busy_s"
+    ) for tier in COMMIT_TIERS},
     # time-series flight recorder (metrics.py FlightRecorder)
     "flight.records": ("counter", "", "flight-recorder snapshots taken"),
     "flight.marks": ("counter", "", "phase-marker transitions stamped (prodday `mark`)"),

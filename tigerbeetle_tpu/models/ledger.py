@@ -84,7 +84,7 @@ from tigerbeetle_tpu.constants import (
     ConfigProcess,
 )
 from tigerbeetle_tpu.lsm import groove as groove_fields
-from tigerbeetle_tpu.metrics import NULL_METRICS
+from tigerbeetle_tpu.metrics import COMMIT_TIERS, NULL_METRICS
 from tigerbeetle_tpu.models import validate
 from tigerbeetle_tpu.models.validate import (
     F_BAL_CR,
@@ -2126,15 +2126,30 @@ class DeviceLedger(HostLedgerBase):
         self._c_batches = metrics.counter("device.commit_batches")
         self._c_slots = metrics.counter("device.commit_slots")
         self._c_fetch = metrics.counter("loop.fetch_s")
+        # the planner: every HazardTracker.plan call (a fuse probe that is
+        # rolled back and the solo path's second call count alike), the
+        # tier of each create_transfers batch that is LAUNCHED, and the
+        # pending registry the planner keeps
+        self._c_plan_calls = metrics.counter("ledger.plan_calls")
+        self._h_plan = metrics.histogram("ledger.plan_us")
+        self._c_tier = {
+            tier: metrics.counter(f"ledger.tier.{tier}") for tier in COMMIT_TIERS
+        }
+        self._c_probe_rejected = metrics.counter("ledger.group_probe_rejected")
+        self._g_registry = metrics.gauge("ledger.pending_registry_rows")
 
     def _note_launch(self, handle, t_launch_ns: int, batches: int,
-                     slots: int) -> None:
+                     slots: int, tier: str | None = None) -> None:
+        """`tier`: the planner's decision for a create_transfers launch
+        (a fused group is `fast` by construction); None for accounts."""
         self._c_launches.add()
         self._c_batches.add(batches)
         self._c_slots.add(slots)
+        if tier is not None:
+            self._c_tier[tier].add(batches)
         clock = self.launch_clock
         if clock is not None:
-            clock.launched(handle, t_launch_ns, batches)
+            clock.launched(handle, t_launch_ns, batches, tier)
 
     def _fetch(self, dev) -> np.ndarray:
         """The blocking device->host read of a commit's reply words: the
@@ -2237,6 +2252,7 @@ class DeviceLedger(HostLedgerBase):
         assert n <= n_pad
         ts = jnp.uint64(timestamp)
         nn = jnp.int32(n)
+        decision = None  # the planner's tier: create_transfers only
         if operation == Operation.create_transfers:
             arr = events if isinstance(events, np.ndarray) else types.transfers_to_np(events)
             if self.spill is not None:
@@ -2249,11 +2265,19 @@ class DeviceLedger(HostLedgerBase):
                     f"({self._xfer_used}+{n} > {self._xfer_limit}): "
                     "grow ConfigProcess.transfer_slots_log2"
                 )
-            if self.mode == "auto":
-                decision, wave_plan = self.hazards.plan(arr)
-            else:  # forced tier (parity tests); the amount bound is unused
-                decision, wave_plan = self.mode, None
-            self.hazards.note_pending(arr)
+            with self._h_plan.time():
+                tok = self.tracer.start("ledger.plan", batches=1)
+                try:
+                    if self.mode == "auto":
+                        decision, wave_plan = self.hazards.plan(arr)
+                        self._c_plan_calls.add()
+                    else:  # forced tier (parity tests); the amount bound is unused
+                        decision, wave_plan = self.mode, None
+                    self.hazards.note_pending(arr)
+                    self.tracer.annotate(tok, tier=decision)
+                finally:
+                    self.tracer.stop(tok)
+            self._g_registry.set(len(self.hazards.pending_accounts))
             if decision == "waves":
                 t_launch = perf_counter_ns()  # the waves upload their own rows
                 results = self._execute_waves(
@@ -2303,7 +2327,7 @@ class DeviceLedger(HostLedgerBase):
                 summary.copy_to_host_async()
             except (AttributeError, RuntimeError):
                 pass  # no async copy: drain pays the sync cost
-        self._note_launch(results, t_launch, 1, 1)
+        self._note_launch(results, t_launch, 1, 1, decision)
         return PendingBatch(
             operation, n, results, flags=arr["flags"].copy(),
             epoch=self._occupancy_epoch, summary=summary, plan=plan_info,
@@ -2532,10 +2556,14 @@ class DeviceLedger(HostLedgerBase):
         # double-counts toward the 2^127 serial cutoff.
         sum_before = self.hazards.amount_sum
         stats_before = dict(self.hazards.plan_stats)
-        decisions = [self.hazards.plan(arr) for _, arr in items]
+        with self.tracer.span("ledger.plan", batches=len(items), tier="probe"), \
+                self._h_plan.time():
+            decisions = [self.hazards.plan(arr) for _, arr in items]
+        self._c_plan_calls.add(len(items))
         if any(d != "fast" for d, _plan in decisions):
             self.hazards.amount_sum = sum_before
             self.hazards.plan_stats = stats_before
+            self._c_probe_rejected.add()
             return None
         k = next(g for g in reversed(self.GROUP_KS) if g >= len(items))
         with self.tracer.span("ledger.group_launch", slots=k,
@@ -2596,8 +2624,12 @@ class DeviceLedger(HostLedgerBase):
                 return None
         slot["fence"] = flat  # this buffer is consumed once `flat` resolves
         self.state = state
-        for _ts, arr in items:
-            self.hazards.note_pending(arr)
+        # the registry half of the group's planning, booked with the probe
+        with self.tracer.span("ledger.plan", batches=len(items),
+                              tier="fast"), self._h_plan.time():
+            for _ts, arr in items:
+                self.hazards.note_pending(arr)
+        self._g_registry.set(len(self.hazards.pending_accounts))
         if self.prefetch_results:
             try:
                 summary.copy_to_host_async()
@@ -2605,7 +2637,7 @@ class DeviceLedger(HostLedgerBase):
                 pass
         self._xfer_used += total
         # the kernel can start once the rows are on their way
-        self._note_launch(flat, self.last_h2d_done_ns, len(items), k)
+        self._note_launch(flat, self.last_h2d_done_ns, len(items), k, "fast")
         group = PendingGroup(flat, n_pad, k, summary=summary)
         return [
             PendingBatch(
