@@ -15,7 +15,12 @@ Contracts under test:
   the pending registry's gauge returns to 32 batches' worth;
 - the schedule: 32 pending requests, then alternating; from request 97 on
   a post resolves the batch 65 requests back; nothing is posted twice;
-- the algorithm's bytes of a pending and of a post batch, by hand.
+- the algorithm's bytes of a pending and of a post batch, by hand;
+- (PR 31) the `fast_pv` program looks each lane's EFFECTIVE accounts up
+  once (the pending's for a post or void, the event's own elsewhere): posts
+  that name their pending's accounts, posts that name others (codes 27 / 28,
+  nothing moves), plain and post lanes in one launch; and the probes of
+  both programs, counted while they are traced.
 """
 
 import argparse
@@ -39,6 +44,7 @@ BATCH = 64
 CONFIG = {"batch_events": BATCH, "accounts": 300, "id_order": "reversed"}
 FP_FIELDS = ("accounts_fp", "transfers_fp", "accounts", "transfers",
              "commit_timestamp")
+PV = int(TF.post_pending_transfer | TF.void_pending_transfer)
 
 
 def twophase_mix(**post) -> dict:
@@ -191,9 +197,90 @@ def case_expired():
         34: [0] * BATCH, 35: [int(R.pending_transfer_expired)] * BATCH}
 
 
+def other_account(ids: np.ndarray) -> np.ndarray:
+    """Another plain account than `ids` (1..299; 300 is the limit account)."""
+    return ids % np.uint64(CONFIG["accounts"] - 1) + np.uint64(1)
+
+
+def case_posts_name_their_pendings_accounts():
+    """Every post carries debit and credit account ids of its own, equal to
+    its pending transfer's: codes 27 / 28 compare ids and pass."""
+    arrays = take(traffic.Stream(twophase_mix(), CONFIG, 2**31 + 35), 60)
+    for k in range(33, 60, 2):
+        pending = arrays[(k - 33) // 2]  # the oldest unresolved batch
+        assert (arrays[k]["pending_id_lo"] == pending["id_lo"]).all()
+        for f in ("debit_account_id_lo", "credit_account_id_lo"):
+            arrays[k][f] = pending[f]
+    return arrays, None, {k: [0] * BATCH for k in range(33, 60, 2)}
+
+
+def case_posts_name_other_accounts():
+    """Posts whose own account ids differ from the pending's: by lane, a
+    wrong debit (27), a wrong credit (28), both wrong (27 comes first), both
+    right (0); in the next post an account that does not exist (27 still:
+    ids are compared, no row is read). A failed post moves no balance, of
+    the pending's accounts or of the ones it names."""
+    arrays = take(traffic.Stream(twophase_mix(), CONFIG, 36), 40)
+    post, pending = arrays[33], arrays[0]
+    lane = np.arange(BATCH)
+    dr, cr = pending["debit_account_id_lo"], pending["credit_account_id_lo"]
+    post["debit_account_id_lo"] = np.where(
+        np.isin(lane % 4, (0, 2)), other_account(dr), dr)
+    post["credit_account_id_lo"] = np.where(
+        np.isin(lane % 4, (1, 2)), other_account(cr), cr)
+    arrays[35]["debit_account_id_lo"][::2] = 10**9
+    d, c = (int(R.pending_transfer_has_different_debit_account_id),
+            int(R.pending_transfer_has_different_credit_account_id))
+    assert (d, c) == (27, 28)
+    return arrays, None, {33: [d, c, d, 0] * (BATCH // 4),
+                          35: [d, 0] * (BATCH // 2), 37: [0] * BATCH}
+
+
+def case_plain_and_posts_share_a_launch():
+    """Batches that mix plain (or pending) creates with posts of
+    registry-known pendings, lane by lane: one `fast_pv` launch in which a
+    plain lane's account keys are its own and a post lane's its pending's,
+    and each changes only those."""
+    st = traffic.Stream(twophase_mix(), CONFIG, 2**31 + 37)
+    arrays = take(st, 40)
+    even = np.arange(BATCH) % 2 == 0
+    # request 33: the posts of batch 0's even lanes between plain creates
+    mixed = st._plain(BATCH, 55_000_000)
+    mixed[even] = arrays[33][even]
+    arrays[33] = mixed
+    # after the stream: the posts of batch 0's odd lanes between pending
+    # creates, over the accounts the even lanes' pendings hold
+    later = st._plain(BATCH, 56_000_000)
+    later["flags"] = int(TF.pending)
+    later["debit_account_id_lo"] = arrays[0]["debit_account_id_lo"][::-1]
+    later["credit_account_id_lo"] = arrays[0]["credit_account_id_lo"][::-1]
+    posts = post_of(arrays[0], 56_100_000)
+    posts["credit_account_id_lo"] = arrays[0]["credit_account_id_lo"]
+    later[~even] = posts[~even]
+    return arrays + [later], None, {33: [0] * BATCH, 40: [0] * BATCH}
+
+
+def still_pending(arrays: list, dense: list) -> int:
+    """What the result codes say is still pending: every pending create that
+    succeeded, less every one a successful post or void resolved."""
+    amount, total = {}, 0
+    for arr, codes in zip(arrays, dense):
+        for ev, code in zip(arr, codes):
+            if code:
+                continue
+            if int(ev["flags"]) & int(TF.pending):
+                amount[int(ev["id_lo"])] = int(ev["amount_lo"])
+                total += int(ev["amount_lo"])
+            elif int(ev["flags"]) & PV:
+                total -= amount.pop(int(ev["pending_id_lo"]))
+    return total
+
+
 @pytest.mark.parametrize("case", [
     case_cell, case_voids, case_posted_twice, case_post_before_pending,
-    case_expired], ids=lambda f: f.__name__[5:])
+    case_expired, case_posts_name_their_pendings_accounts,
+    case_posts_name_other_accounts, case_plain_and_posts_share_a_launch],
+    ids=lambda f: f.__name__[5:])
 def test_twophase_stream_equals_the_reference(case):
     arrays, gap_ns, expected = case()
     led, m, ref, dense = run_both(arrays, gap_ns)
@@ -206,6 +293,15 @@ def test_twophase_stream_equals_the_reference(case):
     assert total["debits_posted"] == total["credits_posted"]
     c = m.snapshot()["counters"]
     assert sum(c[f"ledger.tier.{t}"] for t in COMMIT_TIERS) == len(arrays)
+    if case in (case_posts_name_their_pendings_accounts,
+                case_posts_name_other_accounts,
+                case_plain_and_posts_share_a_launch):
+        # a lane that failed moved nothing, and every batch that holds a
+        # post or void lane, mixed or not, was ONE launch of `fast_pv`
+        assert total["debits_pending"] == still_pending(arrays, dense)
+        n_pv = sum(1 for a in arrays if (a["flags"] & np.uint16(PV)).any())
+        assert c["ledger.tier.fast_pv"] == n_pv
+        assert c["ledger.tier.fast"] == len(arrays) - n_pv
     if case in (case_cell, case_voids, case_posted_twice):
         # what is still pending is what no post or void has resolved, once:
         # the second resolution of batch 0 moved nothing
@@ -250,6 +346,47 @@ def test_a_rejected_probe_counts_calls_but_no_tier():
     assert c["ledger.plan_calls"] == 2 + 1 + 1
     assert (c["ledger.tier.fast"], c["ledger.tier.fast_pv"]) == (1, 1)
     assert m.snapshot()["gauges"]["ledger.pending_registry_rows"] == 0
+
+
+# -- the probes of a program, counted while it is traced ------------------
+
+
+A_LOG2, T_LOG2 = 10, 14  # the account and the transfer table of the probe test
+
+
+@pytest.mark.parametrize("mode,probes", [
+    ("fast", [(A_LOG2, 2 * BATCH), (T_LOG2, BATCH)]),
+    # the pending row, each lane's effective accounts ONCE, the transfer id
+    ("fast_pv", [(T_LOG2, BATCH), (A_LOG2, 2 * BATCH), (T_LOG2, BATCH)]),
+])
+def test_commit_program_probes_the_account_table_once(monkeypatch, mode, probes):
+    """A window lookup is the chip's time in this kernel (PERF.md section
+    5), so the count is held by tracing, not by timing: (table, lanes) of
+    every `ht.lookup`, debit and credit sides in one 2B-lane call."""
+    import jax
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.constants import ConfigProcess
+    from tigerbeetle_tpu.models import ledger
+
+    process = ConfigProcess(account_slots_log2=A_LOG2, transfer_slots_log2=T_LOG2)
+    calls = []
+    lookup = ledger.ht.lookup
+
+    def counted(key4, rows, cap_log2, *a, **kw):
+        assert rows.shape[0] == (1 << cap_log2) + 1
+        calls.append((cap_log2, key4.shape[0]))
+        return lookup(key4, rows, cap_log2, *a, **kw)
+
+    monkeypatch.setattr(ledger.ht, "lookup", counted)
+    sds = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda st, rows, n, ts: ledger.LedgerKernels(process)._commit_transfers(
+            st, {"rows": rows}, n, ts, mode=mode),
+        jax.eval_shape(lambda: ledger.init_state(process)),
+        sds((BATCH, ledger.ROW_WORDS), jnp.uint32), sds((), jnp.int32),
+        sds((), jnp.uint64))
+    assert calls == probes
 
 
 # -- the schedule ---------------------------------------------------------

@@ -836,7 +836,18 @@ class LedgerKernels:
         additionally carries fast-eligible post/void events (distinct,
         registry-known pendings, or waves ordered after their in-batch
         creators — see HazardTracker.plan), "serial" for the exact
-        scan."""
+        scan.
+
+        What is probed: "fast" looks up each lane's debit and credit account
+        (one 2B-lane window lookup) and its transfer id. "fast_pv" first
+        looks up each lane's pending_id in the transfer table, then makes the
+        SAME one account lookup over each lane's EFFECTIVE accounts — the
+        pending's where the lane is a post/void, the event's own elsewhere —
+        then the transfer id. A post/void lane never reads its event's own
+        accounts: validate.validate_post_void takes no account row ("the
+        pending transfer's accounts are not validated — only mutated on
+        apply, exactly as the reference"), its codes 27/28 compare ids, and
+        the balances it moves are the pending's accounts'."""
         if mode == "serial":
             return self._serial_transfers(state, ev, n, timestamp)
         assert mode in ("fast", "fast_pv"), mode
@@ -854,8 +865,32 @@ class LedgerKernels:
 
         acct_rows = state["acct_rows"]
         xfer_rows = state["xfer_rows"]
+        dr_k4, cr_k4 = rows_b[:, 4:8], rows_b[:, 8:12]
+        if pv_mode:
+            # pending-transfer wave: probe the p row (+ its fulfill column)
+            # first, because a post/void lane changes p's accounts and never
+            # reads its own (validate_post_void's docstring): its account
+            # keys below are p's ids. A lane whose p is not found reads the
+            # row at p's insert target (empty or tombstone ids: never found,
+            # code 25, no write).
+            is_pv = (e["flags"] & jnp.uint32(F_POST | F_VOID)) != 0
+            p_slot, p_found, p_res = ht.lookup(
+                rows_b[:, 16:20], xfer_rows, self.t_log2
+            )
+            p = unpack_transfer(xfer_rows[p_slot])
+            p["fulfill"] = state["fulfill"][p_slot]
+            dr_k4 = jnp.where(
+                is_pv[:, None],
+                key4_from_fields({"id_lo": p["dr_lo"], "id_hi": p["dr_hi"]}),
+                dr_k4,
+            )
+            cr_k4 = jnp.where(
+                is_pv[:, None],
+                key4_from_fields({"id_lo": p["cr_lo"], "id_hi": p["cr_hi"]}),
+                cr_k4,
+            )
         # dr and cr probe the same table: fuse into one 2B-lane lookup.
-        both_k4 = jnp.concatenate([rows_b[:, 4:8], rows_b[:, 8:12]], axis=0)
+        both_k4 = jnp.concatenate([dr_k4, cr_k4], axis=0)
         both_slot, both_found, both_res = ht.lookup(both_k4, acct_rows, self.a_log2)
         both_rows = acct_rows[both_slot]
         dr_slot, cr_slot = both_slot[:B], both_slot[B:]
@@ -878,23 +913,6 @@ class LedgerKernels:
         probe_bad = jnp.any(valid2 & ~both_res) | jnp.any(valid & ~ex_res)
 
         if pv_mode:
-            # pending-transfer wave: p row + fulfill, then p's accounts
-            is_pv = (e["flags"] & jnp.uint32(F_POST | F_VOID)) != 0
-            p_slot, p_found, p_res = ht.lookup(
-                rows_b[:, 16:20], xfer_rows, self.t_log2
-            )
-            p = unpack_transfer(xfer_rows[p_slot])
-            p["fulfill"] = state["fulfill"][p_slot]
-            p_both_k4 = jnp.concatenate([
-                key4_from_fields({"id_lo": p["dr_lo"], "id_hi": p["dr_hi"]}),
-                key4_from_fields({"id_lo": p["cr_lo"], "id_hi": p["cr_hi"]}),
-            ], axis=0)
-            pb_slot, pb_found, pb_res = ht.lookup(
-                p_both_k4, acct_rows, self.a_log2
-            )
-            pb_rows = acct_rows[pb_slot]
-            pdr_slot, pcr_slot = pb_slot[:B], pb_slot[B:]
-            pdr_row, pcr_row = pb_rows[:B], pb_rows[B:]
             r_pv, amt_pv_lo, amt_pv_hi = validate.validate_post_void(
                 r0, e_a, p, p_found, ex, ex_found
             )
@@ -902,11 +920,7 @@ class LedgerKernels:
             amt_lo = jnp.where(is_pv, amt_pv_lo, amt_lo)
             amt_hi = jnp.where(is_pv, amt_pv_hi, amt_hi)
             pvv = valid & is_pv
-            probe_bad = (
-                probe_bad
-                | jnp.any(pvv & ~p_res)
-                | jnp.any(jnp.concatenate([pvv, pvv]) & ~pb_res)
-            )
+            probe_bad = probe_bad | jnp.any(pvv & ~p_res)
         else:
             is_pv = jnp.zeros(B, dtype=bool)
 
@@ -938,25 +952,19 @@ class LedgerKernels:
                 jnp.where(is_pv[:, None], neg_p, zeros8)
             post8 = jnp.where((simple & ~pending)[:, None], digits, zeros8) + \
                 jnp.where(is_post[:, None], digits, zeros8)
-            dr_slot_eff = jnp.where(is_pv, pdr_slot, dr_slot)
-            cr_slot_eff = jnp.where(is_pv, pcr_slot, cr_slot)
-            dr_row_eff = jnp.where(is_pv[:, None], pdr_row, dr_row)
-            cr_row_eff = jnp.where(is_pv[:, None], pcr_row, cr_row)
         else:
             pend8 = jnp.where(pending[:, None], digits, zeros8)
             post8 = jnp.where(pending[:, None], zeros8, digits)
-            dr_slot_eff, cr_slot_eff = dr_slot, cr_slot
-            dr_row_eff, cr_row_eff = dr_row, cr_row
         upd_dr = jnp.concatenate([pend8, post8, zeros8, zeros8], axis=-1)  # [B,32]
         upd_cr = jnp.concatenate([zeros8, zeros8, pend8, post8], axis=-1)
         slots_t = jnp.concatenate([
-            jnp.where(ok, dr_slot_eff, self.a_dump),
-            jnp.where(ok, cr_slot_eff, self.a_dump),
+            jnp.where(ok, dr_slot, self.a_dump),
+            jnp.where(ok, cr_slot, self.a_dump),
         ])
         upd = jnp.concatenate([upd_dr, upd_cr], axis=0)  # [2B, 32]
         acc = state["bal_acc"].at[slots_t].add(upd)
         acc_t = acc[slots_t]  # [2B, 32]
-        old_rows_t = jnp.concatenate([dr_row_eff, cr_row_eff], axis=0)
+        old_rows_t = jnp.concatenate([dr_row, cr_row], axis=0)
         if pv_mode:
             new_rows_t, over_t = _fold_digits_signed(old_rows_t, acc_t)
         else:
