@@ -36,7 +36,7 @@ def main() -> int:
     ap.add_argument("--batches-per-session", type=int, default=6)
     ap.add_argument("--replicas", type=int, default=3)
     ap.add_argument("--backend", default="native",
-                    help="native | dual | native+device | device")
+                    help="native | dual | device")
     ap.add_argument("--faults", default="kill_primary",
                     help="comma list of " + "|".join(CHAOS_ACTIONS))
     ap.add_argument("--restart-after", type=float, default=2.0,

@@ -72,7 +72,7 @@ def main() -> int:
     live.add_argument("--restart-after", type=float, default=1.5,
                       metavar="S", help="kill -> respawn delay")
     live.add_argument("--backend", default="native",
-                      help="native | dual | native+device | device")
+                      help="native | dual | device")
     live.add_argument("--deadline", type=float, default=600.0,
                       metavar="S")
     live.add_argument("--jax-platform", default="cpu",

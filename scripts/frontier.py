@@ -1,16 +1,11 @@
 """Standalone load/latency frontier sweep driver.
 
-Runs ONLY the frontier segment (benchmark.run_frontier) against a fresh
-live server and writes the JSON segment — the quick loop for ROADMAP
-item 4 work, without paying for the full bench.py run:
+Runs benchmark.run_frontier against a fresh live server and writes its
+JSON report:
 
   python scripts/frontier.py out.json
   python scripts/frontier.py --steps 50000,100000,200000 \
       --backend dual --step-s 8 out.json
-
-The segment shape matches bench.py's `frontier` detail section, so a
-sweep captured here can be compared against (or spliced into) a driver
-artifact directly.
 """
 
 import argparse
@@ -30,7 +25,7 @@ def main() -> int:
     p.add_argument("--batch", type=int, default=2048)
     p.add_argument("--sessions", type=int, default=32)
     p.add_argument("--backend", default="dual",
-                   help="server backend (dual | native | native+device)")
+                   help="server backend (dual | native)")
     p.add_argument("--sample-every", type=int, default=1,
                    help="server-side latency sampling (1 = every request)")
     p.add_argument("--jax-platform", default="",

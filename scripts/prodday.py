@@ -576,7 +576,7 @@ def run_prodday(
 
         cdc = _parse_cdc_stream(cdc_path)
         parity_ok = True
-        if backend in ("dual", "native+device"):
+        if backend == "dual":
             parity_ok = all(
                 v["verified"] and v["hash_log_ok"] is not False
                 for v in parity.values()

@@ -128,7 +128,7 @@ def test_async_driver_two_phase_smoke():
 
     out = run_e2e(
         n_accounts=200, n_transfers=64 * 6, batch=64, clients=3,
-        warmup_batches=1, jax_platform="cpu", backend="native+device",
+        warmup_batches=1, jax_platform="cpu", backend="dual",
         driver="async", workload="two_phase",
     )
     assert out["durable_tps"] > 0

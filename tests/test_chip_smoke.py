@@ -272,29 +272,3 @@ def test_wait_listening_explains_a_server_that_never_listens(
     assert why in msg and "[boot]" in msg
     if why == "died before":
         assert "exit code 3" in msg and "disk on fire" in msg
-
-
-# -- bench.py: a failed segment is an exit code ---------------------------
-
-def test_bench_records_failed_segments_for_its_exit_code(monkeypatch, capsys):
-    """A segment that raises still returns its error dict (the JSON line
-    prints) but lands in SEGMENT_ERRORS, which main() turns into exit 1."""
-    import contextlib
-    import inspect
-
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    monkeypatch.setattr(bench, "SEGMENT_ERRORS", [])
-
-    def boom(**_kw):
-        raise ValueError("boom")
-
-    monkeypatch.setattr(benchmark, "run_ingress_sessions", boom)
-    out = bench.bench_ingress(lambda _name: contextlib.nullcontext())
-    assert out == {"error": "ValueError: boom"}
-    assert bench.SEGMENT_ERRORS == ["ingress: ValueError: boom"]
-    assert "[ingress] FAILED" in capsys.readouterr().err
-    assert "return 1 if SEGMENT_ERRORS else 0" in inspect.getsource(bench.main)

@@ -30,7 +30,6 @@ from tigerbeetle_tpu.latency import (
     DLEG_COALESCE,
     DLEG_DISPATCH,
     DLEG_H2D,
-    NULL_DEVICE_ANATOMY,
     DeviceAnatomy,
     device_leg_totals,
     dominant_leg,
@@ -102,13 +101,6 @@ def test_device_anatomy_eviction_and_discard_leak_free():
     assert 7 not in a._recs
     a.finish(6)
     assert a.slowest()  # the survivor folded
-
-
-def test_null_device_anatomy_is_inert():
-    assert NULL_DEVICE_ANATOMY.open(5, t_enq=1) == 0
-    NULL_DEVICE_ANATOMY.stamp(5, DLEG_BUSY)
-    NULL_DEVICE_ANATOMY.finish(5)
-    assert NULL_DEVICE_ANATOMY.slowest() == []
 
 
 def test_device_metric_names_cataloged():
@@ -230,7 +222,7 @@ def test_follower_stall_names_queue_wait_dominant_and_watch_columns():
     from tigerbeetle_tpu.metrics import FlightRecorder
     from tigerbeetle_tpu.models.dual_ledger import DualLedger
 
-    led = DualLedger(12, 14, follower=True)
+    led = DualLedger(12, 14)
     led.instrument(Metrics(), NULL_TRACER)
     # warm round on a throwaway registry: the solo-apply kernels compile
     # here, so the stall round below measures a WARM applier (a cold
@@ -277,7 +269,7 @@ def test_follower_partition_exactness_all_sampled_no_stall():
     from tigerbeetle_tpu.models.dual_ledger import DualLedger
 
     m = Metrics()
-    led = DualLedger(12, 14, follower=True)
+    led = DualLedger(12, 14)
     led.instrument(m, NULL_TRACER)
     acc = np.zeros(16, dtype=types.ACCOUNT_DTYPE)
     acc["id_lo"] = np.arange(1, 17, dtype=np.uint64)
@@ -311,7 +303,7 @@ def test_same_seed_follower_device_digests_identical_with_stamping():
 
     digests = []
     for _run in range(2):
-        led = DualLedger(12, 14, follower=True)
+        led = DualLedger(12, 14)
         led.instrument(Metrics(), NULL_TRACER)
         acc = np.zeros(16, dtype=types.ACCOUNT_DTYPE)
         acc["id_lo"] = np.arange(1, 17, dtype=np.uint64)

@@ -6,8 +6,7 @@ the typed-shed rate, and a dominant-leg attribution sourced from the
 server's per-request latency anatomy over the wire — and the slowest
 sampled request's breakdown must ACCOUNT for its end-to-end latency
 (legs are consecutive stamp intervals; the acceptance bound is 20%).
-The full ladder (`--backend dual`, 4+ steps) runs in bench.py's
-frontier segment / scripts/frontier.py.
+The full ladder (`--backend dual`, 4+ steps) is scripts/frontier.py.
 """
 
 import tests.conftest  # noqa: F401 — CPU platform before jax init

@@ -152,8 +152,7 @@ def test_native_reply_encoding_matches_state_machine():
 def test_native_throughput_sanity():
     """Sanity floor, not a benchmark: the engine must stay orders of
     magnitude above the Python oracle (~50k TPS). The threshold is set
-    far below the measured ~2.8M TPS so loaded/slow CI hosts stay green;
-    bench.py reports the real number."""
+    far below the measured ~2.8M TPS so loaded/slow CI hosts stay green."""
     import time
 
     nat = NativeLedger(16, 22)

@@ -227,7 +227,7 @@ def _drive_device(metrics) -> _LaunchSpy:
 def _drive_dual(metrics) -> _LaunchSpy:
     from tigerbeetle_tpu.models.dual_ledger import DualLedger
 
-    led = DualLedger(12, 14, follower=True)
+    led = DualLedger(12, 14)
     led.instrument(metrics, NULL_TRACER)
     spy = _LaunchSpy(led.device)
     op = [0]
@@ -537,3 +537,38 @@ def test_new_metric_names_are_cataloged(name, kind, unit):
 
 def test_the_bridge_counter_left_the_catalog():
     assert "device.trace_windows" not in CATALOG
+
+
+# -- (f) the yardstick's divisor over such a trace ----------------------
+
+
+def test_a_chip_that_never_idles_is_busy_for_at_most_its_window():
+    """The case of benchmarks/tests/test_yardstick.py (not tier-1), held
+    here too: ops back to back up to the last collected instant, the host
+    tracer and the stop stamp both short of it. The divisor is the span
+    the profiler collected, not the stamps' (here 2 ms shorter): busy_s
+    over the stamps' span read more than 100 %, which the driver refuses."""
+    import math
+
+    from benchmarks.harness import readers, trace
+
+    ops = [("fusion.1", 0.045 + 0.001 * i, 0.001) for i in range(4055)]
+    every = 0.018  # the event loop's thread: a `tb.loop.poll` span each
+    polls = [0.046 + i * every
+             for i in range(math.ceil((4.052 - 0.046) / every))]
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [("jit_step", 0.045, 4.055)]},
+            {"name": "XLA Ops", "events": ops}]},
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+            ("tb.loop.poll", s, min(every, 4.052 - s)) for s in polls]}]},
+    ]
+    red = trace.reduce_planes(planes)
+    stamps_s = red["collected_s"] - 0.002
+    assert red["busy_s"] > stamps_s
+    window_s = trace.traced_window_s(red, stamps_s)
+    assert 0 < red["busy_s"] <= window_s
+    assert red["collected_first_s"] == pytest.approx(0.045)
+    assert red["collected_last_s"] == pytest.approx(4.100)
+    idle = readers.device_idle_share({"trace": red})
+    assert 0.0 <= idle < 1e-6

@@ -113,6 +113,38 @@ def test_native_client_end_to_end(server):
     client.close()
 
 
+@pytest.mark.parametrize("backend", ["native+device", "bogus"])
+def test_start_refuses_a_backend_it_does_not_have(backend, tmp_path):
+    """An unknown --backend is refused where the arguments are parsed:
+    one line naming the backends there are, before the data file is
+    opened (there is none here) or the socket bound."""
+    out = subprocess.run(
+        [sys.executable, "-m", "tigerbeetle_tpu", "start",
+         "--addresses", f"127.0.0.1:{_free_port()}",
+         "--backend", backend, str(tmp_path / "no_such.tigerbeetle")],
+        cwd=REPO, env=dict(os.environ, TB_JAX_PLATFORM="cpu", PYTHONPATH=REPO),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert out.stderr.strip().splitlines()[-1] == (
+        f"error: unknown --backend {backend!r} (native|dual|device|sharded)"
+    ), out.stderr
+
+
+@pytest.mark.parametrize("argv,lists", [
+    (["-m", "tigerbeetle_tpu", "start", "--help"], "--backend <str>"),
+    (["chip_smoke.py", "--help"], "--chips"),
+])
+def test_help_is_printed_without_a_data_file_or_a_device(argv, lists):
+    out = subprocess.run(
+        [sys.executable, *argv], cwd=REPO,
+        env=dict(os.environ, TB_JAX_PLATFORM="cpu", PYTHONPATH=REPO),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert lists in out.stdout
+
+
 def test_repl_against_live_server(server):
 
     from tigerbeetle_tpu.repl import Repl, parse_statement

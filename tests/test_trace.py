@@ -145,7 +145,7 @@ def test_dual_mode_transfer_full_causal_chain(tmp_path):
     tracers = [JsonTracer(pid=i) for i in range(3)]
     cluster = Cluster(
         replica_count=3,
-        backend_factory=lambda: DualLedger(12, 14, follower=True),
+        backend_factory=lambda: DualLedger(12, 14),
         tracer_factory=lambda i: tracers[i],
     )
     r0 = cluster.replicas[0]

@@ -1,6 +1,5 @@
-"""Shared artifact provenance: the fields every in-session artifact
-(`BENCH_r0N.json`, `PRODDAY_r0N.json`) must carry so no number can be
-mistaken for a rig number and no two emitters can drift.
+"""Artifact provenance: the fields the prodday artifact must carry so
+no number can be mistaken for a chip number.
 
 Each artifact stamps:
 
@@ -14,10 +13,8 @@ Each artifact stamps:
   poisoned cache is the known sandbox pathology, see models/ledger.py
   and the tests/conftest.py guard).
 
-`scripts/make_bench_artifact.py` and the prodday emitter
-(`scripts/prodday.py`) both build their wrapper through
-`wrap_artifact()`; only the `parsed` payload and the incomplete-segment
-rules differ per artifact kind.
+The prodday emitter (`scripts/prodday.py`) builds its wrapper through
+`wrap_artifact()`.
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""End-to-end benchmark driver: the BASELINE protocol through the FULL
+"""End-to-end test drivers: the BASELINE protocol through the FULL
 system.
 
 The reference measures its headline number by formatting a data file,
@@ -14,9 +14,10 @@ one request in flight (the replica's commit window overlaps their journal
 writes and device commits — reference: src/vsr/replica.zig:52-70); pass
 clients=1 for the strictly sequential protocol.
 
-Used by bench.py (reported as `durable_tps` alongside the kernel flagship
-number) and by tests/test_process.py's smoke test (tiny sizes, CPU
-backend).
+A test harness, not the yardstick: the tests drive it at tiny sizes on
+the CPU backend (`run_e2e`, `run_ingress_sessions`, `run_frontier`), and
+chaos, prodday, federation and chip_smoke.py borrow its spawn helpers.
+What the chip is judged by is `benchmarks/run.py`.
 """
 
 from __future__ import annotations
@@ -219,12 +220,9 @@ def run_e2e(
     warmup_batches: int = 2,
     jax_platform: str | None = None,
     tmpdir: str | None = None,
-    server_args: tuple[str, ...] = (),
     backend: str = "native",
     workload: str = "simple",
     driver: str = "python",
-    trace: str | None = None,
-    cdc_slow_us: int | None = None,
     log=None,
 ) -> dict:
     """Format, start a real replica, drive the protocol, return metrics.
@@ -263,30 +261,12 @@ def run_e2e(
     # and skew later timings. The server also carries a parent-death
     # watchdog (cli._install_parent_death_watchdog) for the paths where
     # this harness itself is SIGKILLed.
-    # --trace: the server dumps its commit-pipeline spans (fuse hold,
-    # journal writes, commit dispatch/finalize, shadow uploads) as Chrome
-    # trace events on SIGTERM; run_e2e loads them back so the bench can
-    # merge them into one Perfetto-loadable file.
-    server_trace = os.path.join(tmpdir, "server_trace.json") if trace else None
-    trace_args = ("--trace", server_trace) if server_trace else ()
-    # CDC A/B mode: a live change-stream pump with a deliberately slow
-    # (non-blocking, refusing) sink — the acceptance run proving the live
-    # tail backpressures the PUMP and never the commit path. The server's
-    # [stats] registry snapshot carries cdc.lag_ops /
-    # cdc.backpressure_pauses back out.
-    cdc_args: tuple[str, ...] = ()
-    if cdc_slow_us is not None:
-        cdc_args = (
-            "--cdc-jsonl", os.path.join(tmpdir, "cdc.jsonl"),
-            "--cdc-slow-us", str(cdc_slow_us),
-        )
     proc = subprocess.Popen(
         [sys.executable, "-m", "tigerbeetle_tpu", "start",
          "--addresses", f"127.0.0.1:{port}",
          "--account-slots-log2", str(acct_log2),
          "--transfer-slots-log2", str(slots_log2),
-         "--backend", backend,
-         *trace_args, *cdc_args, *server_args, path],
+         "--backend", backend, path],
         cwd=REPO, env=env, start_new_session=True,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
     )
@@ -329,11 +309,10 @@ def run_e2e(
         # shutdown — off the clock, but the wait must cover it.
         proc.terminate()
         try:
-            # dual modes: must outlast DualLedger.finalize's own drain
+            # dual: must outlast DualLedger.finalize's own drain
             # timeout (600s) or a slow-but-legal verification is killed
             # mid-flight and the [stats] line is lost
-            dual = "+" in backend or backend == "dual"
-            proc.wait(timeout=650 if dual else 10)
+            proc.wait(timeout=650 if backend == "dual" else 10)
         except subprocess.TimeoutExpired:
             pass
         drain_thread.join(timeout=5)
@@ -366,24 +345,13 @@ def run_e2e(
                 # histogram percentiles) — sourced from the same store as
                 # the loop/group numbers above
                 result["server_metrics"] = server_stats["metrics"]
-                if cdc_slow_us is not None:
-                    m = server_stats["metrics"]
-                    result["cdc_lag_ops"] = m.get("gauges", {}).get(
-                        "cdc.lag_ops"
-                    )
-                    result["cdc_backpressure_pauses"] = m.get(
-                        "counters", {}
-                    ).get("cdc.backpressure_pauses")
-                    result["cdc_ops_streamed"] = m.get(
-                        "counters", {}
-                    ).get("cdc.ops")
             if "device_shadow" in server_stats:
                 result["device_shadow"] = server_stats["device_shadow"]
                 sh = server_stats["device_shadow"].get("shadow") or {}
                 if sh.get("upload_overlap") is not None:
                     result["shadow_upload_overlap"] = sh["upload_overlap"]
-                # dual (follower) mode: the end-of-run hash-log ring
-                # check + the applier's lag/overlap gauges
+                # the end-of-run hash-log ring check + the applier's
+                # lag/overlap gauges
                 hl = server_stats["device_shadow"].get("hash_log")
                 if hl is not None:
                     result["device_hash_log_ok"] = hl.get("ok")
@@ -396,14 +364,6 @@ def run_e2e(
                     result["device_apply_overlap"] = gauges[
                         "shadow.device_apply_overlap"
                     ]
-        if server_trace and os.path.exists(server_trace):
-            import json as _json
-
-            try:
-                with open(server_trace) as f:
-                    result["trace_events"] = _json.load(f)["traceEvents"]
-            except (ValueError, KeyError, OSError):
-                pass  # a torn dump must not sink the run's numbers
         return result
     finally:
         if proc.poll() is None:

@@ -47,6 +47,9 @@ class FormatArgs:
     client_reply_slots: int = 0
 
 
+BACKENDS = ("native", "dual", "device", "sharded")
+
+
 @dataclasses.dataclass
 class StartArgs:
     addresses: str  # comma-separated host:port per replica
@@ -97,19 +100,18 @@ class StartArgs:
     # Commit backend: "native" = the C++ host engine (native/ledger.cc —
     # the durable hot path, replies at host speed; why the device is not
     # on the reply path by default: models/native_ledger.py),
-    # "native+device" = the SHADOW dual mode: native serves replies while
-    # the device mirrors every prepare (h2d only) and shutdown verifies
-    # the device state bit-exact (models/dual_ledger.py),
-    # "dual" = the dual-commit FOLLOWER plan: like native+device, but the
-    # REPLICA enqueues committed ops to the device applier at commit
-    # finalize — rolling per-op hash-log rings (first divergent op named
-    # exactly), bounded-lag admission backpressure, checkpoint/state-sync
-    # drains, and restart recovery via snapshot row install,
+    # "dual" = dual commit: native serves replies while the REPLICA
+    # enqueues committed ops to the device applier at commit finalize
+    # (h2d only) and shutdown verifies the device state bit-exact
+    # (models/dual_ledger.py) — rolling per-op hash-log rings (first
+    # divergent op named exactly), bounded-lag admission backpressure,
+    # checkpoint/state-sync drains, and restart recovery via snapshot
+    # row install,
     # "device" = the JAX DeviceLedger (the TPU compute path; supports
     # HBM->LSM spill), "sharded" = the multi-chip ShardedLedger over a
     # jax.sharding.Mesh (parallel/mesh.py; slots flags are PER SHARD).
     backend: str = "native"
-    # Dual-commit follower: device-applier lag (committed ops not yet
+    # Dual commit: device-applier lag (committed ops not yet
     # dispatched to the device) beyond this window throttles admission
     # (Replica.ingress_occupancy / the _on_request cap) instead of
     # growing without bound.
@@ -171,6 +173,14 @@ class StartArgs:
     # operators and the live harness can tell regions apart.
     federation_region: int = -1
     federation_regions: int = 0
+
+    def __post_init__(self):
+        # refused where the arguments are parsed: before the data file
+        # is opened, the socket bound or a device asked for
+        if self.backend not in BACKENDS:
+            flags.fatal(
+                f"unknown --backend {self.backend!r} ({'|'.join(BACKENDS)})"
+            )
 
 
 @dataclasses.dataclass
@@ -397,10 +407,10 @@ def serving_device(devices, asked: tuple[str, ...]) -> dict:
 
 
 def announce_device() -> dict:
-    """For tools that measure on a device IN-PROCESS (bench.py,
-    scripts/profile_*.py, scripts/probe_device.py): print the device this
-    process got on stderr and refuse a CPU nobody asked for — the same
-    rule `start` serves by."""
+    """For tools that measure on a device IN-PROCESS
+    (scripts/profile_kernel.py, scripts/probe_device.py): print the
+    device this process got on stderr and refuse a CPU nobody asked for —
+    the same rule `start` serves by."""
     import json
 
     import jax
@@ -541,7 +551,7 @@ def cmd_start(args) -> int:
         backend_factory = lambda: NativeLedger(  # noqa: E731
             args.account_slots_log2, args.transfer_slots_log2
         )
-    elif args.backend in ("native+device", "dual"):
+    elif args.backend == "dual":
         from tigerbeetle_tpu.models.dual_ledger import DualLedger
 
         backend_factory = lambda: DualLedger(  # noqa: E731
@@ -549,9 +559,6 @@ def cmd_start(args) -> int:
             # compiles happen at boot, before "listening" — an in-window
             # compile stalls the apply queue into the reply path
             warm_kernels=True,
-            # "dual" = the follower plan: the replica enqueues committed
-            # ops at finalize, with hash-log rings + lag backpressure
-            follower=args.backend == "dual",
             lag_window=args.device_lag_window,
         )
     elif args.backend == "sharded":
@@ -573,11 +580,6 @@ def cmd_start(args) -> int:
         mesh = Mesh(_np.array(devs), ("shard",))
         backend_factory = lambda: ShardedLedger(  # noqa: E731
             mesh, process_cfg
-        )
-    elif args.backend != "device":
-        flags.fatal(
-            f"unknown --backend {args.backend!r} "
-            "(native|native+device|dual|device|sharded)"
         )
     replica = Replica(
         args.replica, len(addresses), storage, bus, RealTime(),

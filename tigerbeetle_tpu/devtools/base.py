@@ -115,9 +115,8 @@ def discover(root: pathlib.Path) -> list[str]:
     for base in ("tigerbeetle_tpu", "tests", "scripts"):
         for path in sorted((root / base).rglob("*.py")):
             rels.append(str(path.relative_to(root)))
-    for extra in ("bench.py", "__graft_entry__.py"):
-        if (root / extra).exists():
-            rels.append(extra)
+    if (root / "__graft_entry__.py").exists():
+        rels.append("__graft_entry__.py")
     return rels
 
 

@@ -24,7 +24,6 @@ class VetConfig:
         "tigerbeetle_tpu/cli.py",
         "tigerbeetle_tpu/repl.py",
         "tigerbeetle_tpu/__main__.py",
-        "bench.py",
         "__graft_entry__.py",
     })
 

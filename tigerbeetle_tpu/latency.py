@@ -379,17 +379,6 @@ class DeviceAnatomy:
         return out[:limit] if limit else out
 
 
-class _NullDeviceAnatomy(DeviceAnatomy):
-    def __init__(self):
-        super().__init__(metrics=NULL_METRICS)
-
-    def open(self, tid, t_enq, t_deq=0):
-        return 0
-
-
-NULL_DEVICE_ANATOMY = _NullDeviceAnatomy()
-
-
 def device_leg_totals(metrics_snapshot: dict) -> dict[str, dict]:
     """Per-device-sub-leg {count, total_us} from a registry snapshot —
     same shape as leg_totals(), feeding the same dominant_leg() delta

@@ -6,8 +6,8 @@ commit path. This module is the metric half of that pair for our port:
 
 - one `Metrics` registry per process (the composition root creates it and
   hands it to the replica, bus, journal, ledger, spill manager, ...), so
-  `bench.py`, `cli.py --statsd` and the `[stats]` shutdown line all read
-  the SAME numbers instead of per-site ad-hoc dicts;
+  `cli.py --statsd`, the `[stats]` shutdown line and the benchmark that
+  parses it all read the SAME numbers instead of per-site ad-hoc dicts;
 - `Counter` / `Gauge` are plain accumulators (float-capable — several
   pipeline stats are cumulative seconds);
 - `Histogram` is a fixed-bucket (powers of two, microseconds) timing
@@ -670,7 +670,7 @@ CATALOG = {
     "shadow.stage_s": ("counter", "s", "host seconds staging+dispatching shadow work"),
     "shadow.idle_s": ("counter", "s", "shadow loop seconds blocked on an empty queue"),
     "shadow.overlapped": ("counter", "", "groups staged while the previous kernel ran"),
-    # dual-commit follower mode (`--backend dual`)
+    # the dual-commit applier (`--backend dual`)
     "shadow.device_lag_ops": ("gauge", "ops", "committed ops not yet device-dispatched"),
     "shadow.device_apply_overlap": ("gauge", "", "fused applies staged while the prior kernel ran"),
     "shadow.drain_timeouts": ("counter", "", "applier drains that timed out (parity at risk)"),
@@ -853,6 +853,4 @@ CATALOG = {
     # cluster-causal tracing + introspection (tracer.py, inspect.py)
     "trace.sigquit_dumps": ("counter", "", "SIGQUIT hang-diagnosis dumps taken"),
     "inspect.live_requests": ("counter", "", "live [stats] snapshots served over the wire"),
-    # bench driver
-    "bench.batch_latency_us": ("histogram", "us", "synced single-batch dispatch latency"),
 }

@@ -1,52 +1,42 @@
-"""DualLedger: native C++ engine serves replies, the TPU applies the same
-prepares asynchronously — the dual-commit durable modes.
+"""DualLedger: the native C++ engine serves replies, the TPU applies the
+same committed ops asynchronously — the dual-commit backend
+(``--backend dual``).
 
-The problem this solves: measured on an earlier rig (see chip_smoke.py's
-`probe`, which re-measures it), ANY device->host fetch permanently slowed
-the process's dispatch path (models/native_ledger.py), so a reply-serving
-server could not run its hot loop through the device — but that blocks
-*reply-from-device*, not *commit-on-device*. Here the native engine (native/ledger.cc) computes
-reply codes at host speed, while a background device thread applies the
-SAME prepares, same timestamps, same order, to the JAX DeviceLedger —
-host->device uploads and kernel launches only, nothing ever read back
-until shutdown. Device state is REAL state: maintained batch-by-batch by
-the same commit kernels the flagship benchmark measures.
-
-Two modes:
-
-- **shadow** (``--backend native+device``): the ledger auto-enqueues every
-  create batch at execute time; the device is a passive mirror verified at
-  shutdown. No op numbers, no replica integration.
-- **follower** (``--backend dual``): the REPLICA drives the apply queue —
-  each committed op is enqueued at commit FINALIZE (reply built, WAL
-  durable) via apply_commit(op, ...), so the device follows the committed
-  op stream with an explicit watermark. This buys: a rolling per-op
-  hash-log ring on BOTH sides (first divergent op is named exactly, not
-  just "the digests differ"), bounded-lag admission backpressure
-  (`apply_lag_excess` feeds Replica.ingress_occupancy and the PR-6
-  credit regulator), checkpoint/state-sync drains, and restart recovery —
-  restore_bytes re-seeds the device from the native snapshot's row images
-  through DeviceLedger.install_snapshot_rows (h2d only).
+The native engine (native/ledger.cc) computes reply codes at host speed.
+The REPLICA drives the apply queue: each committed create op is enqueued
+at commit FINALIZE (reply built, WAL durable) via apply_commit(op, ...),
+and a background thread applies the SAME events, same timestamps, same
+order, to the JAX DeviceLedger — host->device uploads and kernel launches
+only, nothing read back until shutdown. Device state is REAL state,
+maintained batch by batch by the same commit kernels the `device` backend
+replies from. Following the committed op stream with an explicit
+watermark buys: a rolling per-op hash-log ring on BOTH sides (the first
+divergent op is named exactly, not just "the digests differ"),
+bounded-lag admission backpressure (`apply_lag_excess` feeds
+Replica.ingress_occupancy and the credit regulator), checkpoint/state-sync
+drains, and restart recovery — restore_bytes re-seeds the device from the
+native snapshot's row images through DeviceLedger.install_snapshot_rows
+(h2d only).
 
 Verification (hash_log semantics, testing/hash_log.py):
 - every batch's dense reply codes are folded into a chained u64 digest on
-  BOTH sides — on device (fold_reply_codes, no d2h) and on host over the
-  native engine's codes (same stream order);
-- in follower mode each op's post-fold chain value is also written into a
-  rolling ring (host-side numpy ring + device-side ring updated inside the
-  fold kernel), so the end-of-run check can walk the rings and fail AT the
-  first divergent op — the reference's -Dhash-log-mode check applied
-  across heterogeneous engines (src/testing/hash_log.zig);
-- at shutdown, finalize() drains the apply queue and does the process's
-  FIRST device->host reads: the fold scalars must match, the rings must
-  match entry for entry, and state_fingerprint — an order-independent
-  digest over every live account/transfer row's 128-byte wire image,
-  implemented identically in C++ (tb_ledger_fingerprint) and JAX
-  (models/ledger.py state_fingerprint) — must match row-set for row-set.
+  BOTH sides — on device (fold_reply_codes, no d2h) and on the apply
+  thread over the native engine's codes (same stream order);
+- each op's post-fold chain value is also written into a rolling ring
+  (host-side ring + device-side ring updated inside the fold kernel), so
+  the end-of-run check can walk the rings and fail AT the first divergent
+  op — the reference's -Dhash-log-mode check applied across heterogeneous
+  engines (src/testing/hash_log.zig);
+- at shutdown, finalize() drains the apply queue and does the applier's
+  device->host reads: the fold scalars must match, the rings must match
+  entry for entry, and state_fingerprint — an order-independent digest
+  over every live account/transfer row's 128-byte wire image, implemented
+  identically in C++ (tb_ledger_fingerprint) and JAX (models/ledger.py
+  state_fingerprint) — must match row-set for row-set.
 
 Reference seam: src/state_machine.zig:508-540 — commit determinism is the
-consensus invariant; the dual mode extends it across heterogeneous engines
-(the reference's simulator cross-checks replicas the same way,
+consensus invariant; the dual backend extends it across heterogeneous
+engines (the reference's simulator cross-checks replicas the same way,
 src/testing/cluster/state_checker.zig).
 """
 
@@ -63,7 +53,6 @@ from tigerbeetle_tpu.latency import (
     DLEG_COALESCE,
     DLEG_DISPATCH,
     DLEG_H2D,
-    NULL_DEVICE_ANATOMY,
     DeviceAnatomy,
 )
 from tigerbeetle_tpu.metrics import Metrics
@@ -76,57 +65,28 @@ _STOP = object()
 _INSTALL = "__install__"  # control item: re-seed the device from a snapshot
 _PROBE = "__probe__"  # control item: checkpoint-commitment fingerprint probe
 
-# Rolling per-op digest ring (follower mode): one chained-fold value per
-# committed create op, op % RING. 4096 ops cover well over a full WAL ring
-# of divergence localization without unbounded memory on either side.
+# Rolling per-op digest ring: one chained-fold value per committed create
+# op, op % RING. 4096 ops cover well over a full WAL ring of divergence
+# localization without unbounded memory on either side.
 APPLY_RING = 1 << 12
 
-_FOLD_GROUP_CACHE: dict = {}
 _FOLD_RING_CACHE: dict = {}
 
 
-def _fold_group_fn(k: int, n_pad: int):
+def _fold_group_ring_fn(k: int, n_pad: int):
     """Jitted chained fold over a fused group's flat results: one dispatch
     folds up to k batches' code streams (active-masked — padding slots
-    must NOT advance the chain, the native side folds only real batches).
-    Digest-identical to k sequential fold_reply_codes calls: the per-batch
-    mix only sums lanes < n, so the trailing fault word / slot layout
-    never contributes."""
-    fn = _FOLD_GROUP_CACHE.get((k, n_pad))
-    if fn is None:
-        import jax
-        import jax.numpy as jnp
-
-        from tigerbeetle_tpu.models.ledger import fold_reply_codes, sentinel_jit
-
-        def f(chk, flat, ns, active):
-            flat2 = flat[: k * n_pad].reshape(k, n_pad)
-
-            def body(c, x):
-                res, n, a = x
-                return jnp.where(
-                    a, fold_reply_codes(c, res, n), c
-                ), None
-
-            c2, _ = jax.lax.scan(body, chk, (flat2, ns, active))
-            return c2
-
-        fn = _FOLD_GROUP_CACHE[(k, n_pad)] = sentinel_jit(
-            f"fold_group_{k}x{n_pad}", f
-        )
-    return fn
-
-
-def _fold_group_ring_fn(k: int, n_pad: int):
-    """Follower variant of _fold_group_fn: the scan also EMITS each
-    batch's post-fold chain value, and the per-op values are scattered
-    into the rolling device ring at their ops' slots. The ring carries a
-    DUMP slot at index APPLY_RING and inactive lanes are routed there by
+    must NOT advance the chain, the native side folds only real batches)
+    and scatters each batch's post-fold chain value into the rolling
+    device ring at its op's slot. Digest-identical to k sequential
+    fold_reply_codes calls: the per-batch mix only sums lanes < n, so the
+    trailing fault word / slot layout never contributes. The ring carries
+    a DUMP slot at index APPLY_RING and inactive lanes are routed there by
     the caller — scattering a stale read-back at a real slot instead
     would race an active lane that maps to the same slot (duplicate-index
-    .at[].set is order-undefined) and fabricate a divergence. Chain-
-    identical to _fold_group_fn — the ring write rides the same dispatch,
-    so the apply loop stays one launch per fused group with no d2h."""
+    .at[].set is order-undefined) and fabricate a divergence. The ring
+    write rides the same dispatch, so the apply loop stays one launch per
+    fused group with no d2h."""
     fn = _FOLD_RING_CACHE.get(("group", k, n_pad))
     if fn is None:
         import jax
@@ -152,7 +112,7 @@ def _fold_group_ring_fn(k: int, n_pad: int):
 
 
 def _fold_ring_fn():
-    """Solo-batch follower fold: chain + one ring write, one dispatch."""
+    """Solo-batch fold: chain + one ring write, one dispatch."""
     fn = _FOLD_RING_CACHE.get("solo")
     if fn is None:
         import jax
@@ -190,6 +150,9 @@ class DualLedger:
     device never blocks (or touches) the reply path."""
 
     zero_copy_events = True  # both consumers only read the event rows
+    # the replica enqueues ops at commit finalize via apply_commit; the
+    # execute paths never enqueue. Replica detects the plan by this.
+    dual_follower = True
 
     SHADOW_KEYS = (
         "batches", "groups", "solo", "stage_s", "idle_s", "overlapped",
@@ -214,27 +177,26 @@ class DualLedger:
         self.shadow_stats = metrics.group(  # vet: handoff
             "shadow", self.SHADOW_KEYS
         )
-        if self.follower:
-            # gauges bound ONCE (a registry lookup per committed op would
-            # tax the hot paths the counters observe — the PR-6 bus
-            # lesson); the APPLY thread is the only writer
-            self._lag_gauge = metrics.gauge(  # vet: handoff
-                "shadow.device_lag_ops"
-            )
-            self._overlap_gauge = metrics.gauge(  # vet: handoff
-                "shadow.device_apply_overlap"
-            )
-            # device-apply lag lane (latency.py parallel-lane contract):
-            # bound once; observed from the APPLY thread only (the
-            # Histogram serializes internally)
-            self._h_apply_lag = metrics.histogram(  # vet: handoff
-                "latency.device_apply_lag_us"
-            )
-            # device anatomy: opened/stamped/finished on the APPLY thread
-            # only (the enqueue stamp arrives by value in the apply
-            # tuple); rebinding swaps the whole object — a GIL-atomic
-            # reference swap read per run
-            self.device_anatomy = DeviceAnatomy(metrics)  # vet: handoff
+        # gauges bound ONCE (a registry lookup per committed op would
+        # tax the hot paths the counters observe — the PR-6 bus
+        # lesson); the APPLY thread is the only writer
+        self._lag_gauge = metrics.gauge(  # vet: handoff
+            "shadow.device_lag_ops"
+        )
+        self._overlap_gauge = metrics.gauge(  # vet: handoff
+            "shadow.device_apply_overlap"
+        )
+        # device-apply lag lane (latency.py parallel-lane contract):
+        # bound once; observed from the APPLY thread only (the
+        # Histogram serializes internally)
+        self._h_apply_lag = metrics.histogram(  # vet: handoff
+            "latency.device_apply_lag_us"
+        )
+        # device anatomy: opened/stamped/finished on the APPLY thread
+        # only (the enqueue stamp arrives by value in the apply
+        # tuple); rebinding swaps the whole object — a GIL-atomic
+        # reference swap read per run
+        self.device_anatomy = DeviceAnatomy(metrics)  # vet: handoff
         # applier throughput surfaces (flight-recorder device columns);
         # written by the apply thread only
         self._g_qdepth = metrics.gauge("device.queue_depth")  # vet: handoff
@@ -249,14 +211,9 @@ class DualLedger:
         xfer_slots_log2: int = 20,
         queue_max: int = 256,
         warm_kernels: bool = False,
-        follower: bool = False,
         lag_window: int = 128,
     ):
         self.native = NativeLedger(acct_slots_log2, xfer_slots_log2)
-        # follower (the `dual` backend plan): the replica enqueues ops at
-        # commit finalize via apply_commit; execute paths do NOT
-        # auto-enqueue. Replica detects the plan via this attribute.
-        self.follower = self.dual_follower = follower
         # Bounded-lag admission window (ops): apply lag beyond it feeds
         # Replica.ingress_occupancy, so the PR-6 credit regulator (and
         # the bare _on_request cap) throttles ADMISSION instead of the
@@ -284,16 +241,11 @@ class DualLedger:
         self.process = None  # replica duck-typing (native backend shape)
         self.spill = None
         self.hazards = self.device.hazards  # [stats] observability
-        # chained digests of the dense reply-code stream (hash_log pair);
-        # shadow mode folds on the native engine's done-callbacks, read at
-        # finalize (follower mode folds on the apply thread instead)
-        self._chk_native = 0  # vet: guarded-by=_chk_lock
-        self._chk_lock = threading.Lock()
         # written only by the apply thread; finalize() joins the thread
         # before reading either (join-before-read)
         self._shadow_error: Exception | None = None  # vet: handoff
         self._shadow_batches = 0  # vet: handoff
-        # follower watermarks: _enqueued_op/_enq_ops written by the event
+        # watermarks: _enqueued_op/_enq_ops written by the event
         # loop at apply_commit, read by the apply thread for the lag
         # gauge; _applied_op/_done_ops/_consumed_seq written by the apply
         # thread, read by the event loop (lag/backpressure/drain). All
@@ -309,7 +261,7 @@ class DualLedger:
         self._put_seq = 0  # event-loop-only (apply_commit/restore_bytes)
         self._consumed_seq = 0  # vet: handoff
         self._apply_cond = threading.Condition()
-        # follower hash-log rings (APPLY_RING entries): the host ring
+        # hash-log rings (APPLY_RING entries): the host ring
         # holds (op, prepare_checksum, native chain value) per applied
         # op; the device ring is its on-device twin, fetched ONCE at
         # finalize. Written only by the apply thread; finalize joins
@@ -331,37 +283,32 @@ class DualLedger:
         # staging + dispatching apply work; idle_s = blocked on an empty
         # queue; overlapped = groups whose staging/dispatch completed
         # while the PREVIOUS group's kernel was still executing (the
-        # double-buffer pipeline working as intended). BENCH reports
-        # overlapped/groups as shadow_upload_overlap. Registry-backed
+        # double-buffer pipeline working as intended). Registry-backed
         # (metrics.py StatGroup under `shadow.`): instrument() re-binds
         # onto the replica's shared registry so the [stats] line and the
-        # bench read the same store.
+        # benchmark read the same store.
         self.metrics = Metrics()
         self.tracer = NULL_TRACER
         self.shadow_stats = self.metrics.group("shadow", self.SHADOW_KEYS)
-        self.device_anatomy = NULL_DEVICE_ANATOMY
         self._g_qdepth = self.metrics.gauge("device.queue_depth")
         self._c_dispatch = self.metrics.counter("device.dispatches")
-        if follower:
-            self._lag_gauge = self.metrics.gauge("shadow.device_lag_ops")
-            self._overlap_gauge = self.metrics.gauge(
-                "shadow.device_apply_overlap"
-            )
-            self._h_apply_lag = self.metrics.histogram(
-                "latency.device_apply_lag_us"
-            )
-            self.device_anatomy = DeviceAnatomy(self.metrics)
-        # device cannot follow a snapshot restore without an install path
-        # (shadow mode, or a follower whose snapshot exceeds the device
-        # geometry). Set on the event loop, polled by the apply loop: a
-        # GIL-atomic bool flip whose one-iteration staleness only delays
-        # the stand-down by a batch
+        self._lag_gauge = self.metrics.gauge("shadow.device_lag_ops")
+        self._overlap_gauge = self.metrics.gauge(
+            "shadow.device_apply_overlap"
+        )
+        self._h_apply_lag = self.metrics.histogram(
+            "latency.device_apply_lag_us"
+        )
+        self.device_anatomy = DeviceAnatomy(self.metrics)
+        # the device cannot follow a snapshot that exceeds its geometry:
+        # _apply_install sets this and the apply loop drains without
+        # applying (finalize reports skipped)
         self._restored = False  # vet: handoff
         # the queue IS the cross-thread handoff (bounded, blocking put)
         self._q: queue.Queue = queue.Queue(maxsize=queue_max)  # vet: handoff
         self._thread = threading.Thread(
             target=self._apply_loop,
-            name="device-applier" if follower else "device-shadow",
+            name="device-applier",
             daemon=True,
         )
         self._thread.start()
@@ -373,19 +320,15 @@ class DualLedger:
         reuses every compile; scratch state is freed before the real
         tables allocate). Covers: accounts commit, transfers fast tier,
         fast_pv (posts), group steppers (both fused capacities), the
-        results summarizer, and the fold kernels (ring variants too in
-        follower mode), all at the wire batch pad. Rare tiers (serial
-        residue at odd pads) compile on demand — the 256-slot queue
-        absorbs those stalls."""
+        results summarizer, and the ring fold kernels, all at the wire
+        batch pad. Rare tiers (serial residue at odd pads) compile on
+        demand — the 256-slot queue absorbs those stalls."""
         import jax
         import jax.numpy as jnp
 
         from tigerbeetle_tpu import types
         from tigerbeetle_tpu.constants import BATCH_PAD, BENCH_BATCH
-        from tigerbeetle_tpu.models.ledger import (
-            DeviceLedger,
-            fold_reply_codes,
-        )
+        from tigerbeetle_tpu.models.ledger import DeviceLedger
 
         scratch = DeviceLedger(process=process, mode="auto")
         scratch.prefetch_results = False
@@ -456,8 +399,7 @@ class DualLedger:
             ts += n
             scratch.execute_async(Operation.create_transfers, ts, wav)
         # both fused group capacities (the replica's group commit) + the
-        # fused group-fold kernel over each (ring variant in follower
-        # mode — the production apply path dispatches that one)
+        # fused group-fold kernel over each
         scratch_ring = jnp.zeros(APPLY_RING + 1, dtype=jnp.uint64)
         for k in (5, 2):  # 5 -> the 16-slot stepper, 2 -> the 4-slot
             items = []
@@ -471,32 +413,18 @@ class DualLedger:
                 ns[:k] = [len(a) for _, a in items]
                 active = np.zeros(g.k, dtype=bool)
                 active[:k] = True
-                if self.follower:
-                    idxs = np.arange(g.k, dtype=np.int32)
-                    _, scratch_ring = _fold_group_ring_fn(g.k, g.n_pad)(
-                        jnp.uint64(0), scratch_ring, jnp.asarray(idxs),
-                        g.results, jnp.asarray(ns), jnp.asarray(active),
-                    )
-                else:
-                    _fold_group_fn(g.k, g.n_pad)(
-                        jnp.uint64(0), g.results, jnp.asarray(ns),
-                        jnp.asarray(active),
-                    )
+                idxs = np.arange(g.k, dtype=np.int32)
+                _, scratch_ring = _fold_group_ring_fn(g.k, g.n_pad)(
+                    jnp.uint64(0), scratch_ring, jnp.asarray(idxs),
+                    g.results, jnp.asarray(ns), jnp.asarray(active),
+                )
         # the solo fold kernel
-        if self.follower:
-            chk, scratch_ring = _fold_ring_fn()(
-                jnp.uint64(0), scratch_ring, jnp.int32(0),
-                jnp.zeros(pad + 1, dtype=jnp.uint32), jnp.int32(1),
-            )
-        else:
-            chk = jax.jit(fold_reply_codes)(
-                jnp.uint64(0),
-                jnp.zeros(pad + 1, dtype=jnp.uint32),
-                jnp.int32(1),
-            )
-        # block WITHOUT fetching: the dual mode's contract is no
-        # device->host read before finalize (measured on an earlier rig,
-        # see `probe`: the first fetch slowed every later dispatch)
+        chk, scratch_ring = _fold_ring_fn()(
+            jnp.uint64(0), scratch_ring, jnp.int32(0),
+            jnp.zeros(pad + 1, dtype=jnp.uint32), jnp.int32(1),
+        )
+        # block WITHOUT fetching: the applier reads nothing back from
+        # the device before finalize
         jax.block_until_ready(chk)
         # compiles past this point are hot-path events (rare tiers and
         # odd pads compile on demand behind the queue — exactly the
@@ -508,14 +436,11 @@ class DualLedger:
     # -- the device apply loop --------------------------------------------
 
     def _apply_loop(self) -> None:
-        """One loop serves both modes (the generalized shadow loop): items
-        are (op, operation, ts, arr, codes, prepare_checksum, trace) —
-        shadow mode enqueues op=None/codes=None/trace=0 (digests fold via
-        the engine done-callbacks instead), follower mode carries the
-        committed op number, the native dense codes, the prepare checksum
-        and the op's cluster-causal trace id (tags the shadow.upload
-        span). Control items (first element a str) re-seed/reset the
-        device between runs."""
+        """Items are (op, operation, ts, arr, codes, prepare_checksum,
+        trace, lat_ns): the committed op number, the native dense codes,
+        the prepare checksum, the op's cluster-causal trace id (tags the
+        shadow.upload span) and the sampled enqueue stamp. Control items
+        (first element a str) re-seed/reset the device between runs."""
         import time as _time
 
         import jax
@@ -523,33 +448,27 @@ class DualLedger:
 
         from tigerbeetle_tpu.models.ledger import (
             DeviceLedger,
-            fold_reply_codes,
             fold_reply_codes_np,
         )
 
-        fold = jax.jit(fold_reply_codes)
         chk = jnp.uint64(0)
         chk_nat = 0
         # +1: the DUMP slot inactive group lanes scatter into (see
         # _fold_group_ring_fn); real ops land in [0, APPLY_RING)
-        dev_ring = (
-            jnp.zeros(APPLY_RING + 1, dtype=jnp.uint64)
-            if self.follower else None
-        )
+        dev_ring = jnp.zeros(APPLY_RING + 1, dtype=jnp.uint64)
         group_max = DeviceLedger.GROUP_KS[0]
         prev_flat = None  # previous fused group's results (overlap probe)
         stop = False
 
-        def note_applied(op: int | None, n_items: int) -> None:
-            if op is not None:
-                self._applied_op = op
-                self._done_ops += n_items
-                self._lag_gauge.set(max(0, self._enq_ops - self._done_ops))
+        def note_applied(op: int, n_items: int) -> None:
+            self._applied_op = op
+            self._done_ops += n_items
+            self._lag_gauge.set(max(0, self._enq_ops - self._done_ops))
 
         def fold_native_run(items) -> None:
             """Chain the native codes + ring entries for a run, in op
-            order (follower mode; runs are consumed in queue order so the
-            chain matches the commit stream)."""
+            order (runs are consumed in queue order so the chain matches
+            the commit stream)."""
             nonlocal chk_nat
             for op2, _o, _t, _a, codes, prep, *_rest in items:
                 chk_nat = fold_reply_codes_np(chk_nat, codes)
@@ -682,38 +601,25 @@ class DualLedger:
                         ns[:m] = [len(a) for _, _, _, a, *_ in run[i:j]]
                         active = np.zeros(g.k, dtype=bool)
                         active[:m] = True
-                        if self.follower:
-                            idxs = np.full(
-                                g.k, APPLY_RING, dtype=np.int32
-                            )  # inactive lanes -> the dump slot
-                            idxs[:m] = [
-                                it[0] % APPLY_RING for it in run[i:j]
-                            ]
-                            # two ACTIVE ops in one run congruent mod
-                            # APPLY_RING (>4096 non-create ops between
-                            # them): duplicate-index scatter is order-
-                            # undefined, so route all but the LAST to
-                            # the dump slot — the host ring keeps the
-                            # last op per slot too (dict overwrite)
-                            seen_slots: dict[int, int] = {}
-                            for lane in range(m):
-                                s_prev = seen_slots.get(int(idxs[lane]))
-                                if s_prev is not None:
-                                    idxs[s_prev] = APPLY_RING
-                                seen_slots[int(idxs[lane])] = lane
-                            chk, dev_ring = _fold_group_ring_fn(
-                                g.k, g.n_pad
-                            )(
-                                chk, dev_ring, jnp.asarray(idxs),
-                                g.results, jnp.asarray(ns),
-                                jnp.asarray(active),
-                            )
-                            fold_native_run(run[i:j])
-                        else:
-                            chk = _fold_group_fn(g.k, g.n_pad)(
-                                chk, g.results, jnp.asarray(ns),
-                                jnp.asarray(active),
-                            )
+                        # inactive lanes -> the dump slot
+                        idxs = np.full(g.k, APPLY_RING, dtype=np.int32)
+                        idxs[:m] = [it[0] % APPLY_RING for it in run[i:j]]
+                        # two ACTIVE ops in one run congruent mod
+                        # APPLY_RING (>4096 non-create ops between them):
+                        # duplicate-index scatter is order-undefined, so
+                        # route all but the LAST to the dump slot — the
+                        # host ring keeps the last op per slot too
+                        seen_slots: dict[int, int] = {}
+                        for lane in range(m):
+                            s_prev = seen_slots.get(int(idxs[lane]))
+                            if s_prev is not None:
+                                idxs[s_prev] = APPLY_RING
+                            seen_slots[int(idxs[lane])] = lane
+                        chk, dev_ring = _fold_group_ring_fn(g.k, g.n_pad)(
+                            chk, dev_ring, jnp.asarray(idxs), g.results,
+                            jnp.asarray(ns), jnp.asarray(active),
+                        )
+                        fold_native_run(run[i:j])
                         self._shadow_batches += m
                         self._c_dispatch.add()
                         stats = self.shadow_stats
@@ -725,10 +631,9 @@ class DualLedger:
                             # while the previous kernel was still running:
                             # the upload pipeline overlapped execution
                             stats.add("overlapped")
-                        if self.follower and stats["groups"]:
-                            self._overlap_gauge.set(round(
-                                stats["overlapped"] / stats["groups"], 4
-                            ))
+                        self._overlap_gauge.set(round(
+                            stats["overlapped"] / stats["groups"], 4
+                        ))
                         prev_flat = g.results
                         if stretch_toks:
                             # h2d_stage closes at the ledger's upload-
@@ -766,23 +671,16 @@ class DualLedger:
                                     opn2, ts2, arr2
                                 )
                                 self.device._c_h2d.add(arr2.nbytes)
-                                if self.follower:
-                                    chk, dev_ring = _fold_ring_fn()(
-                                        chk, dev_ring,
-                                        jnp.int32(op2 % APPLY_RING),
-                                        pending.results,
-                                        jnp.int32(len(arr2)),
-                                    )
-                                else:
-                                    chk = fold(
-                                        chk, pending.results,
-                                        jnp.int32(len(arr2)),
-                                    )
+                                chk, dev_ring = _fold_ring_fn()(
+                                    chk, dev_ring,
+                                    jnp.int32(op2 % APPLY_RING),
+                                    pending.results,
+                                    jnp.int32(len(arr2)),
+                                )
                                 self._shadow_batches += 1
                                 self.shadow_stats.add("batches")
                                 self.shadow_stats.add("solo")
-                        if self.follower:
-                            fold_native_run(run[i:end])
+                        fold_native_run(run[i:end])
                         self.shadow_stats.add(
                             "stage_s", _time.perf_counter() - t_stage)
                         self._c_dispatch.add(end - i)
@@ -802,17 +700,16 @@ class DualLedger:
                     i = j
             except Exception as e:  # divergence surfaces at finalize
                 self._shadow_error = e
-            if self.follower:
-                # latency anatomy's device-apply LANE: enqueue (commit
-                # finalize, event loop) -> dispatched to the device (all
-                # of this run's uploads issued). Sampled ops only (slot 8
-                # is 0 otherwise); same perf_counter_ns domain both sides.
-                t_done = _time.perf_counter_ns()
-                for item in run:
-                    if item[7]:
-                        self._h_apply_lag.observe(
-                            (t_done - item[7]) / 1000.0
-                        )
+            # latency anatomy's device-apply LANE: enqueue (commit
+            # finalize, event loop) -> dispatched to the device (all
+            # of this run's uploads issued). Sampled ops only (slot 8
+            # is 0 otherwise); same perf_counter_ns domain both sides.
+            t_done = _time.perf_counter_ns()
+            for item in run:
+                if item[7]:
+                    self._h_apply_lag.observe(
+                        (t_done - item[7]) / 1000.0
+                    )
             self._consumed_seq += len(run)
             note_applied(run[-1][0], len(run))
             if any_tok:
@@ -870,9 +767,7 @@ class DualLedger:
         state, exactly like the native side's restored tables."""
         import jax.numpy as jnp
 
-        # install items are only ever enqueued in follower mode
-        # (restore_bytes); both exits restart the chains/rings from the
-        # installed state
+        # both exits restart the chains/rings from the installed state
         fresh_chains = (
             jnp.uint64(0), 0, jnp.zeros(APPLY_RING + 1, dtype=jnp.uint64),
         )
@@ -939,7 +834,7 @@ class DualLedger:
             **detail,
         }
 
-    # -- follower apply seam (driven by the replica at commit finalize) ----
+    # -- apply seam (driven by the replica at commit finalize) -------------
 
     def apply_commit(
         self,
@@ -952,8 +847,8 @@ class DualLedger:
         trace: int = 0,
         lat_ns: int = 0,
     ) -> None:
-        """Enqueue one COMMITTED op for the device applier (follower
-        mode): called by the replica at commit finalize, in op order,
+        """Enqueue one COMMITTED op for the device applier: called by
+        the replica at commit finalize, in op order,
         with the event rows (a read-only view over the prepare body) and
         the native engine's dense reply codes. The bounded queue
         backpressures the event loop only as a last resort — admission
@@ -965,7 +860,6 @@ class DualLedger:
         event loop): the apply loop observes enqueue->device-dispatch
         into latency.device_apply_lag_us — the dual mode's parallel
         lane, never part of the reply's critical-path legs."""
-        assert self.follower
         self._enqueued_op = op
         self._enq_ops += 1
         self._put_seq += 1
@@ -975,13 +869,12 @@ class DualLedger:
         )
 
     def commitment_probe(self, op: int, fp_host: dict) -> None:
-        """Enqueue a checkpoint-commitment fingerprint probe (follower
-        mode): called by the replica at the boundary op's commit
+        """Enqueue a checkpoint-commitment fingerprint probe: called by
+        the replica at the boundary op's commit
         finalize with the HOST engine's fingerprint from the commitment
         chain. The apply thread stashes the device twin's lazy
         fingerprint at the matching point in its queue; finalize()
         compares them per checkpoint."""
-        assert self.follower
         self._put_seq += 1
         self._q.put((_PROBE, op, fp_host))
 
@@ -1017,25 +910,6 @@ class DualLedger:
                         return False
         return True
 
-    def _enqueue_shadow(self, operation, timestamp: int, arr) -> None:
-        # the queue bounds host-memory growth; a full queue briefly
-        # backpressures the event loop rather than dropping shadow batches
-        # (a dropped batch would be an unverifiable run, not a fast one)
-        self._q.put((None, operation, timestamp, arr, None, 0, 0, 0))
-
-    def _fold_native(self, pending) -> None:
-        """Chain the native codes into the host-side digest when the engine
-        worker completes the batch (FIFO worker => stream order matches the
-        shadow queue's). Shadow mode only — the follower folds on the
-        apply thread with op numbers instead."""
-        from tigerbeetle_tpu.models.ledger import fold_reply_codes_np
-
-        def _cb(_fut, codes=pending.codes):
-            with self._chk_lock:
-                self._chk_native = fold_reply_codes_np(self._chk_native, codes)
-
-        pending.fut.add_done_callback(_cb)
-
     # -- backend protocol (reply path: native) ----------------------------
 
     @property
@@ -1050,34 +924,11 @@ class DualLedger:
         self.native.prepare(operation, event_count)
 
     def execute_async(self, operation, timestamp: int, events):
-        arr = events if isinstance(events, np.ndarray) else None
-        pending = self.native.execute_async(operation, timestamp, events)
-        if self.follower:
-            return pending  # the replica enqueues at commit finalize
-        if operation in (Operation.create_accounts, Operation.create_transfers):
-            if arr is None:
-                # list-of-objects path (REPL/tests): reuse the bytes the
-                # native wrapper built
-                from tigerbeetle_tpu import types as _t
-
-                arr = (
-                    _t.accounts_to_np(events)
-                    if operation == Operation.create_accounts
-                    else _t.transfers_to_np(events)
-                )
-            self._fold_native(pending)
-            self._enqueue_shadow(operation, timestamp, arr)
-        return pending
+        # the replica enqueues for the device at commit finalize
+        return self.native.execute_async(operation, timestamp, events)
 
     def try_execute_group_async(self, items):
-        pendings = self.native.try_execute_group_async(items)
-        if pendings is None:
-            return None
-        if not self.follower:
-            for (ts, arr), p in zip(items, pendings):
-                self._fold_native(p)
-                self._enqueue_shadow(Operation.create_transfers, ts, arr)
-        return pendings
+        return self.native.try_execute_group_async(items)
 
     def drain(self, pending):
         return self.native.drain(pending)
@@ -1122,24 +973,16 @@ class DualLedger:
 
     def restore_bytes(self, raw: bytes) -> None:
         self.native.restore_bytes(raw)
-        if self.follower:
-            # Re-seed the device from the SAME snapshot's row images (the
-            # row-level upload path: h2d staging + insert kernels, no
-            # d2h) — queued as a control item so it serializes with any
-            # in-flight applies; the replica drains the applier before
-            # any state-replacing restore (checkpoint/state-sync
-            # contract). Digest chains/rings reset with the state.
-            if len(raw) <= 64:
-                return  # fresh/empty snapshot: nothing to install
-            self._put_seq += 1
-            self._q.put((_INSTALL, raw))
-            return
-        # Shadow mode: the device table cannot be rebuilt from a
-        # mid-history snapshot (no op-tagged apply seam); the shadow
-        # stands down and finalize() reports it (bench/format-fresh runs
-        # never hit this).
-        if len(raw) > 64 and self.native.counts()["accounts"] > 0:
-            self._restored = True
+        # Re-seed the device from the SAME snapshot's row images (the
+        # row-level upload path: h2d staging + insert kernels, no
+        # d2h) — queued as a control item so it serializes with any
+        # in-flight applies; the replica drains the applier before
+        # any state-replacing restore (checkpoint/state-sync
+        # contract). Digest chains/rings reset with the state.
+        if len(raw) <= 64:
+            return  # fresh/empty snapshot: nothing to install
+        self._put_seq += 1
+        self._q.put((_INSTALL, raw))
 
     # -- shutdown verification --------------------------------------------
 
@@ -1154,21 +997,19 @@ class DualLedger:
         s["upload_overlap"] = (
             round(s["overlapped"] / s["groups"], 4) if s["groups"] else None
         )
-        if self.follower:
-            s["applied_op"] = self._applied_op
-            s["lag_ops"] = self.apply_lag_ops()
-            # worst sampled apply items with their sub-leg breakdowns
-            # (the commit_wait decomposition, latency.py DeviceAnatomy)
-            ds = self.device_anatomy.slowest(4)
-            if ds:
-                s["device_slowest"] = ds
+        s["applied_op"] = self._applied_op
+        s["lag_ops"] = self.apply_lag_ops()
+        # worst sampled apply items with their sub-leg breakdowns
+        # (the commit_wait decomposition, latency.py DeviceAnatomy)
+        ds = self.device_anatomy.slowest(4)
+        if ds:
+            s["device_slowest"] = ds
         return s
 
     def _hash_ring_check(self) -> dict:
         """Walk the host/device per-op digest rings (one ring fetch — the
         finalize-time d2h) and name the FIRST divergent op, the
-        -Dhash-log-mode check across engines. Only meaningful in follower
-        mode (shadow mode has no op numbers)."""
+        -Dhash-log-mode check across engines."""
         dev = np.asarray(self._dev_ring_out)
         entries = sorted(
             (e for e in self._op_ring if e is not None), key=lambda e: e[0]
@@ -1195,10 +1036,10 @@ class DualLedger:
         }
 
     def finalize(self, timeout: float = 600.0) -> dict:
-        """Drain the apply queue, then do the process's FIRST d2h reads:
-        compare the two reply-code digests, the per-op digest rings
-        (follower mode), and the two state fingerprints. Returns the
-        verification report the server prints on its [stats] line."""
+        """Drain the apply queue, then do the applier's d2h reads:
+        compare the two reply-code digests, the per-op digest rings and
+        the two state fingerprints. Returns the verification report the
+        server prints on its [stats] line."""
         self._q.put(_STOP)
         self._thread.join(timeout=timeout)
         if self._thread.is_alive():
@@ -1223,18 +1064,7 @@ class DualLedger:
                 "error": f"{type(e).__name__}: {e}",
             }
         chk_dev = int(np.asarray(self._chk_device_scalar))
-        if self.follower:
-            chk_nat = self._chk_native_thread
-        else:
-            # Barrier through the engine's FIFO worker: a job submitted
-            # now starts only after every prior execute's future has
-            # resolved AND run its inline done-callbacks (the fold chain)
-            # on the worker thread — Future.result() alone wakes waiters
-            # BEFORE callbacks, so without this the last batch's fold
-            # could be missing.
-            self.native._submit(lambda: 0).result()
-            with self._chk_lock:
-                chk_nat = self._chk_native
+        chk_nat = self._chk_native_thread
         fp_nat = self.native.fingerprint()
         fp_dev = self.device.fingerprint()
         ok = (
@@ -1253,10 +1083,9 @@ class DualLedger:
             "fingerprint_native": fp_nat,
             "fingerprint_device": fp_dev,
         }
-        if self.follower and self._dev_ring_out is not None:
-            report["hash_log"] = self._hash_ring_check()
-            if not report["hash_log"]["ok"]:
-                report["verified"] = False
+        report["hash_log"] = self._hash_ring_check()
+        if not report["hash_log"]["ok"]:
+            report["verified"] = False
         if self._probe_out:
             report["commitments"] = self._commitment_probe_check()
             if not report["commitments"]["ok"]:

@@ -2227,9 +2227,8 @@ class DeviceLedger(HostLedgerBase):
         self.launch_clock = None
         # Start each batch's device->host result copy AT DISPATCH so a
         # reply-serving driver (the VSR replica) drains landed buffers
-        # instead of paying sync round trips. OPT-IN: on transports where
-        # the first d2h permanently degrades dispatch (see bench.py), a
-        # fetch-free driver (the flagship benchmark) must never trigger it.
+        # instead of paying sync round trips. OPT-IN: a fetch-free
+        # driver (the dual backend's applier) must never trigger it.
         self.prefetch_results = False
 
     # ------------------------------------------------------------------

@@ -798,7 +798,7 @@ def run_chaos(
             log(f"cdc stream verification FAILED (reported, not fatal): "
                 f"{cdc_error[:200]}")
 
-        if backend in ("dual", "native+device"):
+        if backend == "dual":
             bad = {
                 k: v for k, v in parity.items()
                 if not v["verified"] or v["hash_log_ok"] is False
