@@ -528,6 +528,9 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     ("device.launch_busy_us", "histogram", "us"),
     ("loop.fetch_s", "counter", "s"),
     ("bus.frame_recv_us", "histogram", "us"),
+    ("ledger.lookup_deferred", "counter", ""),
+    ("ledger.lookup_inline", "counter", ""),
+    ("commit.group.replies_ahead", "counter", "ops"),
 ])
 def test_new_metric_names_are_cataloged(name, kind, unit):
     assert name in CATALOG, name

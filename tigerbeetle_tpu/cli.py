@@ -1026,13 +1026,13 @@ def cmd_start(args) -> int:
             loop_stats.add("busy_s", time.monotonic() - t0)
             loop_stats.add("turns")
         if n == 0 and busy:
-            # Bus idle: flush once the whole window's device results are
-            # computed — ONE device->host round trip then drains every
-            # in-flight batch (fetching earlier would pay a round trip
-            # per batch on high-latency transports).
-            if replica.commits_ready():
-                t0 = time.monotonic()
-                replica.flush_commits()
+            # Bus idle: send every reply whose OWN result is ready (WAL
+            # durable, device handle computed), oldest first, and stop at
+            # the first that is not — a create's reply does not wait for
+            # the lookup dispatched behind it. Never blocks: the loop
+            # keeps reading frames while the chip computes.
+            t0 = time.monotonic()
+            if replica.flush_commits(only_ready=True):
                 loop_stats.add("busy_s", time.monotonic() - t0)
             elif replica._inflight:
                 time.sleep(0.0002)

@@ -592,6 +592,9 @@ CATALOG = {
     "commit.group.wave_dispatches": (
         "counter", "waves", "waves dispatched across wave-scheduled ops"
     ),
+    "commit.group.replies_ahead": (
+        "counter", "ops", "ops finalized while the newest in-flight op's result was not ready"
+    ),
     # conflict-wave scheduler (models/ledger.py HazardTracker.plan +
     # DeviceLedger._execute_waves)
     "waves.batches": ("counter", "", "batches executed through the wave scheduler"),
@@ -692,6 +695,12 @@ CATALOG = {
     ),
     "ledger.pending_registry_rows": (
         "gauge", "rows", "unresolved pending transfers the planner's registry holds"
+    ),
+    "ledger.lookup_deferred": (
+        "counter", "", "lookups launched and left in flight (lookup_async), read back at finalize"
+    ),
+    "ledger.lookup_inline": (
+        "counter", "", "lookups answered before the call returned (lookup_rows)"
     ),
     # change-data-capture (tigerbeetle_tpu/cdc/pump.py)
     "cdc.ops": ("counter", "ops", "committed ops streamed (gap spans excluded)"),

@@ -1947,6 +1947,25 @@ class HazardTracker:
             )
 
 
+class PendingLookup:
+    """Handle for a lookup launched against the tables and not yet read
+    back: the kernel's three device outputs and how many lanes were asked
+    for. The replica keeps it in its in-flight queue like a PendingBatch
+    and finalizes it when `is_ready()` (DeviceLedger.lookup_finish)."""
+
+    __slots__ = ("n", "found", "rows", "resolved")
+
+    def __init__(self, n: int, found, rows, resolved):
+        self.n = n
+        self.found = found
+        self.rows = rows
+        self.resolved = resolved
+
+    def is_ready(self) -> bool:
+        # one program wrote all three: its largest output stands for them
+        return self.rows.is_ready()
+
+
 class HostLedgerBase:
     """Shared host-side driver surface of the single-chip and sharded
     ledgers: prepare-timestamp bookkeeping (reference:
@@ -1964,31 +1983,53 @@ class HostLedgerBase:
     def _pad_for(self, n: int) -> int:
         return self.pad_to if self.pad_to is not None else _next_pow2(n)
 
-    def _lookup(self, kernel, ids: list[int]):
-        n_pad = self._pad_for(len(ids))
-        found, rows, resolved = kernel(self.state, ids_to_batch(ids, n_pad))
-        # resolved is a scalar (device kernel: jnp.all over its lanes) or
-        # per-lane (sharded kernel) — only the REQUESTED lanes matter: the
-        # padding lanes probe key 0, whose single fixed window can fill with
-        # tombstones over time.
-        res = np.asarray(resolved).reshape(-1)
-        if not (res if res.size == 1 else res[: len(ids)]).all():
-            raise RuntimeError("lookup probe-window overflow: grow the table")
-        found = np.asarray(found)[: len(ids)]
-        rows = np.asarray(rows)[: len(ids)]
-        return found, rows
-
-    def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
-        """Found objects' 128-byte wire rows, request order, missing skipped
-        (reference: src/state_machine.zig:701-736) — the reply body, with no
-        per-row Python object round-trip."""
-        kernel = (
+    def _lookup_kernel(self, operation: Operation):
+        return (
             self.kernels.lookup_accounts
             if operation == Operation.lookup_accounts
             else self.kernels.lookup_transfers
         )
-        found, rows = self._lookup(kernel, ids)
+
+    def _lookup_launch(self, kernel, ids: list[int]) -> PendingLookup:
+        """Launch a lookup against the tables as they stand (after every
+        commit dispatched before it, before any dispatched after: one
+        device stream). The outputs stay on the device; nothing waits."""
+        n = len(ids)
+        return PendingLookup(
+            n, *kernel(self.state, ids_to_batch(ids, self._pad_for(n)))
+        )
+
+    @staticmethod
+    def _lookup_finish(pending: PendingLookup):
+        """Materialize a launched lookup's requested lanes (blocks until
+        the chip has run it)."""
+        n = pending.n
+        # resolved is a scalar (device kernel: jnp.all over its lanes) or
+        # per-lane (sharded kernel) — only the REQUESTED lanes matter: the
+        # padding lanes probe key 0, whose single fixed window can fill with
+        # tombstones over time.
+        res = np.asarray(pending.resolved).reshape(-1)
+        if not (res if res.size == 1 else res[:n]).all():
+            raise RuntimeError("lookup probe-window overflow: grow the table")
+        return np.asarray(pending.found)[:n], np.asarray(pending.rows)[:n]
+
+    def _lookup(self, kernel, ids: list[int]):
+        return self._lookup_finish(self._lookup_launch(kernel, ids))
+
+    def lookup_finish(self, pending: PendingLookup) -> bytes:
+        """A launched lookup's reply body: found objects' 128-byte wire
+        rows, request order, missing skipped (reference:
+        src/state_machine.zig:701-736), with no per-row Python object
+        round-trip. Blocks until the chip has run it; the probe-window
+        overflow raises here."""
+        found, rows = self._lookup_finish(pending)
         return rows[found].tobytes()
+
+    def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
+        """The reply body of a lookup, launched and read back at once."""
+        return self.lookup_finish(
+            self._lookup_launch(self._lookup_kernel(operation), ids)
+        )
 
     def lookup_accounts(self, ids: list[int]) -> list[types.Account]:
         found, rows = self._lookup(self.kernels.lookup_accounts, ids)
@@ -2145,6 +2186,10 @@ class DeviceLedger(HostLedgerBase):
         }
         self._c_probe_rejected = metrics.counter("ledger.group_probe_rejected")
         self._g_registry = metrics.gauge("ledger.pending_registry_rows")
+        # which way a lookup took: launched and left in the caller's
+        # in-flight queue (lookup_async), or answered before returning
+        self._c_lookup_deferred = metrics.counter("ledger.lookup_deferred")
+        self._c_lookup_inline = metrics.counter("ledger.lookup_inline")
 
     def _note_launch(self, handle, t_launch_ns: int, batches: int,
                      slots: int, tier: str | None = None) -> None:
@@ -2896,7 +2941,27 @@ class DeviceLedger(HostLedgerBase):
 
     # -- lookups (spill-aware: HBM miss falls back to the LSM store) --
 
+    def lookup_async(self, operation: Operation,
+                     ids: list[int]) -> PendingLookup | None:
+        """Launch a lookup and return at once; `lookup_finish` reads it
+        back. None = this lookup must be answered inline: a transfers
+        lookup over a spill store merges rows from the LSM tree, which may
+        raise GridBlockCorrupt, and the replica's stall-and-retry for that
+        lives at dispatch."""
+        if self.spill is not None and operation == Operation.lookup_transfers:
+            return None
+        self._c_lookup_deferred.add()
+        pending = self._lookup_launch(self._lookup_kernel(operation), ids)
+        if self.prefetch_results:
+            try:  # the reply's rows start home right behind the kernel
+                pending.rows.copy_to_host_async()
+                pending.found.copy_to_host_async()
+            except (AttributeError, RuntimeError):
+                pass  # no async copy: lookup_finish pays the sync cost
+        return pending
+
     def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
+        self._c_lookup_inline.add()
         if self.spill is None or operation == Operation.lookup_accounts:
             return super().lookup_rows(operation, ids)
         found, rows = self._lookup(self.kernels.lookup_transfers, ids)

@@ -15,7 +15,8 @@ wire encoding:
 
 The backend is anything with the ledger driver API (execute_dense /
 prepare / lookup_* — device backends also expose lookup_rows, the
-zero-copy reply path): the single-chip DeviceLedger, the multi-chip
+zero-copy reply path, and DeviceLedger lookup_async / lookup_finish, the
+same reply left in flight): the single-chip DeviceLedger, the multi-chip
 ShardedLedger, or the scalar OracleStateMachine — so VSR, the REPL, and the
 client server all run unchanged on any of them, and wire-level parity tests
 can diff backends byte-for-byte.
@@ -43,6 +44,7 @@ _EVENT_DTYPES = {
     Operation.create_accounts: ACCOUNT_DTYPE,
     Operation.create_transfers: TRANSFER_DTYPE,
 }
+_LOOKUP_OPS = (Operation.lookup_accounts, Operation.lookup_transfers)
 _RESULT_DTYPES = {
     Operation.create_accounts: CREATE_ACCOUNTS_RESULT_DTYPE,
     Operation.create_transfers: CREATE_TRANSFERS_RESULT_DTYPE,
@@ -129,7 +131,7 @@ class StateMachine:
     def input_valid(self, operation: Operation, body: bytes) -> bool:
         if operation in _EVENT_DTYPES:
             event_size = EVENT_SIZE
-        elif operation in (Operation.lookup_accounts, Operation.lookup_transfers):
+        elif operation in _LOOKUP_OPS:
             event_size = ID_SIZE
         else:
             return False
@@ -162,14 +164,26 @@ class StateMachine:
     def commit_async(self, operation: Operation, timestamp: int, body: bytes):
         """Dispatch a commit WITHOUT materializing results (the device
         launch is queued; results stay on device). Returns a handle for
-        commit_finish. Only create ops are truly asynchronous; lookups are
-        reads and compute their reply inline (the handle is the bytes).
+        commit_finish: `(operation, pending)` when the backend left the
+        work in flight, the reply bytes when it answered already. Creates
+        are asynchronous on every backend with `execute_async`; a lookup is
+        where the backend offers `lookup_async` and takes it (DeviceLedger:
+        not a transfers lookup over a spill store) — it then reads the
+        tables as they stand after every op dispatched before it, and its
+        reply is built at commit_finish. Elsewhere (oracle, native, dual,
+        sharded) a lookup computes its reply inline.
         This is the replica's commit-stage overlap seam (reference:
         src/vsr/replica.zig:3045-3103 commit_dispatch stages)."""
+        if operation in _LOOKUP_OPS:
+            launch = getattr(self.backend, "lookup_async", None)
+            pending = launch(operation, decode_ids(body)) if launch else None
+            if pending is None:
+                return self.commit(operation, timestamp, body)
+            return (operation, pending)
         if operation not in _EVENT_DTYPES or not hasattr(
             self.backend, "execute_async"
         ):
-            return self.commit(operation, timestamp, body)  # reads / oracle
+            return self.commit(operation, timestamp, body)  # oracle
         if getattr(self.backend, "zero_copy_events", False):
             # backend only reads the rows: skip the 1 MiB defensive copy
             events = np.frombuffer(body, dtype=_EVENT_DTYPES[operation])
@@ -216,7 +230,10 @@ class StateMachine:
         """Pre-materialize several commit_async handles with one
         device->host transfer (see DeviceLedger.drain_many); the
         subsequent per-handle commit_finish calls hit the cache."""
-        pendings = [h[1] for h in handles if not isinstance(h, bytes)]
+        pendings = [
+            h[1] for h in handles
+            if not isinstance(h, bytes) and h[0] in _EVENT_DTYPES
+        ]
         if pendings and hasattr(self.backend, "drain_many"):
             self.backend.drain_many(pendings)
 
@@ -225,6 +242,8 @@ class StateMachine:
         if isinstance(handle, bytes):
             return handle
         operation, pending = handle
+        if operation in _LOOKUP_OPS:
+            return self.backend.lookup_finish(pending)
         if hasattr(self.backend, "drain_reply"):
             # vectorized sparse encoding; empty for all-success without
             # materializing dense codes at all
@@ -247,7 +266,7 @@ class StateMachine:
             return encode_results(
                 [(i, c) for i, c in enumerate(dense) if c], operation
             )
-        if operation in (Operation.lookup_accounts, Operation.lookup_transfers):
+        if operation in _LOOKUP_OPS:
             ids = decode_ids(body)
             if hasattr(self.backend, "lookup_rows"):  # device backends:
                 return self.backend.lookup_rows(operation, ids)  # raw wire rows

@@ -238,9 +238,10 @@ class Replica:
         # — the launch is queued, the host returns immediately) and their
         # results drained later, so the journal write + broadcast of op N+1
         # overlap the device execution of op N. 0 = fully synchronous
-        # (deterministic tests). The event loop calls flush_commits() when
-        # idle; state-changing transitions (checkpoint, view change, state
-        # sync) flush first.
+        # (deterministic tests). The event loop, when idle, finalizes the
+        # entries whose own results are ready (flush_commits(only_ready=
+        # True)); state-changing transitions (checkpoint, view change,
+        # state sync) drain the whole queue first, blocking.
         self.commit_window = 0
         # Group-commit fuse window (ns): with commit_window > 0, a
         # quorum-ready run of fewer than GROUP_MAX create_transfers
@@ -286,8 +287,11 @@ class Replica:
             # conflict-wave scheduler (dependent transfers executed as
             # dependency-ordered waves instead of a whole-batch serial
             # scan), and the total waves those ops dispatched
+            # replies_ahead: entries finalized by the non-blocking flush
+            # while the newest in-flight op's result was still being
+            # computed (a reply that did not wait for a younger op)
             ("fused_ops", "solo_ops", "fused_groups", "fuse_holds",
-             "fuse_expired", "wave_ops", "wave_dispatches"),
+             "fuse_expired", "wave_ops", "wave_dispatches", "replies_ahead"),
         )
         # commit-pipeline timing histograms (metrics.py CATALOG for units)
         self._h_quorum = self.metrics.histogram("replica.quorum_wait_us")
@@ -2173,8 +2177,9 @@ class Replica:
 
     @staticmethod
     def _handle_ready(h) -> bool:
-        """Readiness probe for a commit handle (shared by the event loop's
-        commits_ready and the non-blocking flush)."""
+        """Readiness probe for a commit handle: reply bytes are ready, a
+        deferred lookup says so itself (PendingLookup.is_ready), a create's
+        device summary or results array is asked."""
         if h is None or isinstance(h, bytes):
             return True
         p = h[1]
@@ -2194,14 +2199,31 @@ class Replica:
             return False  # finalize would block on the WAL fsync
         return self._handle_ready(entry["handle"])
 
-    def flush_commits(self, keep: int = 0, only_ready: bool = False) -> None:
-        """Finalize queued async commits (oldest first) until at most
-        `keep` remain in flight. The event loop calls this when the bus has
-        no more incoming frames; _maybe_commit_pipeline calls it with
-        keep=commit_window AND only_ready=True — the dispatch path must not
-        BLOCK on its own group's device compute (that serialized recv of
-        the next window behind execution of this one). A hard cap of
-        4x keep still blocks to bound the in-flight window."""
+    def _finalize_and_reply(self, entry: dict) -> None:
+        wire = self._commit_finalize(entry)
+        if wire is not None and entry["to_client"]:
+            lt = entry.get("lt", 0)
+            if lt:
+                h = entry["header"]
+                self.latency.egress(lt, h.client, h.context)
+            self.network.send(self.replica, entry["header"].client, wire)
+
+    def flush_commits(self, keep: int = 0, only_ready: bool = False) -> int:
+        """Finalize queued async commits (oldest first, so replies leave in
+        op order) until at most `keep` remain in flight; returns how many
+        it finalized.
+
+        only_ready=True never blocks: it finalizes the longest prefix whose
+        OWN results are ready (WAL durable, device handle computed) and
+        stops at the first entry that is not — a reply does not wait for a
+        younger op's result. The event loop's idle branch and the tick call
+        it with keep=0; the dispatch path with keep=commit_window, where a
+        hard cap of 4x keep still blocks to bound the in-flight window.
+
+        only_ready=False blocks until the queue is down to `keep`
+        (checkpoint, restore, status change, shutdown), fetching the
+        window's create results in one go first."""
+        done = 0
         if only_ready:
             hard_cap = 4 * keep if keep else (1 << 30)
             while (
@@ -2211,20 +2233,16 @@ class Replica:
                     or len(self._inflight) > hard_cap
                 )
             ):
-                entry = self._inflight.popleft()
-                wire = self._commit_finalize(entry)
-                if wire is not None and entry["to_client"]:
-                    lt = entry.get("lt", 0)
-                    if lt:
-                        h = entry["header"]
-                        self.latency.egress(lt, h.client, h.context)
-                    self.network.send(
-                        self.replica, entry["header"].client, wire
-                    )
-            return
+                if len(self._inflight) > 1 and not self._handle_ready(
+                    self._inflight[-1]["handle"]
+                ):
+                    # the newest op's result is still being computed:
+                    # this reply leaves ahead of it
+                    self.group_stats.add("replies_ahead")
+                self._finalize_and_reply(self._inflight.popleft())
+                done += 1
+            return done
         n_final = len(self._inflight) - keep
-        if n_final <= 0:
-            return
         if n_final > 1:
             # one device->host round trip for the whole window, not one
             # per batch (high-latency transports)
@@ -2234,14 +2252,9 @@ class Replica:
                 if e["handle"] is not None
             ])
         while len(self._inflight) > keep:
-            entry = self._inflight.popleft()
-            wire = self._commit_finalize(entry)
-            if wire is not None and entry["to_client"]:
-                lt = entry.get("lt", 0)
-                if lt:
-                    h = entry["header"]
-                    self.latency.egress(lt, h.client, h.context)
-                self.network.send(self.replica, entry["header"].client, wire)
+            self._finalize_and_reply(self._inflight.popleft())
+            done += 1
+        return done
 
     def pump_commits(self) -> None:
         """Event-loop hook: commit whatever reached quorum during this
@@ -2330,16 +2343,6 @@ class Replica:
             )
         self._fuse_clear()
         return False
-
-    def commits_ready(self) -> bool:
-        """True when the NEWEST in-flight commit's device results are
-        computed — batches execute in order, so the whole window is then
-        fetchable in one transfer. The event loop uses this to defer
-        flushes until one round trip can drain everything (fetching
-        mid-compute would serialize a round trip per batch)."""
-        if not self._inflight:
-            return False
-        return self._handle_ready(self._inflight[-1]["handle"])
 
     # ------------------------------------------------------------------
     # view change (reference: src/vsr/replica.zig:1595-1924)
