@@ -373,7 +373,7 @@ def test_launch_clock_on_a_device_ledger_agrees_with_the_launch_counters():
     assert launches == c["device.commit_launches"] == 3
     assert batches == c["device.commit_batches"] == 7
     assert c["device.commit_slots"] == 18
-    assert 0 < busy_s <= wall and busy_s == pytest.approx(hist_s, rel=1e-4)
+    assert 0 < busy_s <= wall and busy_s == pytest.approx(hist_s, rel=1e-4, abs=1e-6)
     assert c["loop.fetch_s"] > 0  # the drains above went through _fetch
 
 
@@ -444,9 +444,12 @@ SMALL = ("--account-slots-log2", "10", "--transfer-slots-log2", "12",
          "--grid-mb", "8")
 
 
-@pytest.mark.parametrize("backend", ["device", "dual"])
+@pytest.mark.parametrize("backend", ["device", "dual", "sharded"])
 def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
         backend, tmp_path):
+    # `sharded`: four shards on the suite's CPU devices (conftest's
+    # XLA_FLAGS reach the child through the environment)
+    shards = ("--shards", "4") if backend == "sharded" else ()
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
     env.update(PYTHONPATH=REPO, TB_PARENT_WATCHDOG="1", TB_JAX_PLATFORM="cpu")
     path = str(tmp_path / "d.tigerbeetle")
@@ -459,8 +462,8 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     trace_dir = str(tmp_path / "trace")
     proc = subprocess.Popen(
         [sys.executable, "-m", "tigerbeetle_tpu", "start",
-         "--addresses", f"127.0.0.1:{port}", "--backend", backend, *SMALL,
-         "--device-trace", trace_dir, "--device-trace-s", "600", path],
+         "--addresses", f"127.0.0.1:{port}", "--backend", backend, *shards,
+         *SMALL, "--device-trace", trace_dir, "--device-trace-s", "600", path],
         cwd=REPO, env=env, start_new_session=True,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
     try:
@@ -507,8 +510,19 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     assert not emitted - set(CATALOG), emitted - set(CATALOG)
     names = {name for name, *_ in _tb_events(trace_dir)}
     assert "tb.replica.commit_dispatch" in names, names
-    assert "tb.ledger.solo_launch" in names or "tb.ledger.group_launch" in names
-    assert "tb.ledger.plan" in names  # the planner, inside the launch span
+    if backend == "sharded":
+        # one synchronous launch a batch, every one in the fast tier, and
+        # the owner hash's skew beside them
+        assert "tb.ledger.sharded_launch" in names, names
+        assert c["device.commit_launches"] == c["device.commit_batches"]
+        assert c["ledger.tier.fast"] == sent
+        g = stats["metrics"]["gauges"]
+        assert g["sharded.xfer_rows_max"] >= g["sharded.xfer_rows_mean"] > 0
+        assert 4 * g["sharded.xfer_rows_mean"] == 16 * sent  # every row, once
+    else:
+        assert ("tb.ledger.solo_launch" in names
+                or "tb.ledger.group_launch" in names)
+        assert "tb.ledger.plan" in names  # the planner, inside the launch span
     if backend == "dual":
         assert "tb.applier.wait_work" in names and "tb.shadow.upload" in names
     else:

@@ -181,7 +181,7 @@ def test_sharded_fast_tier_and_state_allocate_per_shard(topo):
     )
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)  # noqa: E731
-    compiled = kernels.commit_transfers_fast.lower(
+    compiled = kernels.commit_transfers_fast.fn.lower(
         state, {"rows": sds((N_PAD, ledger.ROW_WORDS), jnp.uint32)},
         sds((), jnp.int32), sds((), jnp.uint64),
     ).compile()

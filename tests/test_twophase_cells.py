@@ -1,9 +1,12 @@
-"""The two cells PR 30 adds, rehearsed end to end on the CPU at a tiny
-geometry through benchmarks/run.py (a real `start --backend device`
-server, the cell's own traffic file, the reference's replay): `correct`
-true, the cell's metrics, and the control `lost_ack` not correct. The
-rehearsal hook is the one benchmarks/tests/test_yardstick.py uses (that
-directory is no package, so its few lines are repeated here).
+"""The two cells PR 30 adds and the one PR 34 adds, rehearsed end to end on
+the CPU at a tiny geometry through benchmarks/run.py (a real `start
+--backend device` or `--backend sharded --shards 4` server, the cell's own
+traffic file, the reference's replay): `correct` true, the cell's metrics,
+and the control `lost_ack` not correct. The rehearsal hook is the one
+benchmarks/tests/test_yardstick.py uses (that directory is no package, so
+its few lines are repeated here). The sharded server finds its four CPU
+devices through the XLA_FLAGS tests/conftest.py puts into the environment,
+which the served child inherits; TINY's geometry is then per shard.
 """
 
 import argparse
@@ -33,6 +36,10 @@ COUNTED = {
     "default_onpath.plain_rate": {
         "gen_late_ms.rate", "wire_ms.rate", "commit_wait_ms.rate",
         "kernel_ms_window.rate", "device_idle_window.rate"},
+    "sharded4.plain_sat16": {
+        "client_retries.sat", "create_p90_ms.sat", "loop_busy_share.sat",
+        "loop_fetch_share.sat", "kernel_ms_window.sat",
+        "device_idle_window.sat", "window_compiles", "shard_rows_skew.sat"},
 }
 TRACED = {
     "twophase_onpath.twophase_sat16": {
@@ -43,11 +50,16 @@ TRACED = {
         "frame_recv_ms.rate", "launches_per_batch.rate",
         "kernel_ms_per_batch.rate", "commit_kernels_roofline.rate",
         "device_idle_share.rate", "idle_unnamed_share.rate"},
+    "sharded4.plain_sat16": {
+        "kernel_ms_per_batch.sat", "kernel_ms_late_over_early.sat",
+        "device_idle_share.sat", "idle_unnamed_share.sat",
+        "sharded_kernels_roofline.sat"},
 }
 END_TO_END = {
     "twophase_onpath.twophase_sat16": {"committed_tps", "setup_s"},
     "default_onpath.plain_rate": {"batch_p50_ms", "batch_p90_ms",
                                   "lookup_p50_ms", "setup_s"},
+    "sharded4.plain_sat16": {"committed_tps", "setup_s"},
 }
 
 
@@ -90,5 +102,15 @@ def test_new_cell_rehearsed_is_correct_and_reports_its_metrics(workload, capfd):
         assert metrics["plan_ms_per_batch.sat"] > 0.0
         assert metrics["kernel_ms_pv_window.sat"] > 0.0
         assert 32 * 64 <= metrics["pending_registry_rows.sat"] <= (33 + 16) * 64
+    elif workload.startswith("sharded4"):
+        # four devices hold the state; every commit is one synchronous
+        # launch, waited for on the event loop; the owner hash spreads the
+        # rows evenly
+        assert result["device"]["count"] == 4
+        assert metrics["loop_fetch_share.sat"] > 0.0
+        assert metrics["kernel_ms_window.sat"] > 0.0
+        assert 0.0 <= metrics["device_idle_window.sat"] < 100.0
+        assert metrics["window_compiles"] == 0.0
+        assert 1.0 <= metrics["shard_rows_skew.sat"] < 1.2
     else:
         assert result["attempted"] == 2 * 20 * 3  # a create and a lookup a tick

@@ -702,6 +702,13 @@ CATALOG = {
     "ledger.lookup_inline": (
         "counter", "", "lookups answered before the call returned (lookup_rows)"
     ),
+    # the sharded ledger (parallel/mesh.py): the owner hash's skew
+    "sharded.xfer_rows_max": (
+        "gauge", "rows", "transfer rows charged to the fullest shard"
+    ),
+    "sharded.xfer_rows_mean": (
+        "gauge", "rows", "transfer rows charged to a shard, mean over shards"
+    ),
     # change-data-capture (tigerbeetle_tpu/cdc/pump.py)
     "cdc.ops": ("counter", "ops", "committed ops streamed (gap spans excluded)"),
     "cdc.records": ("counter", "records", "change records accepted by the sink"),

@@ -1969,12 +1969,68 @@ class PendingLookup:
 class HostLedgerBase:
     """Shared host-side driver surface of the single-chip and sharded
     ledgers: prepare-timestamp bookkeeping (reference:
-    src/state_machine.zig:336-343) and the lookup wrappers (reference:
-    src/state_machine.zig:701-736). Subclasses provide `state`,
-    `kernels.lookup_accounts/lookup_transfers`, and optionally `pad_to`."""
+    src/state_machine.zig:336-343), the lookup wrappers (reference:
+    src/state_machine.zig:701-736) and the launch bookkeeping (what a commit
+    launch carried, its device time, the blocking reply read). Subclasses
+    provide `state`, `kernels.lookup_accounts/lookup_transfers`, optionally
+    `pad_to`, and call `_bind_counters(self.metrics)` when constructed."""
 
     pad_to: int | None = None
     prepare_timestamp: int = 0
+
+    # observability seams (tigerbeetle_tpu/metrics.py, tracer.py);
+    # instrument() re-points them at a shared registry
+    metrics = NULL_METRICS
+    tracer = NULL_TRACER
+    # metrics.LaunchClock, installed by the serving process only
+    # (cli.cmd_start): books each launch's device time from a
+    # completion thread. None everywhere else — the simulator's
+    # seeded runs stay single-threaded.
+    launch_clock = None
+
+    def instrument(self, metrics, tracer) -> None:
+        self.metrics = metrics
+        self.tracer = tracer
+        # the compile sentinel rides the same registry rebind (warm-up
+        # totals carry over; see CompileSentinel.instrument)
+        COMPILE_SENTINEL.instrument(metrics)
+        self._bind_counters(metrics)
+
+    def _bind_counters(self, metrics) -> None:
+        # the ONE place every backend launches commits through (the dual
+        # applier, the device backend's replica, the sharded ledger): what
+        # a launch carried, counted where it is made
+        self._c_launches = metrics.counter("device.commit_launches")
+        self._c_batches = metrics.counter("device.commit_batches")
+        self._c_slots = metrics.counter("device.commit_slots")
+        self._c_fetch = metrics.counter("loop.fetch_s")
+        # the tier of each create_transfers batch that is LAUNCHED
+        self._c_tier = {
+            tier: metrics.counter(f"ledger.tier.{tier}") for tier in COMMIT_TIERS
+        }
+
+    def _note_launch(self, handle, t_launch_ns: int, batches: int,
+                     slots: int, tier: str | None = None) -> None:
+        """`tier`: the planner's decision for a create_transfers launch
+        (a fused group is `fast` by construction); None for accounts."""
+        self._c_launches.add()
+        self._c_batches.add(batches)
+        self._c_slots.add(slots)
+        if tier is not None:
+            self._c_tier[tier].add(batches)
+        clock = self.launch_clock
+        if clock is not None:
+            clock.launched(handle, t_launch_ns, batches, tier)
+
+    def _fetch(self, dev) -> np.ndarray:
+        """The blocking device->host read of a commit's reply words: the
+        one site where whoever drains (the device backend's event loop)
+        waits for the chip."""
+        t0 = perf_counter_ns()
+        with self.tracer.span("ledger.fetch_replies"):
+            host = np.asarray(dev)
+        self._c_fetch.add((perf_counter_ns() - t0) / 1e9)
+        return host
 
     def prepare(self, operation: Operation, event_count: int) -> None:
         if operation in (Operation.create_accounts, Operation.create_transfers):
@@ -2150,69 +2206,25 @@ class DeviceLedger(HostLedgerBase):
     - "fast" / "serial": force one tier (parity testing).
     """
 
-    # observability seams (tigerbeetle_tpu/metrics.py, tracer.py);
-    # instrument() re-points them at a shared registry — the group-staging
-    # fence waits report there
-    metrics = NULL_METRICS
-    tracer = NULL_TRACER
-
     def instrument(self, metrics, tracer) -> None:
-        self.metrics = metrics
-        self.tracer = tracer
-        # the compile sentinel rides the same registry rebind (warm-up
-        # totals carry over; see CompileSentinel.instrument)
-        COMPILE_SENTINEL.instrument(metrics)
-        self._bind_counters(metrics)
+        super().instrument(metrics, tracer)
         if getattr(self, "spill", None) is not None:
             self.spill.instrument(metrics, tracer)
 
     def _bind_counters(self, metrics) -> None:
+        super()._bind_counters(metrics)
         self._c_h2d = metrics.counter("device.h2d_bytes")
-        # the ONE place both backends launch commits through (the dual
-        # applier and the device backend's replica): what a launch
-        # carried, counted where it is made
-        self._c_launches = metrics.counter("device.commit_launches")
-        self._c_batches = metrics.counter("device.commit_batches")
-        self._c_slots = metrics.counter("device.commit_slots")
-        self._c_fetch = metrics.counter("loop.fetch_s")
         # the planner: every HazardTracker.plan call (a fuse probe that is
-        # rolled back and the solo path's second call count alike), the
-        # tier of each create_transfers batch that is LAUNCHED, and the
+        # rolled back and the solo path's second call count alike) and the
         # pending registry the planner keeps
         self._c_plan_calls = metrics.counter("ledger.plan_calls")
         self._h_plan = metrics.histogram("ledger.plan_us")
-        self._c_tier = {
-            tier: metrics.counter(f"ledger.tier.{tier}") for tier in COMMIT_TIERS
-        }
         self._c_probe_rejected = metrics.counter("ledger.group_probe_rejected")
         self._g_registry = metrics.gauge("ledger.pending_registry_rows")
         # which way a lookup took: launched and left in the caller's
         # in-flight queue (lookup_async), or answered before returning
         self._c_lookup_deferred = metrics.counter("ledger.lookup_deferred")
         self._c_lookup_inline = metrics.counter("ledger.lookup_inline")
-
-    def _note_launch(self, handle, t_launch_ns: int, batches: int,
-                     slots: int, tier: str | None = None) -> None:
-        """`tier`: the planner's decision for a create_transfers launch
-        (a fused group is `fast` by construction); None for accounts."""
-        self._c_launches.add()
-        self._c_batches.add(batches)
-        self._c_slots.add(slots)
-        if tier is not None:
-            self._c_tier[tier].add(batches)
-        clock = self.launch_clock
-        if clock is not None:
-            clock.launched(handle, t_launch_ns, batches, tier)
-
-    def _fetch(self, dev) -> np.ndarray:
-        """The blocking device->host read of a commit's reply words: the
-        one site where whoever drains (the device backend's event loop)
-        waits for the chip."""
-        t0 = perf_counter_ns()
-        with self.tracer.span("ledger.fetch_replies"):
-            host = np.asarray(dev)
-        self._c_fetch.add((perf_counter_ns() - t0) / 1e9)
-        return host
 
     def __init__(
         self,
@@ -2265,11 +2277,6 @@ class DeviceLedger(HostLedgerBase):
         # vet: owner=device-shadow
         self.last_h2d_done_ns = 0
         self._bind_counters(self.metrics)
-        # metrics.LaunchClock, installed by the serving process only
-        # (cli.cmd_start): books each launch's device time from a
-        # completion thread. None everywhere else — the simulator's
-        # seeded runs stay single-threaded.
-        self.launch_clock = None
         # Start each batch's device->host result copy AT DISPATCH so a
         # reply-serving driver (the VSR replica) drains landed buffers
         # instead of paying sync round trips. OPT-IN: a fetch-free

@@ -38,6 +38,8 @@ every shard agrees.
 
 from __future__ import annotations
 
+from time import perf_counter_ns
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,10 +66,12 @@ from tigerbeetle_tpu.models.ledger import (
     HazardTracker,
     HostLedgerBase,
     accounts_to_batch,
+    applied_insert_mask,
     build_stored_transfer,
     key4_from_fields,
     pack_account,
     pack_transfer,
+    sentinel_jit,
     transfers_to_batch,
     unpack_account,
     unpack_transfer,
@@ -186,7 +190,8 @@ class ShardedLedgerKernels:
         def wrap(fn, out_state=True):
             out_specs = (state_spec, P()) if out_state else (P(), P(), P())
             in_specs = (state_spec, P(), P(), P()) if out_state else (state_spec, P())
-            return jax.jit(
+            return sentinel_jit(
+                "sharded" + fn.__name__,
                 shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                           check_vma=False),
                 donate_argnums=(0,) if out_state else (),
@@ -877,16 +882,51 @@ class ShardedLedger(HostLedgerBase):
         self._xfer_used = np.zeros(self.n_shards, dtype=np.int64)
         self._acct_limit = (1 << process.account_slots_log2) // 2
         self._xfer_limit = (1 << process.transfer_slots_log2) // 2
+        self._bind_counters(self.metrics)
+
+    def _bind_counters(self, metrics) -> None:
+        super()._bind_counters(metrics)
+        # the owner hash's skew: rows charged to the fullest transfer
+        # shard and the mean over shards (host counts; no device read)
+        self._g_rows_max = metrics.gauge("sharded.xfer_rows_max")
+        self._g_rows_mean = metrics.gauge("sharded.xfer_rows_mean")
 
     def _shard_counts(self, arr: np.ndarray) -> np.ndarray:
         owners = owner_of_ids_np(arr["id_lo"], arr["id_hi"], self.n_shards)
         return np.bincount(owners, minlength=self.n_shards)
 
     def execute_dense(self, operation, timestamp: int, events) -> list[int]:
+        n = len(events)
+        with self.tracer.span("ledger.sharded_launch", events=n):
+            arr, results = self._launch(operation, timestamp, events)
+        dense = [int(x) for x in self._fetch(results)[:n]]
+        self.check_fault()
+        # Reconcile the conservative per-shard estimate to the exact
+        # ever-applied count (rolled-back inserts tombstone their slot on the
+        # owner shard and still occupy it — see models.ledger.applied_insert_mask).
+        not_applied = ~applied_insert_mask(dense, arr["flags"])
+        if not_applied.any():
+            owners = owner_of_ids_np(
+                arr["id_lo"][not_applied], arr["id_hi"][not_applied], self.n_shards
+            )
+            dec = np.bincount(owners, minlength=self.n_shards)
+            if operation == Operation.create_transfers:
+                self._xfer_used -= dec
+            else:
+                self._acct_used -= dec
+        self._g_rows_max.set(int(self._xfer_used.max()))
+        self._g_rows_mean.set(float(self._xfer_used.mean()))
+        return dense
+
+    def _launch(self, operation, timestamp: int, events):
+        """The host work of one batch up to its dispatch: shard counts,
+        hazard test, rows to the device, the jit call. Returns the wire
+        array and the launched results (still on the device)."""
         from tigerbeetle_tpu import types as t
 
         n = len(events)
         n_pad = _next_pow2(n)
+        tier = None  # the tier of a create_transfers launch
         if operation == Operation.create_transfers:
             arr = events if isinstance(events, np.ndarray) else t.transfers_to_np(events)
             counts = self._shard_counts(arr)
@@ -895,14 +935,14 @@ class ShardedLedger(HostLedgerBase):
                     "a transfer shard is at its load-factor limit: grow "
                     "ConfigProcess.transfer_slots_log2 (per-shard capacity)"
                 )
-            mode = self.mode
-            if mode == "auto":
-                mode = "serial" if self.hazards.transfers_hazard(arr) else "fast"
+            tier = self.mode
+            if tier == "auto":
+                tier = "serial" if self.hazards.transfers_hazard(arr) else "fast"
             # the [stats] `split` surface: which tier each batch took
-            self.hazards.plan_stats[mode] += 1
+            self.hazards.plan_stats[tier] += 1
             fn = (
                 self.kernels.commit_transfers_fast
-                if mode == "fast"
+                if tier == "fast"
                 else self.kernels.commit_transfers_serial
             )
             batch = transfers_to_batch(arr, n_pad)
@@ -928,27 +968,12 @@ class ShardedLedger(HostLedgerBase):
             self._acct_used += counts
         else:
             raise AssertionError(operation)
+        t_launch = perf_counter_ns()  # rows on their way: kernel next
         self.state, results = fn(
             self.state, batch, jnp.int32(n), jnp.uint64(timestamp)
         )
-        dense = [int(x) for x in np.asarray(results)[:n]]
-        self.check_fault()
-        # Reconcile the conservative per-shard estimate to the exact
-        # ever-applied count (rolled-back inserts tombstone their slot on the
-        # owner shard and still occupy it — see models.ledger.applied_insert_mask).
-        from tigerbeetle_tpu.models.ledger import applied_insert_mask
-
-        not_applied = ~applied_insert_mask(dense, arr["flags"])
-        if not_applied.any():
-            owners = owner_of_ids_np(
-                arr["id_lo"][not_applied], arr["id_hi"][not_applied], self.n_shards
-            )
-            dec = np.bincount(owners, minlength=self.n_shards)
-            if operation == Operation.create_transfers:
-                self._xfer_used -= dec
-            else:
-                self._acct_used -= dec
-        return dense
+        self._note_launch(results, t_launch, 1, 1, tier)
+        return arr, results
 
     def check_fault(self) -> None:
         raise_on_fault(int(np.asarray(self.state["fault"])), "sharded ledger")
