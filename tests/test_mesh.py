@@ -338,3 +338,178 @@ def test_sharded_chain_rollback_spans_shards(mesh):
     accounts_d, transfers_d, _ = dev.extract()
     assert accounts_d == oracle.accounts
     assert transfers_d == oracle.transfers
+
+
+# ----------------------------------------------------------------------
+# execute_async / drain: a commit left in flight (the contract DeviceLedger
+# has; the drain itself is HostLedgerBase's, written once)
+# ----------------------------------------------------------------------
+
+
+def _plain(first_id, n, accounts=24, **kw):
+    return [
+        Transfer(id=first_id + i, debit_account_id=1 + i % accounts,
+                 credit_account_id=1 + (i + 7) % accounts, amount=3,
+                 ledger=1, code=1, **kw)
+        for i in range(n)
+    ]
+
+
+def _stream(kind):
+    """Batches of (operation, events); the first loads 24 accounts."""
+    batches = [(Operation.create_accounts,
+                [Account(id=i, ledger=1, code=1) for i in range(1, 25)])]
+    batches += [(Operation.create_transfers, _plain(1000 + 40 * k, 40))
+                for k in range(3)]
+    if kind == "failures":
+        # ids that exist (batch 1's), and accounts that do not
+        again = _plain(1000, 12)
+        lost = [Transfer(id=5000 + i, debit_account_id=900 + i,
+                         credit_account_id=2, amount=1, ledger=1, code=1)
+                for i in range(9)]
+        batches.append((Operation.create_transfers,
+                        again + lost + _plain(6000, 10)))
+        batches.append((Operation.create_accounts,
+                        [Account(id=i, ledger=1, code=1) for i in range(20, 30)]))
+        batches.append((Operation.create_transfers, _plain(7000, 16)))
+    elif kind == "chain_rollback":
+        # a linked chain whose last member fails: the serial tier rolls the
+        # applied members back on their owner shards (tombstones stay)
+        chain = _plain(8000, 6, flags=1) + [
+            Transfer(id=8006, debit_account_id=1, credit_account_id=1,
+                     amount=1, ledger=1, code=1)]
+        batches.append((Operation.create_transfers,
+                        _plain(8100, 5) + chain + _plain(8200, 5)))
+        batches.append((Operation.create_transfers, _plain(8000, 8)))
+    else:
+        assert kind == "all_ok"
+    return batches
+
+
+@pytest.mark.parametrize("kind", ["all_ok", "failures", "chain_rollback"])
+def test_sharded_batches_in_flight_equal_one_at_a_time(mesh, kind):
+    """K batches dispatched with execute_async BEFORE any drain give what K
+    execute_dense calls give: codes, state, clock, per-shard occupancy."""
+    from tigerbeetle_tpu.models.ledger import PendingBatch
+
+    batches = _stream(kind)
+    oracle = OracleStateMachine()
+    sync, flight = ShardedLedger(mesh, PROCESS), ShardedLedger(mesh, PROCESS)
+    ts, want, pendings = 1_000_000, [], []
+    for op, events in batches:
+        ts += len(events)
+        want.append(sync.execute_dense(op, ts, events))
+        assert want[-1] == oracle.execute_dense(op, ts, events)
+        pendings.append(flight.execute_async(op, ts, events))
+    assert all(isinstance(p, PendingBatch) and p.dense is None for p in pendings)
+    if kind != "all_ok":
+        assert any(any(codes) for codes in want)
+        # until the drains the charge is the conservative one
+        assert flight._xfer_used.sum() > sync._xfer_used.sum()
+    got = [flight.drain(p) for p in pendings]
+    assert got == want
+    assert [p.failures for p in pendings] == [sum(map(bool, w)) for w in want]
+    assert flight.extract() == sync.extract() == (
+        oracle.accounts, oracle.transfers, oracle.posted)
+    assert flight.commit_timestamp == sync.commit_timestamp == oracle.commit_timestamp
+    assert flight._xfer_used.tolist() == sync._xfer_used.tolist()
+    assert flight._acct_used.tolist() == sync._acct_used.tolist()
+    if kind == "chain_rollback":
+        assert flight.hazards.plan_stats["serial"] == 1
+        # the six rolled-back members stay charged: their tombstones occupy
+        assert flight._xfer_used.sum() == len(oracle.transfers) + 6
+    else:
+        assert flight._xfer_used.sum() == len(oracle.transfers)
+    assert flight.drain(pendings[-1]) is got[-1]  # idempotent: cached
+
+
+@pytest.mark.parametrize("operation", [
+    Operation.create_accounts, Operation.create_transfers])
+def test_sharded_state_machine_leaves_creates_in_flight(mesh, operation):
+    """commit_async hands back a handle for both create operations,
+    commit_finish gives the bytes commit gives, and an all-success batch is
+    drained from its two-word summary: the dense codes are never read."""
+    from tigerbeetle_tpu import types
+    from tigerbeetle_tpu.metrics import Metrics
+    from tigerbeetle_tpu.models.ledger import PendingBatch
+    from tigerbeetle_tpu.state_machine import StateMachine
+
+    ledgers = ShardedLedger(mesh, PROCESS), ShardedLedger(mesh, PROCESS)
+    metrics = Metrics()
+    ledgers[1].instrument(metrics, ledgers[1].tracer)
+    sync, flight = (StateMachine(x) for x in ledgers)
+    fetched = []
+    real_fetch = ledgers[1]._fetch
+    ledgers[1]._fetch = lambda dev: fetched.append(dev) or real_fetch(dev)
+
+    accounts = [Account(id=i, ledger=1, code=1) for i in range(1, 25)]
+    if operation == Operation.create_accounts:
+        ok = types.accounts_to_np(accounts).tobytes()
+        bad = types.accounts_to_np(
+            accounts[:5] + [Account(id=77, ledger=0, code=1)]).tobytes()
+    else:
+        body = types.accounts_to_np(accounts).tobytes()
+        assert sync.commit(Operation.create_accounts, 24, body) == \
+            flight.commit(Operation.create_accounts, 24, body) == b""
+        fetched.clear()
+        ok = types.transfers_to_np(_plain(100, 30)).tobytes()
+        bad = types.transfers_to_np(_plain(95, 10)).tobytes()
+    before = metrics.snapshot()["counters"].get("ledger.drain_all_ok", 0)
+
+    handle = flight.commit_async(operation, 1000, ok)
+    assert isinstance(handle, tuple) and handle[0] == operation
+    pending = handle[1]
+    assert isinstance(pending, PendingBatch) and pending.summary is not None
+    assert flight.commit_finish(handle) == sync.commit(operation, 1000, ok) == b""
+    assert len(fetched) == 1 and fetched[0] is pending.summary
+    assert pending.codes_np is None and pending.failures == 0
+    c = metrics.snapshot()["counters"]
+    assert c["ledger.drain_all_ok"] == before + 1
+    assert c.get("ledger.drain_dense", 0) == 0
+
+    # a batch with failures reads its dense codes, once
+    handle = flight.commit_async(operation, 2000, bad)
+    reply = flight.commit_finish(handle)
+    assert reply == sync.commit(operation, 2000, bad) != b""
+    assert fetched[-1] is handle[1].results
+    c = metrics.snapshot()["counters"]
+    assert (c["ledger.drain_all_ok"], c["ledger.drain_dense"]) == (before + 1, 1)
+
+
+def test_sharded_fault_word_raises_at_drain_and_again(mesh):
+    """A nonzero device fault word comes home on the batch's own summary:
+    the dispatch returns a handle, the drain raises, and a second drain
+    raises again instead of returning cached codes."""
+    from tigerbeetle_tpu.models.ledger import FAULT_PROBE
+
+    dev = ShardedLedger(mesh, PROCESS)
+    accounts = [Account(id=i, ledger=1, code=1) for i in range(1, 25)]
+    assert dev.execute_dense(Operation.create_accounts, 24, accounts) == [0] * 24
+    leaf = dev.state["fault"]
+    dev.state["fault"] = jax.device_put(
+        np.asarray(FAULT_PROBE, dtype=leaf.dtype), leaf.sharding)
+    pending = dev.execute_async(Operation.create_transfers, 100, _plain(100, 8))
+    assert pending.dense is None  # dispatched, nothing raised
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="sharded ledger fault"):
+            dev.drain(pending)
+        assert pending.dense is None
+    with pytest.raises(RuntimeError, match="sharded ledger fault"):
+        dev.drain_reply(pending, Operation.create_transfers)
+    # sticky: the faulting batch and everything after are no-ops
+    assert dev.extract()[1] == {}
+    with pytest.raises(RuntimeError, match="sharded ledger fault"):
+        dev.check_fault()
+
+
+def test_sharded_and_device_ledgers_drain_through_one_implementation():
+    from tigerbeetle_tpu.models.ledger import DeviceLedger, HostLedgerBase
+
+    for name in ("drain", "drain_reply", "drain_many", "_drain_all_ok",
+                 "_drain_from_host", "_summarize_fn", "_summarize",
+                 "execute_dense"):
+        assert name in vars(HostLedgerBase), name
+        assert name not in vars(DeviceLedger), name
+        assert name not in vars(ShardedLedger), name
+    # the one hook: how not-applied lanes come off the occupancy charge
+    assert "_uncharge" in vars(DeviceLedger) and "_uncharge" in vars(ShardedLedger)

@@ -137,3 +137,69 @@ def test_sharded_checkpoint_restart_and_resume(mesh8):
         ]).tobytes(),
     )
     assert reply == b""
+
+
+def _drive_creates(mesh8, commit_window: int):
+    """Three sessions, two rounds of one create_transfers each (the second
+    round's first batch re-sends six ids of the first round: `exists`
+    codes), then one lookup. Returns (replica, the replies' bodies in send order, the
+    most creates seen in flight at once)."""
+    from tigerbeetle_tpu.models.ledger import PendingBatch
+
+    cluster = Cluster(replica_count=1, backend_factory=_factory(mesh8))
+    r = cluster.replicas[0]
+    clients = [cluster.add_client() for _ in range(3)]
+    accounts = [types.Account(id=i, ledger=1, code=1) for i in range(1, 25)]
+    _h, reply = cluster.execute(
+        clients[0], Operation.create_accounts,
+        types.accounts_to_np(accounts).tobytes(),
+    )
+    assert reply == b""
+    r.commit_window = commit_window
+    bodies, most = [], 0
+    for rnd in range(2):
+        for k, c in enumerate(clients):
+            first = 500 + 20 * (3 * rnd + k) - 6 * rnd  # round 2 overlaps
+            xfers = [
+                types.Transfer(id=first + i, debit_account_id=1 + i % 24,
+                               credit_account_id=1 + (i + 11) % 24, amount=2,
+                               ledger=1, code=1)
+                for i in range(20)
+            ]
+            c.request(Operation.create_transfers,
+                      types.transfers_to_np(xfers).tobytes())
+            cluster.network.run()
+        r.pump_commits()
+        if commit_window:
+            handles = [e["handle"] for e in r._inflight]
+            assert all(isinstance(h, tuple) and isinstance(h[1], PendingBatch)
+                       for h in handles)
+            most = max(most, len(handles))
+            assert all(c.reply is None for c in clients)
+            r.flush_commits()
+        cluster.network.run()
+        bodies += [c.take_reply()[1] for c in clients]
+    r.commit_window = 0  # `execute` expects its reply at once
+    _h, rows = cluster.execute(
+        clients[0], Operation.lookup_accounts, encode_ids(list(range(1, 25)))
+    )
+    bodies.append(rows)
+    return r, bodies, most
+
+
+@pytest.mark.parametrize("commit_window", [4, 16])
+def test_replica_holds_sharded_creates_in_flight(mesh8, commit_window):
+    """With a commit window the replica leaves more than one sharded create
+    in flight (the backend hands it a handle), and its replies are byte for
+    byte those of the synchronous replica."""
+    r0, want, _ = _drive_creates(mesh8, 0)
+    assert r0.group_stats["solo_ops"] == 0
+    r, got, most = _drive_creates(mesh8, commit_window)
+    assert most == 3 > 1
+    assert r.group_stats["solo_ops"] == 6 and r.group_stats["fused_ops"] == 0
+    assert got == want
+    # round 2's first batch re-sends six ids of round 1's last: `exists`
+    assert want[:3] == [b""] * 3 and len(want[3]) == 6 * 8
+    c = r.metrics.snapshot()["counters"]
+    # the account load and five creates from two words; one from its codes
+    assert (c["ledger.drain_all_ok"], c["ledger.drain_dense"]) == (6, 1)

@@ -511,11 +511,15 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     names = {name for name, *_ in _tb_events(trace_dir)}
     assert "tb.replica.commit_dispatch" in names, names
     if backend == "sharded":
-        # one synchronous launch a batch, every one in the fast tier, and
-        # the owner hash's skew beside them
+        # one launch a batch, left in flight by the replica's window
+        # (solo_ops) and drained from its two-word summary, every one in
+        # the fast tier, and the owner hash's skew beside them
         assert "tb.ledger.sharded_launch" in names, names
         assert c["device.commit_launches"] == c["device.commit_batches"]
         assert c["ledger.tier.fast"] == sent
+        assert c["commit.group.solo_ops"] >= sent
+        assert c["ledger.drain_all_ok"] == sent + 1
+        assert not c.get("ledger.drain_dense")
         g = stats["metrics"]["gauges"]
         assert g["sharded.xfer_rows_max"] >= g["sharded.xfer_rows_mean"] > 0
         assert 4 * g["sharded.xfer_rows_mean"] == 16 * sent  # every row, once
@@ -545,6 +549,8 @@ def test_served_device_trace_holds_tb_spans_and_stats_hold_the_launches(
     ("ledger.lookup_deferred", "counter", ""),
     ("ledger.lookup_inline", "counter", ""),
     ("commit.group.replies_ahead", "counter", "ops"),
+    ("ledger.drain_all_ok", "counter", ""),
+    ("ledger.drain_dense", "counter", ""),
 ])
 def test_new_metric_names_are_cataloged(name, kind, unit):
     assert name in CATALOG, name

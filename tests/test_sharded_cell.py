@@ -5,8 +5,10 @@ limit account among them) through `ShardedLedger` behind the wire state
 machine, against the benchmark's plain reference byte for byte; the
 configuration's fifth guarantee (every row on exactly one shard, the one
 its id hashes to, and the shards' rows together the reference's rows, each
-once); and the launch bookkeeping `ShardedLedger` shares with
-`DeviceLedger`.
+once); and the launch bookkeeping and the drain `ShardedLedger` shares with
+`DeviceLedger`. Every test runs over two drives of the stream: commits left
+in flight behind one another (what the cell runs since PR 35), and one at a
+time.
 """
 
 import json
@@ -34,11 +36,18 @@ SMALL = {"batch_events": 1024, "account_slots_log2": 13,
          "transfer_slots_log2": 13}
 
 
-@pytest.fixture(scope="module")
-def driven():
+WINDOW = 4  # handles left in flight by the `in_flight` drive
+
+
+@pytest.fixture(scope="module", params=["in_flight", "one_at_a_time"])
+def driven(request):
     """One drive of the stream through both: the account load, CREATES
     create_transfers requests, then the cell's own read-back (every
-    account, the ids of every create batch)."""
+    account, the ids of every create batch). `in_flight` drives it as the
+    replica does since PR 35, `commit_async` with a window of handles and
+    `commit_finish` oldest first (a lookup's handle is its reply: inline on
+    this backend, behind every create dispatched before it);
+    `one_at_a_time` through the synchronous `commit`."""
     import jax
     from jax.sharding import Mesh
 
@@ -57,15 +66,25 @@ def driven():
     metrics = Metrics()
     ledger.instrument(metrics, ledger.tracer)
     sm = StateMachine(ledger)
-    requests, got = [], []
+    requests, got, handles = [], [], []
     ts = 1_000_000_000
+
+    def finish(keep: int) -> None:
+        while len(handles) > keep:
+            got.append(sm.commit_finish(handles.pop(0)))
 
     def send(operation, body: bytes, events: int) -> None:
         nonlocal ts
         ts += events
         requests.append(SimpleNamespace(
             operation=int(operation), body=body, ts=ts, op=len(requests) + 1))
-        got.append(sm.commit(Operation(int(operation)), ts, body))
+        if request.param == "one_at_a_time":
+            got.append(sm.commit(Operation(int(operation)), ts, body))
+            return
+        handles.append(sm.commit_async(Operation(int(operation)), ts, body))
+        if events:  # a create: left in flight
+            assert isinstance(handles[-1], tuple)
+        finish(WINDOW)
 
     for arr in stream.account_batches():
         send(Operation.create_accounts, arr.tobytes(), len(arr))
@@ -74,8 +93,10 @@ def driven():
         send(Operation.create_transfers, arr.tobytes(), len(arr))
     for op, ids in bench_run.readback_requests(stream, mix, config, SEED):
         send(op, ids.tobytes(), 0)
+    finish(0)
     want, _fp, ref = check.replay(requests)
-    return SimpleNamespace(config=config, ledger=ledger, metrics=metrics,
+    return SimpleNamespace(drive=request.param,
+                           config=config, ledger=ledger, metrics=metrics,
                            requests=requests, got=got, want=want, ref=ref)
 
 
@@ -153,6 +174,9 @@ def test_sharded_launches_are_booked_where_the_device_ledgers_are(driven):
         == c["device.commit_slots"] == loads + CREATES
     assert c["ledger.tier.fast"] == CREATES and not c["ledger.tier.serial"]
     assert c["loop.fetch_s"] > 0
+    # plain traffic: every batch was drained from its two-word summary
+    assert c["ledger.drain_all_ok"] == loads + CREATES
+    assert not c.get("ledger.drain_dense")
     used = driven.ledger._xfer_used
     assert g["sharded.xfer_rows_max"] == used.max()
     assert g["sharded.xfer_rows_mean"] == CREATES * SMALL["batch_events"] / SHARDS
@@ -160,6 +184,6 @@ def test_sharded_launches_are_booked_where_the_device_ledgers_are(driven):
     # no completion thread outside the serving process: nothing booked
     assert "device.commit_busy_s" not in c and driven.ledger.launch_clock is None
     # one bookkeeping path, not a copy
-    for name in ("_note_launch", "_fetch"):
+    for name in ("_note_launch", "_fetch", "drain", "drain_reply"):
         assert name not in vars(type(driven.ledger)) and name not in vars(DeviceLedger)
         assert name in vars(HostLedgerBase)

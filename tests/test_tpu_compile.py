@@ -187,3 +187,26 @@ def test_sharded_fast_tier_and_state_allocate_per_shard(topo):
     ).compile()
     assert _footprint(compiled) < 0.25 * HBM_BYTES
     assert "all-reduce" in compiled.as_text()
+
+
+def test_sharded_summary_compiles_on_the_mesh_without_a_collective(topo):
+    """What a sharded launch adds since PR 35: the two-word summary (failure
+    count, fault) over the program's replicated results and the replicated
+    fault word. Every chip holds both, so the summary needs no collective
+    and is a few KiB a chip."""
+    from types import SimpleNamespace
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    kernels = pmesh.ShardedLedgerKernels(mesh, DEFAULT_PROCESS)
+    summarize = ledger.HostLedgerBase._summarize_fn(SimpleNamespace(kernels=kernels))
+    assert kernels._summarize_cache is summarize
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)  # noqa: E731
+    compiled = summarize.fn.lower(
+        sds((N_PAD,), jnp.uint32), sds((), jnp.uint32), sds((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "all-gather" not in text
+    packed, summary = compiled.output_shardings
+    assert packed.is_fully_replicated and summary.is_fully_replicated
+    assert _footprint(compiled) < 1 << 20

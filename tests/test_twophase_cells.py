@@ -103,11 +103,13 @@ def test_new_cell_rehearsed_is_correct_and_reports_its_metrics(workload, capfd):
         assert metrics["kernel_ms_pv_window.sat"] > 0.0
         assert 32 * 64 <= metrics["pending_registry_rows.sat"] <= (33 + 16) * 64
     elif workload.startswith("sharded4"):
-        # four devices hold the state; every commit is one synchronous
-        # launch, waited for on the event loop; the owner hash spreads the
-        # rows evenly
+        # four devices hold the state; every commit is one launch left in
+        # flight (PR 35: the loop is busy with something queued, and reads
+        # a reply's two words off a landed buffer instead of waiting out
+        # the kernel); the owner hash spreads the rows evenly
         assert result["device"]["count"] == 4
-        assert metrics["loop_fetch_share.sat"] > 0.0
+        assert metrics["loop_busy_share.sat"] > 0.0
+        assert 0.0 < metrics["loop_fetch_share.sat"] < 50.0
         assert metrics["kernel_ms_window.sat"] > 0.0
         assert 0.0 <= metrics["device_idle_window.sat"] < 100.0
         assert metrics["window_compiles"] == 0.0

@@ -702,6 +702,12 @@ CATALOG = {
     "ledger.lookup_inline": (
         "counter", "", "lookups answered before the call returned (lookup_rows)"
     ),
+    "ledger.drain_all_ok": (
+        "counter", "", "drained batches the two-word summary proved all-success"
+    ),
+    "ledger.drain_dense": (
+        "counter", "", "drained batches whose dense codes were read (failures, or no summary)"
+    ),
     # the sharded ledger (parallel/mesh.py): the owner hash's skew
     "sharded.xfer_rows_max": (
         "gauge", "rows", "transfer rows charged to the fullest shard"

@@ -1970,10 +1970,13 @@ class HostLedgerBase:
     """Shared host-side driver surface of the single-chip and sharded
     ledgers: prepare-timestamp bookkeeping (reference:
     src/state_machine.zig:336-343), the lookup wrappers (reference:
-    src/state_machine.zig:701-736) and the launch bookkeeping (what a commit
-    launch carried, its device time, the blocking reply read). Subclasses
-    provide `state`, `kernels.lookup_accounts/lookup_transfers`, optionally
-    `pad_to`, and call `_bind_counters(self.metrics)` when constructed."""
+    src/state_machine.zig:701-736), the launch bookkeeping (what a commit
+    launch carried, its device time, the blocking reply read) and a
+    launched batch's way home (`_summarize` at dispatch, `drain` /
+    `drain_reply` / `drain_many` later). Subclasses provide `state`,
+    `kernels.lookup_accounts/lookup_transfers`, `execute_async` returning a
+    PendingBatch, `_uncharge`, optionally `pad_to` and `fault_name`, and
+    call `_bind_counters(self.metrics)` when constructed."""
 
     pad_to: int | None = None
     prepare_timestamp: int = 0
@@ -1987,6 +1990,13 @@ class HostLedgerBase:
     # completion thread. None everywhere else — the simulator's
     # seeded runs stay single-threaded.
     launch_clock = None
+    # Start each batch's device->host result copy AT DISPATCH so a
+    # reply-serving driver (the VSR replica) drains landed buffers
+    # instead of paying sync round trips. OPT-IN: a fetch-free
+    # driver (the dual backend's applier) must never trigger it.
+    prefetch_results = False
+    # what a raised fault word calls this ledger
+    fault_name = "device ledger"
 
     def instrument(self, metrics, tracer) -> None:
         self.metrics = metrics
@@ -2004,6 +2014,10 @@ class HostLedgerBase:
         self._c_batches = metrics.counter("device.commit_batches")
         self._c_slots = metrics.counter("device.commit_slots")
         self._c_fetch = metrics.counter("loop.fetch_s")
+        # which way a drained batch took: the two-word summary proved it
+        # all-success, or its dense codes were read
+        self._c_drain_all_ok = metrics.counter("ledger.drain_all_ok")
+        self._c_drain_dense = metrics.counter("ledger.drain_dense")
         # the tier of each create_transfers batch that is LAUNCHED
         self._c_tier = {
             tier: metrics.counter(f"ledger.tier.{tier}") for tier in COMMIT_TIERS
@@ -2031,6 +2045,130 @@ class HostLedgerBase:
             host = np.asarray(dev)
         self._c_fetch.add((perf_counter_ns() - t0) / 1e9)
         return host
+
+    # -- a launched batch's way home: summary at dispatch, drain later --
+
+    def _summarize_fn(self):
+        """Jitted (results, fault, n) -> (packed results+fault, [count,
+        fault]): ONE dispatch for the post-kernel bookkeeping (the previous
+        out-of-jit concatenate was its own XLA launch per batch). Cached on
+        the SHARED kernels object so fresh ledgers reuse the compile."""
+        fn = getattr(self.kernels, "_summarize_cache", None)
+        if fn is None:
+            def s(results, fault, n):
+                res = results.astype(jnp.uint32)
+                lane = jnp.arange(res.shape[0], dtype=jnp.int32)
+                cnt = jnp.sum(
+                    ((res != 0) & (lane < n)).astype(jnp.uint32)
+                )
+                f = fault.reshape(1).astype(jnp.uint32)
+                packed = jnp.concatenate([res, f])
+                return packed, jnp.concatenate([cnt.reshape(1), f])
+
+            fn = self.kernels._summarize_cache = sentinel_jit("summarize", s)
+        return fn
+
+    def _summarize(self, results, nn):
+        """Pack the fault word onto a launch's results, compute the
+        device-side failure count, and START the summary's device->host
+        copy now: the all-success steady state drains TWO words per batch
+        (count + fault) off an already-landed buffer — no dense-codes
+        transfer, no per-event host loop, no sync round trip."""
+        results, summary = self._summarize_fn()(
+            results, self.state["fault"], nn
+        )
+        if self.prefetch_results:
+            try:
+                summary.copy_to_host_async()
+            except (AttributeError, RuntimeError):
+                pass  # no async copy: drain pays the sync cost
+        return results, summary
+
+    def _uncharge(self, pending: PendingBatch, not_applied: np.ndarray) -> None:
+        """Take a drained batch's not-applied lanes off the occupancy
+        charge its dispatch made (+n, conservative). The one thing the two
+        ledgers' drains differ in: a scalar on one chip, a count per owner
+        shard on a mesh."""
+        raise NotImplementedError
+
+    def drain(self, pending: PendingBatch) -> list[int]:
+        """Materialize a pending batch's dense result codes; reconciles the
+        conservative occupancy charge to the exact ever-applied insert count
+        (rolled-back inserts leave tombstones, which still occupy probe
+        slots — see applied_insert_mask). Idempotent: a second drain returns
+        the cached codes without double-reconciling.
+
+        Fast path: the device-side summary (failure count + fault word —
+        a few words, prefetched at dispatch) proves the batch all-success,
+        in which case every event applied (applied == n, reconcile is a
+        no-op) and the dense codes are all zeros — no codes transfer, no
+        per-event host loop."""
+        if pending.dense is not None:
+            return pending.dense
+        if pending.group is not None:
+            g = pending.group
+            if g.summary is not None:
+                s = g.fetch_summary(self._fetch)  # [k counts..., fault]
+                fault = int(s[-1])
+                if int(s[pending.group_idx]) == 0:
+                    return self._drain_all_ok(pending, fault)
+            arr = g.fetch(self._fetch)  # one transfer a group (cached)
+            off = pending.group_idx * g.n_pad
+            codes = arr[off : off + pending.n]
+            return self._drain_from_host(pending, codes, int(arr[-1]))
+        if pending.summary is not None:
+            s = self._fetch(pending.summary)  # [count, fault]
+            if int(s[0]) == 0:
+                return self._drain_all_ok(pending, int(s[1]))
+        arr = self._fetch(pending.results)  # one transfer: results + fault
+        return self._drain_from_host(pending, arr[: pending.n], int(arr[-1]))
+
+    def _drain_all_ok(self, pending: PendingBatch, fault: int) -> list[int]:
+        raise_on_fault(fault, self.fault_name)
+        self._c_drain_all_ok.add()
+        pending.failures = 0
+        pending.dense = [0] * pending.n
+        return pending.dense
+
+    def drain_reply(self, pending: PendingBatch, operation) -> bytes:
+        """The reply body bytes (sparse non-ok result structs, reference:
+        src/tigerbeetle.zig:231-249) without any per-event Python loop:
+        all-success replies are empty by construction, and the failure path
+        encodes via vectorized nonzero."""
+        self.drain(pending)
+        if not pending.failures:
+            return b""
+        from tigerbeetle_tpu.state_machine import encode_sparse_results
+
+        return encode_sparse_results(pending.codes_np, operation)
+
+    def drain_many(self, pendings) -> None:
+        """Materialize a window of pending batches. Each batch's
+        device->host copy was started AT DISPATCH (it pipelines right
+        behind the commit kernel), so draining the window costs one
+        wait for the oldest in-flight copy and the rest read landed
+        buffers — NOT one transport round trip per batch. (A device-side
+        concat would be worse: a fresh launch + fetch that ignores the
+        prefetched copies.)"""
+        for p in pendings:
+            if p is not None:
+                self.drain(p)
+
+    def _drain_from_host(self, pending: PendingBatch, codes,
+                         fault: int) -> list[int]:
+        raise_on_fault(fault, self.fault_name)
+        self._c_drain_dense.add()
+        pending.codes_np = np.asarray(codes, dtype=np.uint32)
+        pending.failures = int(np.count_nonzero(pending.codes_np))
+        dense = [int(x) for x in codes]
+        self._uncharge(pending, ~applied_insert_mask(dense, pending.flags))
+        # Cache only AFTER the fault check and reconcile: a drain retried
+        # after a fault exception must re-raise, not return unsound codes.
+        pending.dense = dense
+        return dense
+
+    def execute_dense(self, operation, timestamp: int, events) -> list[int]:
+        return self.drain(self.execute_async(operation, timestamp, events))
 
     def prepare(self, operation: Operation, event_count: int) -> None:
         if operation in (Operation.create_accounts, Operation.create_transfers):
@@ -2277,11 +2415,6 @@ class DeviceLedger(HostLedgerBase):
         # vet: owner=device-shadow
         self.last_h2d_done_ns = 0
         self._bind_counters(self.metrics)
-        # Start each batch's device->host result copy AT DISPATCH so a
-        # reply-serving driver (the VSR replica) drains landed buffers
-        # instead of paying sync round trips. OPT-IN: a fetch-free
-        # driver (the dual backend's applier) must never trigger it.
-        self.prefetch_results = False
 
     # ------------------------------------------------------------------
     # execution
@@ -2373,44 +2506,12 @@ class DeviceLedger(HostLedgerBase):
             self._acct_used += n
         else:
             raise AssertionError(operation)
-        # Pack the fault word onto the results, compute the device-side
-        # failure count, and START the summary's device->host copy now:
-        # the all-success steady state drains TWO words per batch (count +
-        # fault) off an already-landed buffer — no dense-codes transfer, no
-        # per-event host loop, no sync round trip.
-        results, summary = self._summarize_fn()(
-            results, self.state["fault"], nn
-        )
-        if self.prefetch_results:
-            try:
-                summary.copy_to_host_async()
-            except (AttributeError, RuntimeError):
-                pass  # no async copy: drain pays the sync cost
+        results, summary = self._summarize(results, nn)
         self._note_launch(results, t_launch, 1, 1, decision)
         return PendingBatch(
             operation, n, results, flags=arr["flags"].copy(),
             epoch=self._occupancy_epoch, summary=summary, plan=plan_info,
         )
-
-    def _summarize_fn(self):
-        """Jitted (results, fault, n) -> (packed results+fault, [count,
-        fault]): ONE dispatch for the post-kernel bookkeeping (the previous
-        out-of-jit concatenate was its own XLA launch per batch). Cached on
-        the SHARED kernels object so fresh ledgers reuse the compile."""
-        fn = getattr(self.kernels, "_summarize_cache", None)
-        if fn is None:
-            def s(results, fault, n):
-                res = results.astype(jnp.uint32)
-                lane = jnp.arange(res.shape[0], dtype=jnp.int32)
-                cnt = jnp.sum(
-                    ((res != 0) & (lane < n)).astype(jnp.uint32)
-                )
-                f = fault.reshape(1).astype(jnp.uint32)
-                packed = jnp.concatenate([res, f])
-                return packed, jnp.concatenate([cnt.reshape(1), f])
-
-            fn = self.kernels._summarize_cache = sentinel_jit("summarize", s)
-        return fn
 
     def _wave_stepper(self, W: int, n_pad: int, mode: str):
         """Jitted dispatch of W dependency-ordered waves over ONE uploaded
@@ -2724,7 +2825,7 @@ class DeviceLedger(HostLedgerBase):
     def check_fault(self) -> None:
         """Raise if the device hit the fault protocol (see module docstring).
         Synchronizes with the device — amortize on the hot path."""
-        raise_on_fault(int(np.asarray(self.state["fault"])), "device ledger")
+        raise_on_fault(int(np.asarray(self.state["fault"])), self.fault_name)
 
     # ------------------------------------------------------------------
     # snapshot row install (the dual follower's restore path)
@@ -2861,90 +2962,16 @@ class DeviceLedger(HostLedgerBase):
                         + (int(np.sum(hi >> np.uint64(32), dtype=np.uint64)) << 32)) << 64)
                 )
 
-    def drain(self, pending: PendingBatch) -> list[int]:
-        """Materialize a pending batch's dense result codes; reconciles the
-        conservative occupancy charge to the exact ever-applied insert count
-        (rolled-back inserts leave tombstones, which still occupy probe
-        slots — see applied_insert_mask). Idempotent: a second drain returns
-        the cached codes without double-reconciling.
-
-        Fast path: the device-side summary (failure count + fault word —
-        a few words, prefetched at dispatch) proves the batch all-success,
-        in which case every event applied (applied == n, reconcile is a
-        no-op) and the dense codes are all zeros — no codes transfer, no
-        per-event host loop."""
-        if pending.dense is not None:
-            return pending.dense
-        if pending.group is not None:
-            g = pending.group
-            if g.summary is not None:
-                s = g.fetch_summary(self._fetch)  # [k counts..., fault]
-                fault = int(s[-1])
-                if int(s[pending.group_idx]) == 0:
-                    return self._drain_all_ok(pending, fault)
-            arr = g.fetch(self._fetch)  # one transfer a group (cached)
-            off = pending.group_idx * g.n_pad
-            codes = arr[off : off + pending.n]
-            return self._drain_from_host(pending, codes, int(arr[-1]))
-        if pending.summary is not None:
-            s = self._fetch(pending.summary)  # [count, fault]
-            if int(s[0]) == 0:
-                return self._drain_all_ok(pending, int(s[1]))
-        arr = self._fetch(pending.results)  # one transfer: results + fault
-        return self._drain_from_host(pending, arr[: pending.n], int(arr[-1]))
-
-    def _drain_all_ok(self, pending: PendingBatch, fault: int) -> list[int]:
-        raise_on_fault(fault, "device ledger")
-        pending.failures = 0
-        pending.dense = [0] * pending.n
-        return pending.dense
-
-    def drain_reply(self, pending: PendingBatch, operation) -> bytes:
-        """The reply body bytes (sparse non-ok result structs, reference:
-        src/tigerbeetle.zig:231-249) without any per-event Python loop:
-        all-success replies are empty by construction, and the failure path
-        encodes via vectorized nonzero."""
-        self.drain(pending)
-        if not pending.failures:
-            return b""
-        from tigerbeetle_tpu.state_machine import encode_sparse_results
-
-        return encode_sparse_results(pending.codes_np, operation)
-
-    def drain_many(self, pendings) -> None:
-        """Materialize a window of pending batches. Each batch's
-        device->host copy was started AT DISPATCH (it pipelines right
-        behind the commit kernel), so draining the window costs one
-        wait for the oldest in-flight copy and the rest read landed
-        buffers — NOT one transport round trip per batch. (A device-side
-        concat would be worse: a fresh launch + fetch that ignores the
-        prefetched copies.)"""
-        for p in pendings:
-            if p is not None:
-                self.drain(p)
-
-    def _drain_from_host(self, pending: PendingBatch, codes,
-                         fault: int) -> list[int]:
-        raise_on_fault(fault, "device ledger")
-        pending.codes_np = np.asarray(codes, dtype=np.uint32)
-        pending.failures = int(np.count_nonzero(pending.codes_np))
-        dense = [int(x) for x in codes]
-        applied = int(applied_insert_mask(dense, pending.flags).sum())
+    def _uncharge(self, pending: PendingBatch, not_applied: np.ndarray) -> None:
+        dec = int(not_applied.sum())
         if pending.operation == Operation.create_transfers:
             # A spill cycle after dispatch rebuilt the table and recounted
             # occupancy exactly — this batch's effect is already measured;
             # reconciling again would double-count the correction.
             if pending.epoch == self._occupancy_epoch:
-                self._xfer_used += applied - pending.n
+                self._xfer_used -= dec
         else:
-            self._acct_used += applied - pending.n
-        # Cache only AFTER the fault check and reconcile: a drain retried
-        # after a fault exception must re-raise, not return unsound codes.
-        pending.dense = dense
-        return dense
-
-    def execute_dense(self, operation, timestamp: int, events) -> list[int]:
-        return self.drain(self.execute_async(operation, timestamp, events))
+            self._acct_used -= dec
 
     # -- lookups (spill-aware: HBM miss falls back to the LSM store) --
 

@@ -63,16 +63,15 @@ from tigerbeetle_tpu.models.ledger import (
     _lohi,
     _next_pow2,
     _set_ts_words,
+    _to_rows_np,
     HazardTracker,
     HostLedgerBase,
-    accounts_to_batch,
-    applied_insert_mask,
+    PendingBatch,
     build_stored_transfer,
     key4_from_fields,
     pack_account,
     pack_transfer,
     sentinel_jit,
-    transfers_to_batch,
     unpack_account,
     unpack_transfer,
 )
@@ -864,8 +863,14 @@ class ShardedLedgerKernels:
 
 class ShardedLedger(HostLedgerBase):
     """Host wrapper over the sharded kernels. Mirrors DeviceLedger's
-    execute() API (HostLedgerBase: prepare/lookups); tier selection is the
-    same host-side HazardTracker."""
+    execute_async / drain contract (HostLedgerBase: prepare, lookups, the
+    launch bookkeeping and the whole drain): a commit is dispatched and
+    left in flight, its fault word and failure count come home as two
+    words, and only a batch with failures has its dense codes read. Tier
+    selection is the same host-side HazardTracker, all-or-nothing (fast /
+    serial). Lookups are answered inline."""
+
+    fault_name = "sharded ledger"
 
     def __init__(self, mesh: Mesh, process: ConfigProcess, mode: str = "auto"):
         self.mesh = mesh
@@ -875,13 +880,15 @@ class ShardedLedger(HostLedgerBase):
         self.kernels = ShardedLedgerKernels(mesh, process)
         self.state = init_sharded_state(mesh, process)
         self.hazards = HazardTracker()
-        # Per-shard occupancy guard (conservative: counts submissions, not
-        # just successes; reconciled in execute_dense). Owner-hash skew means
-        # one shard can fill well before aggregate capacity.
+        # Per-shard occupancy guard (conservative: a dispatch charges every
+        # submission, the drain takes the not-applied ones off again).
+        # Owner-hash skew means one shard can fill well before aggregate
+        # capacity.
         self._acct_used = np.zeros(self.n_shards, dtype=np.int64)
         self._xfer_used = np.zeros(self.n_shards, dtype=np.int64)
         self._acct_limit = (1 << process.account_slots_log2) // 2
         self._xfer_limit = (1 << process.transfer_slots_log2) // 2
+        self._replicated = NamedSharding(mesh, P())
         self._bind_counters(self.metrics)
 
     def _bind_counters(self, metrics) -> None:
@@ -891,45 +898,39 @@ class ShardedLedger(HostLedgerBase):
         self._g_rows_max = metrics.gauge("sharded.xfer_rows_max")
         self._g_rows_mean = metrics.gauge("sharded.xfer_rows_mean")
 
-    def _shard_counts(self, arr: np.ndarray) -> np.ndarray:
-        owners = owner_of_ids_np(arr["id_lo"], arr["id_hi"], self.n_shards)
+    def _shard_counts(self, id_lo: np.ndarray, id_hi: np.ndarray) -> np.ndarray:
+        owners = owner_of_ids_np(id_lo, id_hi, self.n_shards)
         return np.bincount(owners, minlength=self.n_shards)
 
-    def execute_dense(self, operation, timestamp: int, events) -> list[int]:
-        n = len(events)
-        with self.tracer.span("ledger.sharded_launch", events=n):
-            arr, results = self._launch(operation, timestamp, events)
-        dense = [int(x) for x in self._fetch(results)[:n]]
-        self.check_fault()
-        # Reconcile the conservative per-shard estimate to the exact
-        # ever-applied count (rolled-back inserts tombstone their slot on the
-        # owner shard and still occupy it — see models.ledger.applied_insert_mask).
-        not_applied = ~applied_insert_mask(dense, arr["flags"])
-        if not_applied.any():
-            owners = owner_of_ids_np(
-                arr["id_lo"][not_applied], arr["id_hi"][not_applied], self.n_shards
-            )
-            dec = np.bincount(owners, minlength=self.n_shards)
-            if operation == Operation.create_transfers:
-                self._xfer_used -= dec
-            else:
-                self._acct_used -= dec
+    def _note_rows(self) -> None:
         self._g_rows_max.set(int(self._xfer_used.max()))
         self._g_rows_mean.set(float(self._xfer_used.mean()))
-        return dense
 
-    def _launch(self, operation, timestamp: int, events):
-        """The host work of one batch up to its dispatch: shard counts,
-        hazard test, rows to the device, the jit call. Returns the wire
-        array and the launched results (still on the device)."""
+    def execute_async(self, operation, timestamp: int, events) -> PendingBatch:
+        """Dispatch a commit without any device->host synchronization: the
+        host work of one batch up to its dispatch (shard counts, load
+        guard, hazard test, rows to the device, the jit call), then the
+        summary's copy home started. `drain` reads it later and takes the
+        not-applied lanes off the per-shard charge made here."""
+        with self.tracer.span("ledger.sharded_launch", events=len(events)):
+            return self._launch(operation, timestamp, events)
+
+    def _launch(self, operation, timestamp: int, events) -> PendingBatch:
         from tigerbeetle_tpu import types as t
 
         n = len(events)
         n_pad = _next_pow2(n)
+        # A launch's inputs go from the host to EVERY chip (numpy scalars,
+        # the rows put with the mesh's replicated sharding). An array that
+        # lands on chip 0 first is copied on by chip 0's own stream, behind
+        # the commit program running there, and the launch queued for the
+        # other chips then starts ~2 ms after its predecessor ended.
+        nn = np.int32(n)
         tier = None  # the tier of a create_transfers launch
         if operation == Operation.create_transfers:
             arr = events if isinstance(events, np.ndarray) else t.transfers_to_np(events)
-            counts = self._shard_counts(arr)
+            id_limbs = (arr["id_lo"].copy(), arr["id_hi"].copy())
+            counts = self._shard_counts(*id_limbs)
             if ((self._xfer_used + counts) > self._xfer_limit).any():
                 raise RuntimeError(
                     "a transfer shard is at its load-factor limit: grow "
@@ -945,11 +946,13 @@ class ShardedLedger(HostLedgerBase):
                 if tier == "fast"
                 else self.kernels.commit_transfers_serial
             )
-            batch = transfers_to_batch(arr, n_pad)
+            rows = _to_rows_np(arr, n_pad)
             self._xfer_used += counts
+            self._note_rows()
         elif operation == Operation.create_accounts:
             arr = events if isinstance(events, np.ndarray) else t.accounts_to_np(events)
-            counts = self._shard_counts(arr)
+            id_limbs = (arr["id_lo"].copy(), arr["id_hi"].copy())
+            counts = self._shard_counts(*id_limbs)
             if ((self._acct_used + counts) > self._acct_limit).any():
                 raise RuntimeError(
                     "an account shard is at its load-factor limit: grow "
@@ -964,19 +967,38 @@ class ShardedLedger(HostLedgerBase):
                 if mode == "fast"
                 else self.kernels.commit_accounts_serial
             )
-            batch = accounts_to_batch(arr, n_pad)
+            rows = _to_rows_np(arr, n_pad)
             self._acct_used += counts
         else:
             raise AssertionError(operation)
+        batch = {"rows": jax.device_put(rows, self._replicated)}
         t_launch = perf_counter_ns()  # rows on their way: kernel next
-        self.state, results = fn(
-            self.state, batch, jnp.int32(n), jnp.uint64(timestamp)
-        )
+        self.state, results = fn(self.state, batch, nn, np.uint64(timestamp))
+        results, summary = self._summarize(results, nn)
         self._note_launch(results, t_launch, 1, 1, tier)
-        return arr, results
+        return PendingBatch(
+            operation, n, results, flags=arr["flags"].copy(),
+            id_limbs=id_limbs, summary=summary,
+        )
+
+    def _uncharge(self, pending: PendingBatch, not_applied: np.ndarray) -> None:
+        # rolled-back inserts tombstone their slot on the owner shard and
+        # still occupy it (models.ledger.applied_insert_mask): only lanes
+        # that never inserted come off their owner's charge
+        if not_applied.any():
+            lo, hi = pending.id_limbs
+            dec = self._shard_counts(lo[not_applied], hi[not_applied])
+            if pending.operation == Operation.create_transfers:
+                self._xfer_used -= dec
+                self._note_rows()
+            else:
+                self._acct_used -= dec
 
     def check_fault(self) -> None:
-        raise_on_fault(int(np.asarray(self.state["fault"])), "sharded ledger")
+        """Raise if the mesh hit the fault protocol. Synchronizes with the
+        devices: for the snapshot and direct callers; a commit's own fault
+        word comes home on its results and raises at drain."""
+        raise_on_fault(int(np.asarray(self.state["fault"])), self.fault_name)
 
     # -- parity extraction (lookups come from HostLedgerBase) --
 

@@ -166,12 +166,15 @@ class StateMachine:
         launch is queued; results stay on device). Returns a handle for
         commit_finish: `(operation, pending)` when the backend left the
         work in flight, the reply bytes when it answered already. Creates
-        are asynchronous on every backend with `execute_async`; a lookup is
-        where the backend offers `lookup_async` and takes it (DeviceLedger:
-        not a transfers lookup over a spill store) — it then reads the
-        tables as they stand after every op dispatched before it, and its
-        reply is built at commit_finish. Elsewhere (oracle, native, dual,
-        sharded) a lookup computes its reply inline.
+        are asynchronous on every backend with `execute_async` (device,
+        sharded, native, dual; the oracle answers at once); a lookup is
+        where the backend offers `lookup_async` and takes it (DeviceLedger
+        alone: not a transfers lookup over a spill store) — it then reads
+        the tables as they stand after every op dispatched before it, and
+        its reply is built at commit_finish. Elsewhere (oracle, native,
+        dual, sharded) a lookup computes its reply inline; on the sharded
+        ledger that read is launched behind every create still in flight,
+        one device stream, so it sees each of them.
         This is the replica's commit-stage overlap seam (reference:
         src/vsr/replica.zig:3045-3103 commit_dispatch stages)."""
         if operation in _LOOKUP_OPS:
