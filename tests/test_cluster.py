@@ -274,7 +274,10 @@ def test_commit_window_overlaps_journal_and_device():
     c1.request(op, body1)
     c2.request(op2, body2)
     cluster.network.run()
-    r.pump_commits()  # the real event loop calls this after each pump turn
+    # the real event loop calls pump_commits after each pump turn; here
+    # neither result is ready before the turn ends (the solo dispatch path
+    # would release a ready one behind the op it just dispatched)
+    cluster.pump_commits_ahead_of_results()
 
     # Both ops are journaled AND dispatched (commit_min advanced) — op 2's
     # journal write happened while op 1's device batch was still in

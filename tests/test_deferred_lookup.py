@@ -105,7 +105,7 @@ def _dispatch_create_then_lookup(cluster, r, creator, reader):
         Operation.lookup_accounts, encode_ids([int(x) for x in acc["id_lo"]])
     )
     cluster.network.run()
-    r.pump_commits()
+    cluster.pump_commits_ahead_of_results()
     assert r.commit_min == base + 2
     assert len(r._inflight) == 2
     create, lookup = (e["handle"] for e in r._inflight)
@@ -142,6 +142,42 @@ def test_create_reply_leaves_while_the_lookup_behind_it_is_in_flight():
     snap = r.metrics.snapshot()["counters"]
     assert snap["ledger.lookup_deferred"] == 1
     assert snap.get("ledger.lookup_inline", 0) == 0
+
+
+def test_the_solo_dispatch_path_hands_a_ready_reply_to_the_wire():
+    """PR 37: the solo dispatch path finalizes the ready prefix behind the
+    op it just dispatched and flushes the transport at once
+    (`Network.flush_pending`: the TCP bus buffers its sends until the loop
+    pumps, and under a dispatch that blocks for a whole launch the next
+    pump is launches away). The op just dispatched is never finalized
+    there, ready or not. The recorder stands in for the in-process
+    network's no-op flush."""
+    cluster, r, c1, c2 = _window_replica()
+    flushes = []
+    cluster.network.flush_pending = lambda: flushes.append(len(r._inflight))
+    gate = _Gate(r.sm.backend)
+    acc = _accounts(1, 16)
+    base = r.commit_min
+    c1.request(Operation.create_accounts, acc.tobytes())
+    cluster.network.run()
+    r.pump_commits()
+    assert len(r._inflight) == 1 and not flushes  # the newest is kept
+    jax.block_until_ready(r._inflight[0]["handle"][1].summary)
+    c2.request(Operation.lookup_accounts, encode_ids(list(range(1, 17))))
+    cluster.network.run()
+    r.pump_commits()  # dispatches the lookup, then releases the create
+    assert flushes == [1] and len(r._inflight) == 1
+    assert r.group_stats["replies_ahead"] == 1
+    cluster.network.run()
+    h1, body1 = c1.take_reply()
+    assert h1.op == base + 1 and body1 == b""
+    assert c2.reply is None
+    jax.block_until_ready(r._inflight[0]["handle"][1].rows)
+    gate.open = True
+    assert r.flush_commits(only_ready=True) == 1
+    cluster.network.run()
+    assert c2.take_reply()[0].op == base + 2
+    assert flushes == [1]  # the idle loop's flush leaves the wire to the pump
 
 
 def test_a_lookup_at_the_head_holds_the_ready_create_behind_it():

@@ -169,7 +169,7 @@ def _drive_creates(mesh8, commit_window: int):
             c.request(Operation.create_transfers,
                       types.transfers_to_np(xfers).tobytes())
             cluster.network.run()
-        r.pump_commits()
+        cluster.pump_commits_ahead_of_results()
         if commit_window:
             handles = [e["handle"] for e in r._inflight]
             assert all(isinstance(h, tuple) and isinstance(h[1], PendingBatch)

@@ -156,6 +156,31 @@ def test_single_chip_program_compiles_and_fits(one_chip, name, share):
         assert used > 0.5 * HBM_BYTES
 
 
+def test_the_linked_cells_launch_is_the_guarded_serial_program(one_chip):
+    """What `linked_onpath.linked3_sat16` launches for every batch of 8190
+    (PR 37): the batch pads to the 8192 lanes compiled above; the state is
+    donated, so the tables' 2.38 GiB alias the outputs and the scan's temp
+    is all the launch adds; the steps and the rollback inside a step are
+    two `while` loops; and the launch's second program, the two-word
+    summary of its results, is a few KiB."""
+    from types import SimpleNamespace as NS
+
+    led = NS(pad_to=N_PAD)
+    assert ledger.HostLedgerBase._pad_for(led, 8190) == N_PAD
+    a = _single_chip_args(one_chip)
+    compiled = _lower_single("commit_transfers_serial", a).compile()
+    m = compiled.memory_analysis()
+    state_bytes = m.argument_size_in_bytes - N_PAD * ledger.ROW_WORDS * 4
+    assert m.alias_size_in_bytes > 0.99 * state_bytes
+    text = compiled.as_text()
+    assert "input_output_alias" in text and text.count(" while(") >= 2
+    summarize = ledger.HostLedgerBase._summarize_fn(
+        NS(kernels=ledger.get_kernels(DEFAULT_PROCESS)))
+    small = summarize.fn.lower(
+        a.sds((N_PAD,), jnp.uint32), a.sds((), jnp.uint32), a.n).compile()
+    assert _footprint(small) < 1 << 20
+
+
 def test_sharded_fast_tier_and_state_allocate_per_shard(topo):
     """The 4-device mesh: commit_transfers_fast compiles with the state
     sharded, and the state's allocator writes each device only its own

@@ -31,6 +31,11 @@ class Network:
     def send(self, src: Address, dst: Address, data: bytes) -> None:
         raise NotImplementedError
 
+    def flush_pending(self) -> None:
+        """Put buffered sends on the wire now. A transport that buffers
+        until its next pump (the TCP bus) overrides this; one that queues
+        each send for delivery at once has nothing to flush."""
+
 
 class InProcessNetwork(Network):
     def __init__(self):

@@ -690,6 +690,16 @@ CATALOG = {
     **{f"ledger.tier.{tier}": (
         "counter", "", f"create_transfers batches launched in the {tier} tier"
     ) for tier in COMMIT_TIERS},
+    "ledger.linked_events": (
+        "counter", "events", "lanes inside a linked chain, summed over HazardTracker.plan calls"
+    ),
+    "ledger.linked_chains": (
+        "counter", "chains", "linked chains (their terminators), summed over plan calls"
+    ),
+    "ledger.solo_dispatch_us": (
+        "counter", "us", "wall time inside a solo launch's jit call (span ledger.solo_dispatch)"
+    ),
+    "ledger.solo_dispatches": ("counter", "", "solo launches whose jit call was timed"),
     "ledger.group_probe_rejected": (
         "counter", "", "fuse attempts turned down because a batch did not plan fast"
     ),

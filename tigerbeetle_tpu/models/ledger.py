@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from contextlib import contextmanager
 from time import perf_counter_ns, time  # vet: observability-only (compile sentinel)
 
 import jax
@@ -1561,6 +1562,14 @@ class HazardTracker:
             "wave_dispatches": 0, "residue_events": 0, "chain_len_max": 0,
             "split": 0, "split_pv": 0,
         }
+        self.bind_counters(NULL_METRICS)
+
+    def bind_counters(self, metrics) -> None:
+        """What plan() saw of linked chains, a call (a fuse probe that is
+        rolled back counts like `ledger.plan_calls` does): lanes inside a
+        chain, and chains (their terminators)."""
+        self._c_linked_events = metrics.counter("ledger.linked_events")
+        self._c_linked_chains = metrics.counter("ledger.linked_chains")
 
     @property
     def split_stats(self) -> dict:
@@ -1693,6 +1702,10 @@ class HazardTracker:
         # whole chain runs: a linked run's terminator is the event AFTER it
         in_chain = linked.copy()
         in_chain[1:] |= linked[:-1]
+        n_in_chain = int(in_chain.sum())
+        self._c_linked_events.add(n_in_chain)
+        # a chain's terminator is its one lane that is not itself linked
+        self._c_linked_chains.add(n_in_chain - int(linked.sum()))
         residue = in_chain | bal
 
         with np.errstate(over="ignore"):
@@ -2363,6 +2376,22 @@ class DeviceLedger(HostLedgerBase):
         # in-flight queue (lookup_async), or answered before returning
         self._c_lookup_deferred = metrics.counter("ledger.lookup_deferred")
         self._c_lookup_inline = metrics.counter("ledger.lookup_inline")
+        # a solo launch's jit call alone: on a busy chip it returns only
+        # when the launch before it is done, and the event loop waits in it
+        self._c_solo_dispatch_us = metrics.counter("ledger.solo_dispatch_us")
+        self._c_solo_dispatches = metrics.counter("ledger.solo_dispatches")
+        self.hazards.bind_counters(metrics)
+
+    @contextmanager
+    def _solo_dispatch(self, tier: str, t_launch_ns: int):
+        """Span and clock around ONE solo launch's jit call, nothing else
+        of `_solo_launch` inside: what the caller waits for the runtime,
+        apart from its own planning, converting and uploading. Timed from
+        the launch's dispatch stamp, taken the line before."""
+        with self.tracer.span("ledger.solo_dispatch", tier=tier):
+            yield
+        self._c_solo_dispatch_us.add((perf_counter_ns() - t_launch_ns) / 1e3)
+        self._c_solo_dispatches.add()
 
     def __init__(
         self,
@@ -2472,15 +2501,17 @@ class DeviceLedger(HostLedgerBase):
             self._g_registry.set(len(self.hazards.pending_accounts))
             if decision == "waves":
                 t_launch = perf_counter_ns()  # the waves upload their own rows
-                results = self._execute_waves(
-                    arr, n, n_pad, nn, ts, timestamp, wave_plan
-                )
+                with self._solo_dispatch(decision, t_launch):
+                    results = self._execute_waves(
+                        arr, n, n_pad, nn, ts, timestamp, wave_plan
+                    )
             else:
                 batch = transfers_to_batch(arr, n_pad)
                 t_launch = perf_counter_ns()  # rows on their way: kernel next
-                self.state, results = self.kernels.commit_transfers(
-                    self.state, batch, nn, ts, mode=decision
-                )
+                with self._solo_dispatch(decision, t_launch):
+                    self.state, results = self.kernels.commit_transfers(
+                        self.state, batch, nn, ts, mode=decision
+                    )
             plan_info = (
                 decision, wave_plan.n_waves if wave_plan is not None else 1
             )
@@ -2499,9 +2530,10 @@ class DeviceLedger(HostLedgerBase):
             self.hazards.note_limit_accounts(arr)
             batch = accounts_to_batch(arr, n_pad)
             t_launch = perf_counter_ns()
-            self.state, results = self.kernels.commit_accounts(
-                self.state, batch, nn, ts, mode=mode
-            )
+            with self._solo_dispatch("accounts", t_launch):
+                self.state, results = self.kernels.commit_accounts(
+                    self.state, batch, nn, ts, mode=mode
+                )
             plan_info = None
             self._acct_used += n
         else:
@@ -2845,6 +2877,7 @@ class DeviceLedger(HostLedgerBase):
         self._acct_used = 0
         self._xfer_used = 0
         self.hazards = HazardTracker()
+        self.hazards.bind_counters(self.metrics)
 
     def _install_fn(self, table: str):
         """Jitted chunk installer for one table: claim slots for `n` wire

@@ -105,6 +105,19 @@ class Cluster:
             self.network.run()
         return client.take_reply()
 
+    def pump_commits_ahead_of_results(self, index: int = 0) -> None:
+        """One `pump_commits` turn of a replica during which no in-flight
+        result reports ready: the dispatches of one turn outrun the device,
+        as they do on a chip (a batch there takes tens of milliseconds; the
+        CPU may finish a test's tiny batch before the loop's next
+        statement, and the solo dispatch path releases what is ready)."""
+        r = self.replicas[index]
+        r._entry_ready = lambda entry: False  # shadows the method
+        try:
+            r.pump_commits()
+        finally:
+            del r._entry_ready
+
     def run_ticks(self, n: int) -> None:
         """Advance virtual time: each tick every replica ticks, then the
         network quiesces (the simulator interleaves these differently)."""

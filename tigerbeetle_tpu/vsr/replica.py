@@ -1762,7 +1762,7 @@ class Replica:
                     d["wal"] = entry.get("wal")
                     self._inflight.append(d)
                     self.group_stats.add("solo_ops")
-                    self.flush_commits(keep=self.commit_window, only_ready=True)
+                    self._release_ready()
                 else:
                     lt = entry.get("lt", 0)
                     reply_wire = self._commit_prepare(header, body, lt=lt)
@@ -2208,7 +2208,21 @@ class Replica:
                 self.latency.egress(lt, h.client, h.context)
             self.network.send(self.replica, entry["header"].client, wire)
 
-    def flush_commits(self, keep: int = 0, only_ready: bool = False) -> int:
+    def _release_ready(self) -> None:
+        """The solo dispatch path's flush: finalize the ready prefix of the
+        in-flight queue (never the op just dispatched; blocking only beyond
+        4x the window) and hand its replies to the wire NOW. A solo launch's
+        dispatch does not return while the runtime's queue is full — a whole
+        launch, two seconds for a serial batch — and this turn's next
+        dispatch follows at once while ops are quorum-ready, so a reply left
+        in the transport's buffer would wait for a pump that may be several
+        launches away."""
+        if self.flush_commits(keep=1, only_ready=True,
+                              hard_cap=4 * self.commit_window):
+            self.network.flush_pending()
+
+    def flush_commits(self, keep: int = 0, only_ready: bool = False,
+                      hard_cap: int | None = None) -> int:
         """Finalize queued async commits (oldest first, so replies leave in
         op order) until at most `keep` remain in flight; returns how many
         it finalized.
@@ -2217,15 +2231,18 @@ class Replica:
         OWN results are ready (WAL durable, device handle computed) and
         stops at the first entry that is not — a reply does not wait for a
         younger op's result. The event loop's idle branch and the tick call
-        it with keep=0; the dispatch path with keep=commit_window, where a
-        hard cap of 4x keep still blocks to bound the in-flight window.
+        it with keep=0; the group and backup dispatch paths with
+        keep=commit_window, where a hard cap of 4x keep still blocks to
+        bound the in-flight window (`hard_cap` states it apart from `keep`:
+        the solo dispatch path, `_release_ready`).
 
         only_ready=False blocks until the queue is down to `keep`
         (checkpoint, restore, status change, shutdown), fetching the
         window's create results in one go first."""
         done = 0
         if only_ready:
-            hard_cap = 4 * keep if keep else (1 << 30)
+            if hard_cap is None:
+                hard_cap = 4 * keep if keep else (1 << 30)
             while (
                 len(self._inflight) > keep
                 and (
