@@ -18,6 +18,8 @@ Contracts under test:
 
 import time
 
+import pytest
+
 import tests.conftest  # noqa: F401 — CPU platform before jax init
 from tigerbeetle_tpu.latency import (
     LEG_DISPATCH,
@@ -55,7 +57,7 @@ class _FakeClock:
 def _run_one(anatomy: LatencyAnatomy, tid: int) -> int:
     """Drive one request through the full stamp protocol; returns the
     token (0 if unsampled)."""
-    anatomy.arrive()
+    anatomy.received(0)
     tok = anatomy.open(tid) if anatomy.want() else 0
     if tok:
         for leg in _ALL_LEGS:
@@ -136,23 +138,34 @@ def test_deferred_egress_parks_and_finishes_by_reply_key():
     assert m.snapshot()["counters"]["latency.samples"] == 1
 
 
-def test_stale_gateway_arrival_is_discarded():
+def test_record_opens_at_the_handler_entry_handed_over():
+    m = Metrics()
     clk = _FakeClock([0])  # manual control below
-    a = LatencyAnatomy(metrics=NULL_METRICS, clock=lambda: clk.t,
-                       sample_every=1)
+    a = LatencyAnatomy(metrics=m, clock=lambda: clk.t, sample_every=1)
     clk.t = 1_000
-    a.arrive()
-    clk.t = 1_000 + 200_000_000  # 200ms later: the arrival is stale
+    a.received(0)  # the transport hands the frame over: handler entry
+    clk.t += 50_000  # 50us of admission work
     assert a.want()
     tok = a.open(5)
-    assert a._recs[tok][0] == clk.t  # fresh clock, not the stale arrival
+    r = a._recs[tok]
+    assert r[1] == r[3] == 1_000  # t0 = entry: no kernel stamp, wait 0
+    assert r[5] == clk.t
     a.finish(tok)
-    # a FRESH arrival is used as t0
+    # the handoff is consumed: a request no transport handed over (a
+    # direct call) opens at the clock's now, never at a stale entry
     clk.t += 1_000
-    a.arrive()
-    clk.t += 50_000  # 50us of admission work
     tok = a.open(6)
-    assert a._recs[tok][0] == clk.t - 50_000
+    assert a._recs[tok][1] == a._recs[tok][3] == clk.t
+    a.finish(tok)
+    assert m.counter("latency.unstamped").value == 2
+    # a kernel stamp 2 ms behind the wall clock opens the record 2 ms
+    # (and the time the test itself took since) before the entry
+    clk.t += 1_000
+    a.received(0, time.time_ns() - 2_000_000)
+    tok = a.open(7)
+    r = a._recs[tok]
+    assert r[3] == clk.t and r[3] - r[1] >= 2_000_000
+    assert m.counter("latency.unstamped").value == 2
 
 
 def test_dominant_leg_delta_math():
@@ -174,11 +187,25 @@ def test_dominant_leg_delta_math():
     assert t == {"wal_write": {"count": 4, "total_us": 10.0}}
 
 
-def test_stamp_budget_under_1us_per_request_noop_backend():
-    """The ISSUE's budget: amortized per-request stamping cost < 1us
-    with the no-op metrics backend at the DEFAULT sampling rate. Best
-    of 5 passes so a scheduler hiccup on a loaded CI core cannot flake
-    the bound (the true cost is ~0.7us on this class of machine)."""
+@pytest.mark.parametrize("kernel_stamp", [False, True],
+                         ids=["no_stamp", "kernel_stamp"])
+def test_stamp_budget_under_1us_per_request_noop_backend(kernel_stamp):
+    """The budget: amortized per-request stamping cost < 1us with the
+    no-op metrics backend at the DEFAULT sampling rate, on the path a
+    TCP server runs: the transport's handoff (`received`, with or
+    without a kernel stamp), the class's countdown and, one request in
+    16, the whole record, over an alternating create / lookup stream as
+    fast as the loop runs (the floor never fires: a class's records are
+    microseconds apart). The kernel stamp lies ahead of the wall clock
+    (its wait clamps to 0), so every record's e2e is alike, as a live
+    server's are: a stamp aging through the pass would make each record
+    the slowest yet and time the top-K ring's insert instead. The cost
+    is the thread's CPU time less that of the same loop calling an
+    empty function, best of 20 passes: time the thread spent
+    descheduled on a loaded CI machine is not stamping work, and a wall
+    clock would charge it (the true cost is ~0.5us on this class of
+    machine)."""
+    from tigerbeetle_tpu.types import Operation
     from tigerbeetle_tpu.vsr.header import Command, Header
 
     req = Header(command=int(Command.request), client=0xABC, request=7)
@@ -186,22 +213,28 @@ def test_stamp_budget_under_1us_per_request_noop_backend():
     req.set_checksum()
     a = LatencyAnatomy(metrics=NULL_METRICS)  # default sample_every
     assert a.sample_every == 16
+    stamp = time.time_ns() + 3_600_000_000_000 if kernel_stamp else 0
+    ops = (int(Operation.create_transfers), int(Operation.lookup_accounts))
 
-    def one_request():
-        a.arrive()
-        tok = a.open(req.trace()) if a.want() else 0
+    def one_request(op):
+        a.received(op, stamp)
+        tok = a.open(req.trace(), op) if a.want(op) else 0
         if tok:
             for leg in _ALL_LEGS:
                 a.stamp(tok, leg)
             a.egress(tok, 0xABC, 123)
 
-    n = 20_000
-    best = float("inf")
-    for _ in range(5):
-        t0 = time.perf_counter_ns()
-        for _i in range(n):
-            one_request()
-        best = min(best, (time.perf_counter_ns() - t0) / n)
+    def nothing(op):
+        return None
+
+    def per_request(fn, n=5_000):
+        t0 = time.thread_time_ns()
+        for i in range(n):
+            fn(ops[i & 1])
+        return (time.thread_time_ns() - t0) / n
+
+    best = min(per_request(one_request) - per_request(nothing)
+               for _ in range(20))
     assert best < 1000.0, f"amortized stamp cost {best:.0f}ns >= 1us"
 
 

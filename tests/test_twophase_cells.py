@@ -41,7 +41,8 @@ COUNTED = {
     "default_onpath.plain_rate": {
         "gen_late_ms.rate", "wire_ms.rate", "commit_wait_ms.rate",
         "kernel_ms_window.rate", "device_idle_window.rate",
-        "lookup_probe_steps.rate"},
+        "lookup_probe_steps.rate", "lookup_wait_ms.rate",
+        "reply_hold_ms.rate"},
     "sharded4.plain_sat16": {
         "client_retries.sat", "create_p90_ms.sat", "loop_busy_share.sat",
         "loop_fetch_share.sat", "kernel_ms_window.sat",

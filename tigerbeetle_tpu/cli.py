@@ -142,10 +142,14 @@ class StartArgs:
     # PR-4 behavior: one pump, one cursor, all sinks move together.
     cdc_fanout: bool = False
     # Per-request critical-path attribution (tigerbeetle_tpu/latency.py):
-    # one request in N is stamped at every pipeline leg and folded into
-    # the latency.* histograms at reply egress; the slowest sampled
-    # requests keep full breakdowns (SIGQUIT dump + `inspect live`).
-    # 1 = every request (regression hunting), 0 = off.
+    # one request in N of each operation class (create / lookup /
+    # other), and a class's first request 10 ms after its last record,
+    # is stamped at every pipeline leg from the kernel's receive of its
+    # first byte (where the kernel stamps it; else from its handler's
+    # entry) and folded into the latency.* histograms at reply
+    # egress; the slowest sampled requests keep full breakdowns (SIGQUIT
+    # dump + `inspect live`). 1 = every request (regression hunting),
+    # 0 = off (and client sockets are read without receive stamps).
     latency_sample_every: int = 16
     # Flight recorder (metrics.py FlightRecorder): seconds between
     # time-series snapshots of the registry (counter deltas + windowed
@@ -591,12 +595,13 @@ def cmd_start(args) -> int:
         tracer=tracer,
     )
     boot("replica constructed (device state allocated)")
-    # latency anatomy: sampling knob + TCP egress (the bus finishes a
-    # sampled record at the flush that writes its reply frame, so the
-    # reply_egress leg measures finalize -> first socket write)
+    # latency anatomy (the bus holds it since the replica's attach):
+    # sampling knob, then egress (the bus finishes a sampled record at
+    # the flush that writes its reply frame, so the reply_egress leg
+    # measures finalize -> first socket write). The bus asks the kernel
+    # for receive stamps on the connections it accepts while it samples.
     replica.latency.sample_every = args.latency_sample_every
     replica.latency.defer_egress = True
-    bus.latency = replica.latency
     flight = None
     if args.flight_interval_s > 0:
         from tigerbeetle_tpu.metrics import FlightRecorder

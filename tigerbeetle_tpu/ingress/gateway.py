@@ -26,7 +26,6 @@ from __future__ import annotations
 
 from tigerbeetle_tpu.io.message_bus import TCPMessageBus
 from tigerbeetle_tpu.ingress.regulator import CreditRegulator
-from tigerbeetle_tpu.latency import NULL_ANATOMY
 from tigerbeetle_tpu.vsr.header import HEADER_SIZE, Command, Header
 
 # peeked header fields, layout-pinned at import by io/message_bus.py
@@ -59,10 +58,6 @@ class IngressGateway:
         )
         self.sessions: dict[int, _Session] = {}
         self._inner = None
-        # latency anatomy bound once (one attr hop per frame instead of
-        # two); harness replicas without one get the shared inert
-        # instance
-        self._latency = getattr(replica, "latency", None) or NULL_ANATOMY
         m = replica.metrics
         self._c_admitted = m.counter("ingress.admitted")
         self._c_shed = m.counter("ingress.shed")
@@ -153,14 +148,6 @@ class IngressGateway:
         req = int.from_bytes(
             frame[_REQUEST_OFF : _REQUEST_OFF + 4], "little"
         )
-        # Latency-anatomy arrival stamp (latency.py): the gateway is the
-        # earliest point the process sees the request, so the sampled
-        # request's ingress_admission leg starts HERE — covering gateway
-        # admission plus the replica's dedup/backpressure checks. One
-        # flag test per request frame while unsampled; a stamp consumed
-        # by no record (this frame shed or deduped) goes stale and the
-        # anatomy's freshness guard discards it.
-        self._latency.arrive()
         sess = self.sessions.get(cid)
         if sess is None:
             # new logical session (its register — or the first frame the

@@ -781,10 +781,12 @@ def _watch_line(e: dict) -> str:
         if w:
             parts.append(f"{short}_p99={w['p99']:.0f}us")
     # the interval's dominant latency leg (largest windowed total):
-    # "where did this second's milliseconds go"
+    # "where did this second's milliseconds go" — over every class, so
+    # the per-class copies (`latency.<class>.<leg>_us`) are skipped
     best, best_total = None, 0.0
     for name, w in h.items():
-        if name.startswith("latency.") and name != "latency.e2e_us" \
+        if name.startswith("latency.") and name.count(".") == 1 \
+                and name != "latency.e2e_us" \
                 and not name.endswith(("lag_us", "lane_us")):
             total = w["count"] * w.get("mean", 0.0)
             if total > best_total:

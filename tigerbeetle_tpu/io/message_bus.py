@@ -56,9 +56,17 @@ from __future__ import annotations
 import errno
 import selectors
 import socket
+import struct
+import sys
 import time as _time
 
-from tigerbeetle_tpu.io.network import Address, Handler, Network
+from tigerbeetle_tpu.io.network import (
+    CMD_OFF,
+    OP_OFF,
+    Address,
+    Handler,
+    Network,
+)
 from tigerbeetle_tpu.metrics import NULL_METRICS
 from tigerbeetle_tpu.tracer import NULL_TRACER
 from tigerbeetle_tpu.vsr.header import HEADER_SIZE, Command, Header, trace_id
@@ -66,6 +74,41 @@ from tigerbeetle_tpu.vsr.header import HEADER_SIZE, Command, Header, trace_id
 MESSAGE_SIZE_MAX_DEFAULT = 1 << 20
 # bus.frame_recv_us times only frames that cannot arrive in one read
 FRAME_RECV_MIN = 1 << 16
+# Kernel receive stamps for the latency anatomy's socket_wait leg: Python's
+# socket module has no SO_TIMESTAMPNS constant; Linux's is 35. With it set,
+# every recvmsg carries one SCM_TIMESTAMPNS control message (level
+# SOL_SOCKET, type SO_TIMESTAMPNS): a CLOCK_REALTIME timespec.
+_SO_TIMESTAMPNS = getattr(
+    socket, "SO_TIMESTAMPNS", 35 if sys.platform.startswith("linux") else None
+)
+_TIMESPEC = struct.Struct("qq")
+_CMSG_SPACE = socket.CMSG_SPACE(_TIMESPEC.size) if _SO_TIMESTAMPNS else 0
+
+
+def _rx_stamp(ancdata) -> int:
+    """The kernel's receive time (CLOCK_REALTIME ns) in one recvmsg's
+    control messages; 0 when there is none."""
+    for level, kind, data in ancdata:
+        if (
+            level == socket.SOL_SOCKET
+            and kind == _SO_TIMESTAMPNS
+            and len(data) >= _TIMESPEC.size
+        ):
+            sec, nsec = _TIMESPEC.unpack_from(data)
+            return sec * 1_000_000_000 + nsec
+    return 0
+
+
+def _enable_rx_stamps(sock) -> bool:
+    """Ask the kernel to stamp what `sock` receives; False where it
+    refuses (or the platform has no such option)."""
+    if _SO_TIMESTAMPNS is None:
+        return False
+    try:
+        sock.setsockopt(socket.SOL_SOCKET, _SO_TIMESTAMPNS, 1)
+    except OSError:
+        return False
+    return True
 
 
 class MessagePool:
@@ -99,7 +142,7 @@ class _Conn:
     __slots__ = (
         "sock", "peer", "connected", "rbuf", "roff", "wbuf",
         "sessions", "strikes", "pending_traces", "pending_lat",
-        "rx_first_ns", "rx_last_ns",
+        "rx_first_ns", "rx_last_ns", "stamped", "rx_marks",
     )
 
     def __init__(self, sock: socket.socket, peer: Address | None = None,
@@ -129,6 +172,12 @@ class _Conn:
         # this connection was last read (perf_counter_ns; 0 = metrics off)
         self.rx_first_ns = 0
         self.rx_last_ns = 0
+        # latency anatomy: this connection is read with recvmsg and
+        # SO_TIMESTAMPNS, and rx_marks holds, for each read whose bytes
+        # are still in rbuf, (rbuf offset just past them, the kernel's
+        # receive stamp of them)
+        self.stamped = False
+        self.rx_marks: list[tuple[int, int]] = []
 
 
 class TCPMessageBus(Network):
@@ -139,13 +188,22 @@ class TCPMessageBus(Network):
     # the counters exist to observe.
     tracer = NULL_TRACER
     _metrics = NULL_METRICS
-    # per-request latency anatomy (latency.py LatencyAnatomy), installed
-    # by the composition root next to `defer_egress = True`: the replica
-    # parks each sampled reply's record in `latency.pending_egress`
-    # keyed by (client, context), send() claims it for the connection
-    # that queues the reply frame, and the flush that writes the conn
-    # closes the record (reply_egress = finalize -> first socket write)
+    # per-request latency anatomy (latency.py LatencyAnatomy), handed
+    # over by the replica's attach. Ingress: while it samples
+    # (`sample_every > 0`) each request frame is handed over just before
+    # its handler runs (`received`), with the kernel's receive stamp of
+    # its first byte where the kernel gives one: the listener has
+    # SO_TIMESTAMPNS set (every connection it accepts inherits it, for
+    # what arrives before the accept too), and connections accepted
+    # while the anatomy samples are read with recvmsg (a kernel that
+    # refuses the option, as gVisor's does with EINVAL, gives none).
+    # Egress (`defer_egress`, set by the composition root): the replica
+    # parks each sampled reply's record in `latency.pending_egress` keyed
+    # by (client, context), send() claims it for the connection that
+    # queues the reply frame, and the flush that writes the conn closes
+    # the record (reply_egress = finalize -> first socket write)
     latency = None
+    _stamp = False  # the listener has SO_TIMESTAMPNS set
 
     @property
     def metrics(self):
@@ -238,8 +296,15 @@ class TCPMessageBus(Network):
 
     # -- Network seam --
 
-    def attach(self, addr: Address, handler: Handler) -> None:
+    def attach(self, addr: Address, handler: Handler,
+               latency=None) -> None:
         self.handlers[addr] = handler
+        if latency is not None:
+            self.latency = latency
+            self._stamp = (
+                self.listener is not None
+                and _enable_rx_stamps(self.listener)
+            )
 
     # Sends below this wbuf level defer their socket write to the pump
     # turn's flush: a window of replies coalesces into ONE send syscall
@@ -424,6 +489,8 @@ class TCPMessageBus(Network):
             s.setblocking(False)
             s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             conn = _Conn(s)
+            # the listener's option is inherited
+            conn.stamped = self._stamp and self.latency.sample_every > 0
             self._links[conn] = None
             self.sel.register(s, selectors.EVENT_READ, ("conn", conn))
             self._c_accepts.add()
@@ -496,7 +563,7 @@ class TCPMessageBus(Network):
         last turn) are drained FIRST, before the select — fairness is
         round-robin across turns, not starvation of the patient."""
         dispatched = 0
-        t0 = _time.perf_counter_ns() if self.metrics.enabled else 0
+        timed = self.metrics.enabled
         self.flush_pending()  # deferred sends must not wait out the select
         if self._hot:
             timeout = 0.0  # buffered work exists: never block the select
@@ -540,10 +607,16 @@ class TCPMessageBus(Network):
             # error, buffered frames STILL dispatch before the close —
             # a one-shot client may send its request and close.
             closing = False
-            t_read = _time.perf_counter_ns() if t0 else 0
+            t_read = _time.perf_counter_ns() if timed else 0
+            stamped = conn.stamped
             for _ in range(64):
                 try:
-                    chunk = conn.sock.recv(1 << 18)
+                    if stamped:
+                        chunk, anc, _flags, _addr = conn.sock.recvmsg(
+                            1 << 18, _CMSG_SPACE
+                        )
+                    else:
+                        chunk = conn.sock.recv(1 << 18)
                 except OSError as e:
                     if e.errno not in (errno.EAGAIN, errno.EWOULDBLOCK):
                         closing = True
@@ -552,6 +625,8 @@ class TCPMessageBus(Network):
                     closing = True
                     break
                 conn.rbuf += chunk
+                if stamped:
+                    conn.rx_marks.append((len(conn.rbuf), _rx_stamp(anc)))
                 if len(chunk) < (1 << 18):
                     break
             if t_read and len(conn.rbuf) > conn.roff:
@@ -562,13 +637,8 @@ class TCPMessageBus(Network):
             if closing:
                 self._close(conn)
         self.flush_pending()  # this turn's handler sends leave with it
-        if dispatched and t0:
-            # only turns that dispatched frames: idle selects would bury
-            # the signal (and cost a histogram write per quiet turn)
+        if dispatched:
             self._c_frames.add(dispatched)
-            self.metrics.histogram("bus.pump_us").observe(
-                (_time.perf_counter_ns() - t0) / 1000.0
-            )
         return dispatched
 
     # Peeked header fields (framing + session aliasing read a handful of
@@ -581,8 +651,8 @@ class TCPMessageBus(Network):
     _CLIENT_OFF = 48
     _CONTEXT_OFF = 64  # context u128 (request checksum on reply frames)
     _REQUEST_OFF = 80
-    _CMD_OFF = 125
-    _OP_OFF = 126  # `operation` u8
+    _CMD_OFF = CMD_OFF
+    _OP_OFF = OP_OFF  # `operation` u8
 
     def _drain(self, conn: _Conn, budget: int | None = None) -> int:
         n = 0
@@ -600,6 +670,14 @@ class TCPMessageBus(Network):
         # frames this parse pass dispatches (annotated onto the span at
         # the end — the ids are learned frame by frame)
         parse_traces: list[int] = [] if tok else None
+        # latency anatomy (while it samples): each request frame's handler
+        # entry, with the receive stamps of the reads still in rbuf,
+        # walked forward as frames are consumed (frames start in order)
+        lat = self.latency
+        if lat is not None and lat.sample_every <= 0:
+            lat = None
+        marks = conn.rx_marks
+        mi = 0
         mv = memoryview(buf)
         try:
             while len(buf) - conn.roff >= HEADER_SIZE:
@@ -616,13 +694,15 @@ class TCPMessageBus(Network):
                     return n
                 if len(buf) - conn.roff < size:
                     break
-                frame = bytes(mv[conn.roff : conn.roff + size])
+                start = conn.roff
+                frame = bytes(mv[start : start + size])
                 conn.roff += size
                 if conn.rx_first_ns:
                     if size > FRAME_RECV_MIN:
-                        # a 1 MiB create takes several reads, and the
-                        # request's own clock (latency.e2e_us) starts
-                        # only once its handler runs
+                        # a 1 MiB create takes several reads; with kernel
+                        # stamps the request's own clock (latency.e2e_us)
+                        # starts at its first byte's receive, else once
+                        # its handler runs
                         self._h_frame_recv.observe(
                             (_time.perf_counter_ns() - conn.rx_first_ns)
                             / 1000.0
@@ -696,6 +776,17 @@ class TCPMessageBus(Network):
                 else:
                     handler = self.handlers.get(self.own)
                 if handler is not None:
+                    if lat is not None and frame[self._CMD_OFF] == _CMD_REQUEST:
+                        # the receive stamp of the read that brought the
+                        # frame's first byte (none on an unstamped conn):
+                        # the request waited in the socket and in rbuf
+                        # since then
+                        while mi < len(marks) and marks[mi][0] <= start:
+                            mi += 1
+                        lat.received(
+                            frame[self._OP_OFF],
+                            marks[mi][1] if mi < len(marks) else 0,
+                        )
                     handler(conn.peer, frame)
                     n += 1
         finally:
@@ -711,6 +802,9 @@ class TCPMessageBus(Network):
                 buf.clear()
             else:
                 del buf[: conn.roff]
+            if marks:
+                roff = conn.roff
+                conn.rx_marks = [(e - roff, st) for e, st in marks if e > roff]
             conn.roff = 0
         return n
 

@@ -19,13 +19,26 @@ from __future__ import annotations
 from collections import deque
 from typing import Callable
 
+from tigerbeetle_tpu.vsr.header import Command
+
 Address = int  # replica index (< 2^32) or client id (u128)
 Handler = Callable[[Address, bytes], None]
 Filter = Callable[[Address, Address, bytes], bool]  # True = deliver
 
+# a frame's `command` and `operation` bytes (vsr/header.py layout;
+# io/message_bus.py pins both at import)
+CMD_OFF = 125
+OP_OFF = 126
+CMD_REQUEST = int(Command.request)
+
 
 class Network:
-    def attach(self, addr: Address, handler: Handler) -> None:
+    def attach(self, addr: Address, handler: Handler,
+               latency=None) -> None:
+        """Deliver the frames for `addr` to `handler`. `latency` (a
+        replica's latency.LatencyAnatomy) is handed every request frame
+        for `addr` just before its handler runs (`received`): the
+        handler entry its records open at."""
         raise NotImplementedError
 
     def send(self, src: Address, dst: Address, data: bytes) -> None:
@@ -40,13 +53,17 @@ class Network:
 class InProcessNetwork(Network):
     def __init__(self):
         self.handlers: dict[Address, Handler] = {}
+        self.anatomies: dict[Address, object] = {}
         self.queue: deque[tuple[Address, Address, bytes]] = deque()
         self.filters: list[Filter] = []
         self.delivered = 0
         self.dropped = 0
 
-    def attach(self, addr: Address, handler: Handler) -> None:
+    def attach(self, addr: Address, handler: Handler,
+               latency=None) -> None:
         self.handlers[addr] = handler
+        if latency is not None:
+            self.anatomies[addr] = latency
 
     def send(self, src: Address, dst: Address, data: bytes) -> None:
         self.queue.append((src, dst, bytes(data)))
@@ -68,6 +85,9 @@ class InProcessNetwork(Network):
             self.dropped += 1
             return True
         self.delivered += 1
+        lat = self.anatomies.get(dst)
+        if lat is not None and data[CMD_OFF] == CMD_REQUEST:
+            lat.received(data[OP_OFF])
         handler(src, data)
         return True
 

@@ -6,11 +6,24 @@ pipeline stage and measures the latency/throughput frontier it feeds);
 this module is our decomposition seam: every sampled request is stamped
 with monotonic timestamps as it crosses the named pipeline legs
 
-    ingress_admission -> wal_write -> quorum_wait -> fuse_hold ->
-    commit_dispatch -> commit_wait -> commit_finalize -> reply_egress
+    socket_wait -> ingress_admission -> wal_write -> quorum_wait ->
+    fuse_hold -> commit_dispatch -> commit_wait -> commit_finalize ->
+    reply_egress
 
 and at reply egress the stamps fold into one `latency.<leg>_us`
-histogram per leg plus `latency.e2e_us` (metrics.py CATALOG for units).
+histogram per leg plus `latency.e2e_us`, and again into the same names
+under the request's operation class, `latency.<class>.<leg>_us` and
+`latency.<class>.e2e_us` (classes: create, lookup, other; metrics.py
+CATALOG for units). The first leg is the socket's: every transport
+hands each request frame over to its destination's anatomy just before
+the frame's handler runs (`received()`, the handler entry), the TCP bus
+with the kernel's receive stamp of the frame's first byte where the
+kernel gives one (it reads client sockets with SO_TIMESTAMPNS), so a
+request that sat readable while the loop was busy books that wait in
+`socket_wait`. Without a kernel stamp (in-process transports, the
+simulator, a kernel that refuses the option) the record opens at
+handler entry, `socket_wait` folds as 0 and `latency.unstamped` counts
+the record.
 Legs are CONSECUTIVE intervals between stamps, so for any single
 request sum(legs) == e2e exactly — the decomposition accounts for all
 of the time by construction (the bench frontier asserts the accounted
@@ -23,12 +36,23 @@ into e2e.
 
 SAMPLING: stamping every request would cost ~2.5us of pure Python per
 request (9 clock reads + list appends), so the anatomy samples one
-request in `sample_every` (default 16; 1 = every request, 0 = off).
-Unsampled requests pay only the `want()` countdown plus a handful of
-`if token:` guards — the no-op-backend budget test in tests/test_latency
-pins the amortized cost under 1us/request. The top-K ring therefore
-holds the slowest SAMPLED requests; crank --latency-sample-every 1 when
-hunting a specific regression.
+request in `sample_every` (default 16; 1 = every request, 0 = off),
+counted per operation class on its own countdown, plus a floor: a
+record that opens SAMPLE_FLOOR_NS (10 ms) or more after its class's last
+one (on the anatomy's clock) has the class sample its next request too.
+A class that arrives that sparsely (a server at ordinary load: tens of
+requests a second a class) so has every request recorded; a client
+firing small batches fast still pays ~1 in 16. The floor reads the
+clock a record opens with, so the unsampled path reads none.
+Unsampled requests pay only the handoff's list test, the `want()`
+countdown and a handful of `if token:` guards — the no-op-backend
+budget test in tests/test_latency pins the amortized cost under
+1us/request. The top-K ring therefore holds the slowest SAMPLED
+requests; crank --latency-sample-every 1 when hunting a specific
+regression. A record the STRIDE picked says so (`strided()`): the dual
+applier fences only those (vsr/replica.py passes `lat_ns` for them
+alone), so the floor never makes the device anatomy's fenced samples
+more frequent.
 
 DETERMINISM: the replica constructs its anatomy with the Time seam's
 monotonic clock (io/time.py), so simulator runs stamp with virtual
@@ -50,25 +74,43 @@ socket write.
 from __future__ import annotations
 
 from time import perf_counter_ns  # vet: observability-only default clock
+from time import time_ns  # vet: observability-only (kernel receive stamps)
 
-from tigerbeetle_tpu.metrics import NULL_METRICS
+from tigerbeetle_tpu.metrics import LATENCY_CLASSES, LATENCY_LEGS, NULL_METRICS
+from tigerbeetle_tpu.types import Operation
 
 # Leg ids (stamp order on the primary's durable path; a leg a request
 # never crosses — e.g. fuse_hold with the window off — folds as 0us and
 # is dropped from its breakdown record).
-LEG_INGRESS = 0  # arrival (gateway admit) -> admission/dedup done
-LEG_WAL = 1  # prepare built + WAL write issued (sync path: completed)
-LEG_QUORUM = 2  # broadcast -> replication quorum reached
-LEG_FUSE = 3  # quorum-ready -> commit dispatch entry (group-fuse hold)
-LEG_DISPATCH = 4  # commit dispatch (stage + launch)
-LEG_WAIT = 5  # dispatch -> finalize entry (async window / device compute)
-LEG_FINALIZE = 6  # finalize (WAL ack wait + drain + reply build)
-LEG_EGRESS = 7  # reply built -> reply leaves (bus flush / send)
+LEG_SOCKET = 0  # kernel receive -> handler entry (socket queue, busy loop)
+LEG_INGRESS = 1  # handler entry -> admission/dedup done
+LEG_WAL = 2  # prepare built + WAL write issued (sync path: completed)
+LEG_QUORUM = 3  # broadcast -> replication quorum reached
+LEG_FUSE = 4  # quorum-ready -> commit dispatch entry (group-fuse hold)
+LEG_DISPATCH = 5  # commit dispatch (stage + launch)
+LEG_WAIT = 6  # dispatch -> finalize entry (async window / device compute)
+LEG_FINALIZE = 7  # finalize (WAL ack wait + drain + reply build)
+LEG_EGRESS = 8  # reply built -> reply leaves (bus flush / send)
 
-LEGS = (
-    "ingress_admission", "wal_write", "quorum_wait", "fuse_hold",
-    "commit_dispatch", "commit_wait", "commit_finalize", "reply_egress",
+LEGS = LATENCY_LEGS
+
+# Operation classes, each sampled on its own countdown and folded into
+# its own `latency.<class>.*` histograms.
+CLASSES = LATENCY_CLASSES
+CLS_CREATE, CLS_LOOKUP, CLS_OTHER = 0, 1, 2
+_CLASS_OF = tuple(
+    CLS_CREATE
+    if op in (Operation.create_accounts, Operation.create_transfers)
+    else CLS_LOOKUP
+    if op in (Operation.lookup_accounts, Operation.lookup_transfers)
+    else CLS_OTHER
+    for op in range(256)
 )
+_OP_NAMES = {int(op): op.name for op in Operation}
+
+# A record that opens this long after its class's last one has the
+# class sample its next request too, whatever its countdown says.
+SAMPLE_FLOOR_NS = 10_000_000
 
 # Parallel-lane histogram names (observed by their owning components —
 # dual_ledger's apply loop and the journal's writer pool — never folded
@@ -76,24 +118,21 @@ LEGS = (
 LANE_DEVICE_APPLY = "latency.device_apply_lag_us"
 LANE_WAL = "latency.wal_lane_us"
 
-# A gateway arrival stamp older than this is stale evidence (the frame
-# it timed was dropped before the replica opened a record — a dup, a
-# shed, a non-primary pass-through) and must not inflate the NEXT
-# sampled request's ingress_admission leg.
-_ARRIVAL_STALE_NS = 100_000_000
-
 
 class LatencyAnatomy:
     """Per-request stamp collector + per-leg histogram folder + top-K
-    slowest ring. One per replica; the gateway and bus hold references.
+    slowest ring. One per replica; the transports hold references.
 
-    Protocol (the replica drives it):
-      if anatomy.want():                  # sampling countdown
-          tok = anatomy.open(trace_id)    # begin the record
+    Protocol (the replica drives it; `op` is the request's operation):
+      if anatomy.want(op):                    # per-class sampling
+          tok = anatomy.open(trace_id, op)    # begin the record
       ...
-      if tok: anatomy.stamp(tok, LEG_X)   # consecutive leg boundaries
+      if tok: anatomy.stamp(tok, LEG_X)       # consecutive leg boundaries
       ...
-      anatomy.egress(tok, client, ctx)    # finish (or hand to the bus)
+      anatomy.egress(tok, client, ctx)        # finish (or hand to the bus)
+
+    Every transport calls `received(op, stamp_ns)` just before it hands
+    a request frame to its handler (io/network.py, io/message_bus.py).
     """
 
     def __init__(self, metrics=None, clock=None, sample_every: int = 16,
@@ -104,24 +143,49 @@ class LatencyAnatomy:
         self.sample_every = sample_every
         self.capacity = capacity
         self.top_k = top_k
-        # leg-indexed histogram handles, bound once (a registry lookup
-        # per stamp would dwarf the stamp)
-        self._h = [m.histogram(f"latency.{leg}_us") for leg in LEGS]
-        self._h_e2e = m.histogram("latency.e2e_us")
+        # histogram handles, bound once (a registry lookup per stamp
+        # would dwarf the stamp). A record folds into its class's
+        # histograms alone; the overall `latency.<leg>_us` and
+        # `latency.e2e_us` are their sums (metrics.SumHistogram).
         self._c_samples = m.counter("latency.samples")
         self._c_dropped = m.counter("latency.dropped")
-        # open records: trace id -> [t0, leg, t1, leg, t2, ...]
+        self._c_unstamped = m.counter("latency.unstamped")
+        by_class = [
+            [m.histogram(f"latency.{c}.{leg}_us") for leg in LEGS]
+            + [m.histogram(f"latency.{c}.e2e_us")]
+            for c in CLASSES
+        ]
+        for i, name in enumerate((*LEGS, "e2e")):
+            m.sum_histogram(f"latency.{name}_us", [hs[i] for hs in by_class])
+        # per class: each leg's observe method, the e2e histogram's, and
+        # the samples counter
+        self._fold = [
+            ([h.observe for h in hs[:-1]], hs[-1].observe,
+             m.counter(f"latency.{c}.samples"))
+            for c, hs in zip(CLASSES, by_class)
+        ]
+        # open records: trace id -> [(class, op, strided), t0, leg, t1,
+        # leg, t2, ...]
         self._recs: dict[int, list] = {}
         # deferred-egress handoff to the TCP bus: (client, context) ->
         # token; the bus pops the match when the reply frame is queued
         # and finishes the record at the flush that writes it
         self.defer_egress = False
         self.pending_egress: dict[tuple, int] = {}
-        # sampling state: _take flags the NEXT request as sampled; the
-        # countdown advances in want() on the unsampled path
-        self._take = sample_every > 0
-        self._since = 0
-        self._arrival = 0  # gateway arrival stamp for the sampled-next req
+        # sampling state, one [left, last, class, saved] list a class:
+        # `left` admitted requests of the class go by unsampled before
+        # one is sampled (0 = the next one is; want() counts it down),
+        # `last` is when the class's last record opened, and `saved` is
+        # the stride's own `left` while the floor has set `left` to 0
+        # for one request (-1 = the floor has not)
+        self._cls = [[0, 0, c, -1] for c in range(len(CLASSES))]
+        self._by_op = [self._cls[_CLASS_OF[op]] for op in range(256)]
+        # the transport's handoff of the request frame in hand, kept only
+        # when its class samples its next request: handler entry on this
+        # anatomy's clock (0 = none handed over) and the kernel's receive
+        # stamp, CLOCK_REALTIME (0 = the kernel gave none)
+        self._rx_entry = 0
+        self._rx_stamp = 0
         # top-K slowest sampled requests, ascending by e2e; _slow_min is
         # the current cutoff so the common case is ONE compare
         self._slow: list[tuple[int, dict]] = []
@@ -129,41 +193,72 @@ class LatencyAnatomy:
 
     # -- the hot path ---------------------------------------------------
 
-    def arrive(self) -> None:
-        """Gateway admission stamp (ingress/gateway.py): records the
-        arrival time IF the next request is the sampled one — one attr
-        test per admitted frame otherwise."""
-        if self._take:
-            self._arrival = self._clock()
+    def received(self, op: int, stamp_ns: int = 0) -> None:
+        """The transport, just before it hands a request frame of
+        operation `op` to its handler. If the frame's class samples its
+        next request, stamps handler entry on this anatomy's clock and
+        keeps the kernel's receive stamp of the frame's first byte
+        (`stamp_ns`, CLOCK_REALTIME; 0 = none): if the frame opens the
+        record, that receive is its t0 (the entry where there is none).
+        Any other frame reads no clock: one list test."""
+        if not self._by_op[op][0]:
+            self._rx_entry = self._clock()
+            self._rx_stamp = stamp_ns
 
-    def want(self) -> bool:
-        """Advance the sampling countdown; True when the caller should
-        open() a record for this request. The unsampled path is this one
-        call: a compare or two, an increment, done. sample_every <= 0
-        disables outright — checked first, because the knob can be
-        turned off at runtime while `_take` is still armed from
-        construction."""
+    def want(self, op: int = 0) -> bool:
+        """Advance operation `op`'s class countdown; True when the caller
+        should open() a record for this request: the stride's pick, or
+        the floor's (see open). sample_every <= 0 disables outright —
+        checked first, because the knob can be turned off at runtime
+        while a countdown is still armed from construction."""
         if self.sample_every <= 0:
             return False
-        if self._take:
-            return True
-        self._since += 1
-        if self._since + 1 >= self.sample_every:
-            self._since = 0
-            self._take = True
-        return False
+        c = self._by_op[op]
+        left = c[0]
+        if left:
+            c[0] = left - 1
+            return False
+        return True
 
-    def open(self, tid: int) -> int:
+    def open(self, tid: int, op: int = 0) -> int:
         """Begin the sampled record for trace id `tid`; returns the
         token (the tid) the caller guards later stamps with, or 0 when
-        the record cannot open (duplicate id, sampling raced off)."""
-        if not self._take:
+        the record cannot open (duplicate id, sampling raced off). The
+        kernel's stamp converts here: the receive lies as far before
+        this clock's now as the stamp lies before the wall clock's.
+        The floor: a record that opens SAMPLE_FLOOR_NS or more after its
+        class's last one has its class sample the next request too, so
+        a class that arrives that sparsely is sampled whole, and the
+        unsampled path never reads a clock to find that out."""
+        every = self.sample_every
+        if every <= 0:
             return 0
-        self._take = self.sample_every <= 1
+        c = self._by_op[op]
+        if c[0]:
+            return 0
+        saved = c[3]
+        if saved < 0:
+            strided = True
+            left = every - 1
+        else:
+            # the floor's pick, which the stride counts as one of its own
+            strided = False
+            left = saved - 1
+            c[3] = -1
         now = self._clock()
-        a = self._arrival
-        self._arrival = 0
-        t0 = a if (a and now - a < _ARRIVAL_STALE_NS) else now
+        if left and now - c[1] >= SAMPLE_FLOOR_NS:
+            c[3] = left  # the floor takes the next request
+            left = 0
+        c[0] = left
+        c[1] = now
+        entry = self._rx_entry or now
+        stamp = self._rx_stamp
+        self._rx_entry = self._rx_stamp = 0
+        if stamp:
+            t0 = min(now - (time_ns() - stamp), entry)
+        else:
+            t0 = entry
+            self._c_unstamped.add()
         recs = self._recs
         if tid in recs:
             return 0
@@ -171,8 +266,16 @@ class LatencyAnatomy:
             # evict the oldest open record (its reply was shed/lost)
             recs.pop(next(iter(recs)))
             self._c_dropped.add()
-        recs[tid] = [t0, LEG_INGRESS, now]
+        recs[tid] = [(c[2], op, strided), t0, LEG_SOCKET, entry,
+                     LEG_INGRESS, now]
         return tid
+
+    def strided(self, tok: int) -> bool:
+        """Whether the open record `tok` is the stride's pick (not the
+        floor's): the dual applier's fenced device samples follow these
+        alone."""
+        r = self._recs.get(tok)
+        return r is not None and r[0][2]
 
     def stamp(self, tok: int, leg: int) -> None:
         r = self._recs.get(tok)
@@ -194,24 +297,25 @@ class LatencyAnatomy:
             self.finish(tok)
 
     def finish(self, tok: int) -> None:
-        """Final stamp (reply_egress) + fold into the histograms and the
-        top-K ring. Idempotent: a second finish for the same token is a
-        dict miss."""
+        """Final stamp (reply_egress) + fold into the class's histograms
+        (so into the overall sums) and the top-K ring. Idempotent: a second
+        finish for the same token is a dict miss."""
         r = self._recs.pop(tok, None)
         if r is None:
             return
         r.append(LEG_EGRESS)
         r.append(self._clock())
-        t0 = r[0]
+        legs, e2e_observe, c_samples = self._fold[r[0][0]]
+        t0 = r[1]
         e2e = r[-1] - t0
-        hs = self._h
         prev = t0
-        for i in range(1, len(r), 2):
+        for i in range(2, len(r), 2):
             t = r[i + 1]
-            hs[r[i]].observe((t - prev) / 1000.0)
+            legs[r[i]]((t - prev) / 1000.0)
             prev = t
-        self._h_e2e.observe(e2e / 1000.0)
+        e2e_observe(e2e / 1000.0)
         self._c_samples.add()
+        c_samples.add()
         if e2e > self._slow_min or len(self._slow) < self.top_k:
             self._slow_insert(tok, t0, e2e, r)
 
@@ -226,15 +330,18 @@ class LatencyAnatomy:
     def _slow_insert(self, tok: int, t0: int, e2e: int, r: list) -> None:
         legs: dict[str, float] = {}
         prev = t0
-        for i in range(1, len(r), 2):
+        for i in range(2, len(r), 2):
             t = r[i + 1]
             d = (t - prev) / 1000.0
             prev = t
             if d or r[i] == LEG_EGRESS:
                 name = LEGS[r[i]]
                 legs[name] = round(legs.get(name, 0.0) + d, 3)
+        cls, op, _strided = r[0]
         rec = {
             "trace": f"{tok:016x}",
+            "class": CLASSES[cls],
+            "operation": _OP_NAMES.get(op, op),
             "t0_ns": t0,
             "e2e_us": round(e2e / 1000.0, 3),
             "legs": legs,
@@ -393,17 +500,6 @@ def device_leg_totals(metrics_snapshot: dict) -> dict[str, dict]:
                 "total_us": h["count"] * h.get("mean", 0.0),
             }
     return out
-
-
-class _NullAnatomy(LatencyAnatomy):
-    """Stamping disabled entirely (sample_every=0 shares the same fast
-    path; this exists for callers that want a shared inert instance)."""
-
-    def __init__(self):
-        super().__init__(metrics=NULL_METRICS, sample_every=0)
-
-
-NULL_ANATOMY = _NullAnatomy()
 
 
 def leg_totals(metrics_snapshot: dict) -> dict[str, dict]:

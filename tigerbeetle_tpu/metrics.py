@@ -155,6 +155,41 @@ class Histogram:
         }
 
 
+class SumHistogram:
+    """A histogram that observes nothing itself and reads as the sum of
+    its `parts` (the request anatomy's overall leg histograms are the
+    sums of its per-class ones: a record folds each leg once, and the
+    two views cannot disagree). Read like a Histogram: snapshot,
+    percentile, and the count / total / max / counts the flight recorder
+    takes its windows from."""
+
+    __slots__ = ("name", "unit", "parts")
+
+    def __init__(self, name: str, parts, unit: str = "us"):
+        self.name = name
+        self.unit = unit
+        self.parts = tuple(parts)
+
+    @property
+    def count(self) -> int:
+        return sum(p.count for p in self.parts)
+
+    @property
+    def total(self) -> float:
+        return sum(p.total for p in self.parts)
+
+    @property
+    def max(self) -> float:
+        return max(p.max for p in self.parts)
+
+    @property
+    def counts(self) -> list[int]:
+        return [sum(c) for c in zip(*(p.counts for p in self.parts))]
+
+    percentile = Histogram.percentile
+    snapshot = Histogram.snapshot
+
+
 class StatGroup(Mapping):
     """Dict-compatible read view over `prefix.key` registry counters.
 
@@ -220,6 +255,15 @@ class Metrics:
         if h is None:
             with self._lock:
                 h = self._histograms.setdefault(name, Histogram(name, unit))
+        return h
+
+    def sum_histogram(self, name: str, parts, unit: str = "us"):
+        """Register `name` as the SumHistogram of `parts` (histograms of
+        this registry); an existing one of that name is returned."""
+        with self._lock:
+            h = self._histograms.get(name)
+            if h is None:
+                h = self._histograms[name] = SumHistogram(name, parts, unit)
         return h
 
     def group(self, prefix: str, keys) -> StatGroup:
@@ -556,6 +600,9 @@ class NullMetrics:
     def histogram(self, name: str, unit: str = "us") -> _NullHistogram:
         return _NULL_HISTOGRAM
 
+    def sum_histogram(self, name: str, parts, unit: str = "us"):
+        return _NULL_HISTOGRAM
+
     def group(self, prefix: str, keys) -> dict:
         # a PLAIN dict: no-op groups must still be read/writable in place
         # (callers do stats["k"] reads) — a dict of zeros is exactly that,
@@ -575,6 +622,17 @@ class _NullGroup(dict):
 
 
 NULL_METRICS = NullMetrics()
+
+
+# the request anatomy's consecutive legs and its operation classes
+# (latency.py): one histogram a leg a class, and the overall one a leg
+# their SumHistogram
+LATENCY_LEGS = (
+    "socket_wait", "ingress_admission", "wal_write", "quorum_wait",
+    "fuse_hold", "commit_dispatch", "commit_wait", "commit_finalize",
+    "reply_egress",
+)
+LATENCY_CLASSES = ("create", "lookup", "other")
 
 
 # -- metric-name catalog (units; surfaced in README's observability
@@ -616,7 +674,6 @@ CATALOG = {
     "bus.frames": ("counter", "", "frames parsed and dispatched"),
     "bus.tx_bytes": ("counter", "bytes", "bytes written to sockets"),
     "bus.flushes": ("counter", "", "deferred-send flush passes"),
-    "bus.pump_us": ("histogram", "us", "event-loop pump turns that dispatched frames"),
     "bus.frame_recv_us": (
         "histogram", "us", "frames over 64 KiB: first byte read -> complete and handed on"
     ),
@@ -783,8 +840,11 @@ CATALOG = {
     "ingress.fanout_lag_ops": ("gauge", "ops", "slowest fan-out consumer vs the watermark"),
     # per-request critical-path attribution (tigerbeetle_tpu/latency.py;
     # legs are CONSECUTIVE intervals, so a request's legs sum to its e2e)
+    "latency.socket_wait_us": (
+        "histogram", "us", "kernel receive of the frame's first byte -> handler entry"
+    ),
     "latency.ingress_admission_us": (
-        "histogram", "us", "arrival/gateway admit -> request admission+dedup done"
+        "histogram", "us", "handler entry -> request admission+dedup done"
     ),
     "latency.wal_write_us": (
         "histogram", "us", "prepare built + WAL write issued (sync path: completed)"
@@ -814,6 +874,20 @@ CATALOG = {
     "latency.dropped": (
         "counter", "requests", "open records evicted unfinished (shed/lost replies)"
     ),
+    "latency.unstamped": (
+        "counter", "requests", "records opened without a kernel receive stamp (socket_wait 0)"
+    ),
+    # the same legs by operation class (create / lookup / other), each
+    # class sampled on its own countdown
+    **{f"latency.{cls}.{leg}_us": (
+        "histogram", "us", f"latency.{leg}_us of {cls} requests alone"
+    ) for cls in LATENCY_CLASSES for leg in LATENCY_LEGS},
+    **{f"latency.{cls}.e2e_us": (
+        "histogram", "us", f"latency.e2e_us of {cls} requests alone"
+    ) for cls in LATENCY_CLASSES},
+    **{f"latency.{cls}.samples": (
+        "counter", "requests", f"{cls} requests stamped end to end"
+    ) for cls in LATENCY_CLASSES},
     # parallel lanes (observed off the critical path, never in e2e)
     "latency.device_apply_lag_us": (
         "histogram", "us", "dual mode: commit finalize enqueue -> device upload"

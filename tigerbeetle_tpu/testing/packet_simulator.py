@@ -12,7 +12,14 @@ from __future__ import annotations
 import heapq
 import random
 
-from tigerbeetle_tpu.io.network import Address, Handler, Network
+from tigerbeetle_tpu.io.network import (
+    CMD_OFF,
+    CMD_REQUEST,
+    OP_OFF,
+    Address,
+    Handler,
+    Network,
+)
 
 
 PARTITION_MODES = (
@@ -65,6 +72,7 @@ class PacketSimulator(Network):
         self.replica_count = replica_count
         self.options = options or PacketSimulatorOptions()
         self.handlers: dict[Address, Handler] = {}
+        self.anatomies: dict[Address, object] = {}
         self.queue: list[tuple[int, int, Address, Address, bytes]] = []
         self._seq = 0
         self.tick_now = 0
@@ -82,8 +90,11 @@ class PacketSimulator(Network):
         self.stats = {"sent": 0, "delivered": 0, "lost": 0, "replayed": 0,
                       "partitioned_drops": 0}
 
-    def attach(self, addr: Address, handler: Handler) -> None:
+    def attach(self, addr: Address, handler: Handler,
+               latency=None) -> None:
         self.handlers[addr] = handler
+        if latency is not None:
+            self.anatomies[addr] = latency
 
     # -- faults --
 
@@ -218,6 +229,9 @@ class PacketSimulator(Network):
             if handler is None:
                 continue
             self.stats["delivered"] += 1
+            lat = self.anatomies.get(dst)
+            if lat is not None and data[CMD_OFF] == CMD_REQUEST:
+                lat.received(data[OP_OFF])
             handler(src, data)
             n += 1
         return n

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 import struct
 
 import numpy as np
@@ -35,6 +36,17 @@ assert _WIRE.size == HEADER_SIZE
 _U64 = 0xFFFFFFFFFFFFFFFF
 
 
+@functools.lru_cache(maxsize=4096)
+def _client_mix(client: int) -> int:
+    """The client id's own splitmix64 finalizer, trace_id's first half:
+    memoized, since a session sends many requests."""
+    c = (client ^ (client >> 64)) & _U64
+    x = (c + 0x9E3779B97F4A7C15) & _U64
+    x = (x ^ (x >> 30)) * 0xBF58476D1CE4E5B9 & _U64
+    x = (x ^ (x >> 27)) * 0x94D049BB133111EB & _U64
+    return x ^ (x >> 31)
+
+
 def trace_id(client: int, request_checksum: int) -> int:
     """Cluster-causal trace id: a u64 derived DETERMINISTICALLY from
     (client id, request checksum) — the pair that uniquely names one
@@ -51,12 +63,8 @@ def trace_id(client: int, request_checksum: int) -> int:
     the folded u128s — the client mixes BEFORE the checksum folds in, so
     the derivation is not symmetric in its arguments: cheap, well-mixed,
     pure int math."""
-    c = (client ^ (client >> 64)) & _U64
     s = (request_checksum ^ (request_checksum >> 64)) & _U64
-    x = (c + 0x9E3779B97F4A7C15) & _U64
-    x = (x ^ (x >> 30)) * 0xBF58476D1CE4E5B9 & _U64
-    x = (x ^ (x >> 27)) * 0x94D049BB133111EB & _U64
-    x = (x ^ (x >> 31)) ^ s
+    x = _client_mix(client) ^ s
     x = (x ^ (x >> 30)) * 0xBF58476D1CE4E5B9 & _U64
     x = (x ^ (x >> 27)) * 0x94D049BB133111EB & _U64
     x ^= x >> 31

@@ -360,7 +360,9 @@ class Replica:
         self._adopt: dict[int, Header] | None = None  # op -> wanted header
         self._adopt_commit_max = 0
 
-        network.attach(replica_index, self._on_message)
+        # the transport hands each request frame's arrival to the anatomy
+        # just before this handler runs (io/network.py Network.attach)
+        network.attach(replica_index, self._on_message, latency=self.latency)
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -926,10 +928,16 @@ class Replica:
         # Latency anatomy: the request survived dedup/backpressure and
         # will become an op — open the sampled record (keyed by the
         # cluster-causal trace id; the id derivation is paid only for
-        # sampled requests). ingress_admission closes here: gateway
-        # arrival (or now) -> admission+dedup done.
+        # sampled requests; each operation class samples on its own
+        # countdown). ingress_admission closes here: handler entry (or
+        # now, where no transport handed the frame over) -> admission+
+        # dedup done.
         lat = self.latency
-        lt = lat.open(header.trace()) if lat.want() else 0
+        lt = (
+            lat.open(header.trace(), header.operation)
+            if lat.want(header.operation)
+            else 0
+        )
         op = self.op + 1
         assert op not in self.pipeline
         self._maybe_checkpoint(op)
@@ -2136,10 +2144,14 @@ class Replica:
                 prepare_checksum=header.checksum,
                 trace=self._tid(header),
                 # device-apply lag is a PARALLEL lane of the anatomy
-                # (the reply does not wait for it): sampled ops carry
-                # their enqueue stamp so the apply loop can observe
-                # enqueue->upload into latency.device_apply_lag_us
-                lat_ns=perf_counter_ns() if entry.get("lt") else 0,
+                # (the reply does not wait for it): ops the STRIDE
+                # sampled carry their enqueue stamp so the apply loop can
+                # observe enqueue->upload into latency.device_apply_lag_us
+                # (and fence them; the per-class floor's extra records
+                # must not fence the applier more often)
+                lat_ns=perf_counter_ns()
+                if self.latency.strided(entry.get("lt", 0))
+                else 0,
             )
         if (
             self._dual_apply
