@@ -348,7 +348,7 @@ def test_transfers_lookup_over_a_spill_store_stays_inline():
     ledger = DeviceLedger(TEST_CLUSTER, TEST_PROCESS, forest=forest)
     ledger.instrument(Metrics(), NULL_TRACER)
     assert ledger.spill is not None
-    dev = StateMachine(ledger, TEST_CLUSTER)
+    dev = StateMachine(ledger, TEST_CLUSTER, metrics=ledger.metrics)
     ref = StateMachine(OracleStateMachine(), TEST_CLUSTER)
     ts = 1_000_000_000
     for op, arr in (
@@ -364,12 +364,17 @@ def test_transfers_lookup_over_a_spill_store_stays_inline():
         return (snap.get("ledger.lookup_deferred", 0),
                 snap.get("ledger.lookup_inline", 0))
 
+    def keys():  # the spill merge takes its ids as Python ints
+        snap = ledger.metrics.snapshot()["counters"]
+        return snap["lookup.keys_wire"], snap["lookup.keys_scalar"]
+
     body = encode_ids(list(range(1000, 1040)))
     handle = dev.commit_async(Operation.lookup_transfers, 0, body)
     assert isinstance(handle, bytes)
     assert handle == ref.commit(Operation.lookup_transfers, 0, body)
     assert len(handle) == 32 * 128
     assert counters() == (0, 1)
+    assert keys() == (0, 1)
     # an accounts lookup never touches the spill store: deferred as ever
     body = encode_ids(list(range(1, 25)))
     handle = dev.commit_async(Operation.lookup_accounts, 0, body)
@@ -378,6 +383,7 @@ def test_transfers_lookup_over_a_spill_store_stays_inline():
         Operation.lookup_accounts, 0, body
     )
     assert counters() == (1, 1)
+    assert keys() == (1, 1)
 
 
 def test_backends_without_the_deferred_call_answer_a_lookup_at_dispatch():
@@ -454,6 +460,8 @@ def test_served_loop_answers_a_create_and_the_lookup_behind_it(
     stats = json.loads(next(
         ln for ln in out.splitlines() if ln.startswith("[stats] "))[8:])
     c = stats["metrics"]["counters"]
+    # every lookup's ids went to the backend as the body's own array
+    assert (c["lookup.keys_wire"], c["lookup.keys_scalar"]) == (rounds, 0)
     if backend == "device":
         assert c["ledger.lookup_deferred"] == rounds
         assert c.get("ledger.lookup_inline", 0) == 0

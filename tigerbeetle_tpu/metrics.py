@@ -772,6 +772,13 @@ CATALOG = {
     "ledger.lookup_probe_steps": (
         "counter", "steps", "probe steps the read-only lookup programs ran, booked at finish"
     ),
+    # how a lookup's ids reach the backend (state_machine.py StateMachine)
+    "lookup.keys_wire": (
+        "counter", "", "lookups whose ids went to the backend as the body's key4 array"
+    ),
+    "lookup.keys_scalar": (
+        "counter", "", "lookups whose ids were decoded to Python ints (oracle, spill merge)"
+    ),
     "ledger.drain_all_ok": (
         "counter", "", "drained batches the two-word summary proved all-success"
     ),

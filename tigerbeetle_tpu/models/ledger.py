@@ -590,11 +590,11 @@ def accounts_to_batch(arr: np.ndarray, n_pad: int) -> dict:
     return {"rows": jnp.asarray(_to_rows_np(arr, n_pad))}
 
 
-def ids_to_batch(ids: list[int], n_pad: int) -> dict:
+def ids_to_batch(key4: np.ndarray, n_pad: int) -> dict:
+    """[n, 4] u32 key4 (a lookup body's own words, types.ids_to_key4) -> the
+    lookup programs' batch, zero-padded to n_pad lanes."""
     k4 = np.zeros((n_pad, 4), dtype=np.uint32)
-    for i, x in enumerate(ids):
-        lo, hi = types.split_u128(x)
-        k4[i] = (lo & 0xFFFFFFFF, lo >> 32, hi & 0xFFFFFFFF, hi >> 32)
+    k4[: len(key4)] = key4
     return {"key4": jnp.asarray(k4)}
 
 
@@ -2209,13 +2209,15 @@ class HostLedgerBase:
             else self.kernels.lookup_transfers
         )
 
-    def _lookup_launch(self, kernel, ids: list[int]) -> PendingLookup:
-        """Launch a lookup against the tables as they stand (after every
-        commit dispatched before it, before any dispatched after: one
-        device stream). The outputs stay on the device; nothing waits."""
-        n = len(ids)
+    def _lookup_launch(self, kernel, ids) -> PendingLookup:
+        """Launch a lookup of `ids` (key4, types.as_key4) against the
+        tables as they stand (after every commit dispatched before it,
+        before any dispatched after: one device stream). The outputs stay
+        on the device; nothing waits."""
+        key4 = types.as_key4(ids)
+        n = len(key4)
         return PendingLookup(
-            n, *kernel(self.state, ids_to_batch(ids, self._pad_for(n)))
+            n, *kernel(self.state, ids_to_batch(key4, self._pad_for(n)))
         )
 
     def _lookup_finish(self, pending: PendingLookup):
@@ -2231,7 +2233,7 @@ class HostLedgerBase:
             self._c_lookup_steps.add(int(pending.steps))
         return np.asarray(pending.found)[:n], np.asarray(pending.rows)[:n]
 
-    def _lookup(self, kernel, ids: list[int]):
+    def _lookup(self, kernel, ids):
         return self._lookup_finish(self._lookup_launch(kernel, ids))
 
     def lookup_finish(self, pending: PendingLookup) -> bytes:
@@ -2243,8 +2245,9 @@ class HostLedgerBase:
         found, rows = self._lookup_finish(pending)
         return rows[found].tobytes()
 
-    def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
-        """The reply body of a lookup, launched and read back at once."""
+    def lookup_rows(self, operation: Operation, ids) -> bytes:
+        """The reply body of a lookup of `ids` (key4, types.as_key4),
+        launched and read back at once."""
         return self.lookup_finish(
             self._lookup_launch(self._lookup_kernel(operation), ids)
         )
@@ -3019,14 +3022,18 @@ class DeviceLedger(HostLedgerBase):
 
     # -- lookups (spill-aware: HBM miss falls back to the LSM store) --
 
-    def lookup_async(self, operation: Operation,
-                     ids: list[int]) -> PendingLookup | None:
-        """Launch a lookup and return at once; `lookup_finish` reads it
-        back. None = this lookup must be answered inline: a transfers
-        lookup over a spill store merges rows from the LSM tree, which may
-        raise GridBlockCorrupt, and the replica's stall-and-retry for that
-        lives at dispatch."""
-        if self.spill is not None and operation == Operation.lookup_transfers:
+    def lookup_needs_ints(self, operation: Operation) -> bool:
+        """A transfers lookup over a spill store: the LSM merge looks its
+        misses up by Python int (StateMachine books it `keys_scalar`)."""
+        return self.spill is not None and operation == Operation.lookup_transfers
+
+    def lookup_async(self, operation: Operation, ids) -> PendingLookup | None:
+        """Launch a lookup of `ids` (key4, types.as_key4) and return at
+        once; `lookup_finish` reads it back. None = this lookup must be
+        answered inline: a transfers lookup over a spill store merges rows
+        from the LSM tree, which may raise GridBlockCorrupt, and the
+        replica's stall-and-retry for that lives at dispatch."""
+        if self.lookup_needs_ints(operation):
             return None
         self._c_lookup_deferred.add()
         pending = self._lookup_launch(self._lookup_kernel(operation), ids)
@@ -3039,12 +3046,13 @@ class DeviceLedger(HostLedgerBase):
                 pass  # no async copy: lookup_finish pays the sync cost
         return pending
 
-    def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
+    def lookup_rows(self, operation: Operation, ids) -> bytes:
         self._c_lookup_inline.add()
-        if self.spill is None or operation == Operation.lookup_accounts:
+        if not self.lookup_needs_ints(operation):
             return super().lookup_rows(operation, ids)
-        found, rows = self._lookup(self.kernels.lookup_transfers, ids)
-        return self.spill.merge_lookup_rows(ids, found, rows)
+        key4 = types.as_key4(ids)
+        found, rows = self._lookup(self.kernels.lookup_transfers, key4)
+        return self.spill.merge_lookup_rows(types.key4_to_ids(key4), found, rows)
 
     def lookup_transfers(self, ids: list[int]) -> list[types.Transfer]:
         if self.spill is None:

@@ -229,28 +229,28 @@ class NativeLedger:
 
     # -- lookups --
 
-    def lookup_rows(self, operation: Operation, ids: list[int]) -> bytes:
-        n = len(ids)
-        raw = np.zeros(2 * n, dtype=np.uint64)
-        for i, x in enumerate(ids):
-            raw[2 * i] = x & 0xFFFFFFFFFFFFFFFF
-            raw[2 * i + 1] = x >> 64
+    def lookup_rows(self, operation: Operation, ids) -> bytes:
+        """Found rows in request order, missing ids skipped. `ids`: key4
+        (types.as_key4), whose bytes are the engine's input as they stand."""
+        key4 = np.ascontiguousarray(types.as_key4(ids))
+        n = len(key4)
         out = np.empty(n * 128, dtype=np.uint8)
         # ride the engine worker queue: the read sees every prior commit
+        # (`key4` stays referenced here until the call has returned)
         found = self._submit(
             self._lib.tb_ledger_lookup,
-            self._h, int(operation), raw.tobytes(), n,
+            self._h, int(operation), key4.ctypes.data_as(ctypes.c_char_p), n,
             out.ctypes.data_as(ctypes.c_void_p),
         ).result()
         return out[: found * 128].tobytes()
 
     def lookup_accounts(self, ids) -> list[types.Account]:
-        body = self.lookup_rows(Operation.lookup_accounts, list(ids))
+        body = self.lookup_rows(Operation.lookup_accounts, ids)
         arr = np.frombuffer(body, dtype=types.ACCOUNT_DTYPE)
         return [types.Account.from_np(arr[i]) for i in range(len(arr))]
 
     def lookup_transfers(self, ids) -> list[types.Transfer]:
-        body = self.lookup_rows(Operation.lookup_transfers, list(ids))
+        body = self.lookup_rows(Operation.lookup_transfers, ids)
         arr = np.frombuffer(body, dtype=types.TRANSFER_DTYPE)
         return [types.Transfer.from_np(arr[i]) for i in range(len(arr))]
 

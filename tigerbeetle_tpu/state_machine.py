@@ -28,6 +28,7 @@ import numpy as np
 
 from tigerbeetle_tpu import types
 from tigerbeetle_tpu.constants import ConfigCluster, DEFAULT_CLUSTER
+from tigerbeetle_tpu.metrics import NULL_METRICS
 from tigerbeetle_tpu.types import (
     ACCOUNT_DTYPE,
     CREATE_ACCOUNTS_RESULT_DTYPE,
@@ -79,18 +80,18 @@ def decode_results(body: bytes, operation: Operation) -> list[tuple[int, int]]:
 
 
 def encode_ids(ids: list[int]) -> bytes:
-    out = np.zeros(2 * len(ids), dtype=np.uint64)
-    for i, x in enumerate(ids):
-        lo, hi = types.split_u128(x)
-        out[2 * i] = lo
-        out[2 * i + 1] = hi
-    return out.tobytes()
+    return types.ids_to_key4(ids).tobytes()
+
+
+def decode_key4(body: bytes) -> np.ndarray:
+    """A lookup body's ids as one read-only [n, 4] u32 view of its bytes
+    (types.ids_to_key4's layout): no copy, no Python int."""
+    assert len(body) % ID_SIZE == 0, len(body)
+    return np.frombuffer(body, dtype="<u4").reshape(-1, 4)
 
 
 def decode_ids(body: bytes) -> list[int]:
-    assert len(body) % ID_SIZE == 0, len(body)
-    arr = np.frombuffer(body, dtype=np.uint64)
-    return [types.join_u128(arr[2 * i], arr[2 * i + 1]) for i in range(len(arr) // 2)]
+    return types.key4_to_ids(decode_key4(body))
 
 
 def decode_accounts(body: bytes) -> np.ndarray:
@@ -112,9 +113,13 @@ class StateMachine:
       reply = sm.commit(op, timestamp, body)
     """
 
-    def __init__(self, backend, cluster: ConfigCluster = DEFAULT_CLUSTER):
+    def __init__(self, backend, cluster: ConfigCluster = DEFAULT_CLUSTER,
+                 metrics=NULL_METRICS):
         self.backend = backend
         self.cluster = cluster
+        # how a lookup's ids reach the backend (metrics.CATALOG)
+        self._c_keys_wire = metrics.counter("lookup.keys_wire")
+        self._c_keys_scalar = metrics.counter("lookup.keys_scalar")
 
     # -- body validation & batch sizing --
 
@@ -178,10 +183,11 @@ class StateMachine:
         This is the replica's commit-stage overlap seam (reference:
         src/vsr/replica.zig:3045-3103 commit_dispatch stages)."""
         if operation in _LOOKUP_OPS:
+            key4 = self._lookup_keys(operation, body)
             launch = getattr(self.backend, "lookup_async", None)
-            pending = launch(operation, decode_ids(body)) if launch else None
+            pending = launch(operation, key4) if launch else None
             if pending is None:
-                return self.commit(operation, timestamp, body)
+                return self._lookup_reply(operation, key4)
             return (operation, pending)
         if operation not in _EVENT_DTYPES or not hasattr(
             self.backend, "execute_async"
@@ -270,15 +276,32 @@ class StateMachine:
                 [(i, c) for i, c in enumerate(dense) if c], operation
             )
         if operation in _LOOKUP_OPS:
-            ids = decode_ids(body)
-            if hasattr(self.backend, "lookup_rows"):  # device backends:
-                return self.backend.lookup_rows(operation, ids)  # raw wire rows
-            found = (
-                self.backend.lookup_accounts(ids)
-                if operation == Operation.lookup_accounts
-                else self.backend.lookup_transfers(ids)
+            return self._lookup_reply(
+                operation, self._lookup_keys(operation, body)
             )
-            if operation == Operation.lookup_accounts:
-                return types.accounts_to_np(found).tobytes()
-            return types.transfers_to_np(found).tobytes()
         raise AssertionError(operation)
+
+    # -- lookups: the body's ids as one array --
+
+    def _lookup_keys(self, operation: Operation, body: bytes) -> np.ndarray:
+        """The lookup's ids as the body's own key4 view, booked by how they
+        reach the backend: `lookup.keys_wire` as that array (lookup_rows /
+        lookup_async), `lookup.keys_scalar` decoded to Python ints (the
+        oracle; a backend whose `lookup_needs_ints` says so, a transfers
+        lookup merged over a spill store)."""
+        needs_ints = getattr(self.backend, "lookup_needs_ints", None)
+        scalar = not hasattr(self.backend, "lookup_rows") or (
+            needs_ints is not None and needs_ints(operation)
+        )
+        (self._c_keys_scalar if scalar else self._c_keys_wire).add()
+        return decode_key4(body)
+
+    def _lookup_reply(self, operation: Operation, key4: np.ndarray) -> bytes:
+        """The reply body, computed now: found rows in request order,
+        missing ids skipped."""
+        if hasattr(self.backend, "lookup_rows"):  # every backend but the oracle
+            return self.backend.lookup_rows(operation, key4)  # raw wire rows
+        ids = types.key4_to_ids(key4)
+        if operation == Operation.lookup_accounts:
+            return types.accounts_to_np(self.backend.lookup_accounts(ids)).tobytes()
+        return types.transfers_to_np(self.backend.lookup_transfers(ids)).tobytes()

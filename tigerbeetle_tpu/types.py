@@ -219,6 +219,37 @@ def join_u128(lo: int, hi: int) -> int:
     return (int(hi) << 64) | int(lo)
 
 
+# --- lookup ids as one array (key4: [n, 4] u32, the wire's u128 words) ---
+#
+# A lookup body is n little-endian u128 ids, which read as u32 words ARE the
+# [n, 4] `key4` the probe kernels take and, as bytes, what the C++ engine
+# reads. These two helpers are the only int <-> key4 conversions: used where
+# Python ints are needed (the oracle, the spill merge) or given (list-taking
+# convenience calls).
+
+
+def ids_to_key4(ids) -> np.ndarray:
+    """u128 ints -> [n, 4] u32 key4 (OverflowError outside 0..2^128-1)."""
+    raw = b"".join(int(x).to_bytes(16, "little") for x in ids)
+    return np.frombuffer(raw, dtype="<u4").reshape(-1, 4)
+
+
+def key4_to_ids(key4: np.ndarray) -> list[int]:
+    """[n, 4] u32 key4 -> u128 ints."""
+    w = np.ascontiguousarray(key4, dtype="<u4").view("<u8")  # [n, 2]: lo, hi
+    return [(hi << 64) | lo for lo, hi in zip(w[:, 0].tolist(), w[:, 1].tolist())]
+
+
+def as_key4(ids) -> np.ndarray:
+    """A lookup's ids as key4: an [n, 4] u32 array passes as it is, any
+    other iterable of ints is packed by ids_to_key4."""
+    if isinstance(ids, np.ndarray) and ids.dtype == np.uint32 and ids.ndim == 2:
+        if ids.shape[1] != 4:  # the engine reads 16 bytes a row
+            raise ValueError(f"key4 has 4 words a row, not {ids.shape[1]}")
+        return ids
+    return ids_to_key4(ids)
+
+
 # --- host-side record classes (exact-integer semantics for the oracle) ---
 
 

@@ -202,7 +202,7 @@ class Replica:
             spill = getattr(backend, "spill", None)
             if spill is not None and hasattr(spill, "instrument"):
                 spill.instrument(self.metrics, self.tracer)
-        self.sm = StateMachine(backend, cluster)
+        self.sm = StateMachine(backend, cluster, metrics=self.metrics)
         self.journal = Journal(storage, cluster)
         self.journal.metrics = self.metrics
         self.journal.tracer = self.tracer
