@@ -28,10 +28,10 @@ FP_FIELDS = ("accounts_fp", "transfers_fp", "accounts", "transfers",
              "commit_timestamp")
 
 
-def _ids(body: bytes) -> list[int]:
+def _ids(body: bytes) -> tuple[np.ndarray, np.ndarray]:
+    """A lookup body's ids as (lo, hi) limb arrays."""
     raw = np.frombuffer(body, dtype=np.uint64)
-    lo, hi = raw[0::2].tolist(), raw[1::2].tolist()
-    return [l | (h << 64) for l, h in zip(lo, hi)]
+    return np.ascontiguousarray(raw[0::2]), np.ascontiguousarray(raw[1::2])
 
 
 def replay(answered: list, fault: str | None = None, fault_at: int = 0):
@@ -138,7 +138,8 @@ def compare(records: list, follower: bool, chip_fp: dict | None,
     out["reference"] = {"fast_batches": ref.fast_batches,
                         "scalar_batches": ref.scalar_batches,
                         "fast_events": ref.fast_events,
-                        "scalar_events": ref.scalar_events}
+                        "scalar_events": ref.scalar_events,
+                        "path_events": ref.path_events}
     window_creates = [i for i, r in enumerate(
         [r for r in answered if r.operation == _CREATE_T]) if r.phase == "window"]
     for name in controls:

@@ -6,6 +6,17 @@ accounts (benchmarks/draws/<name>.py), both found by the name the file
 gives. `--seed` draws accounts, amounts and user data only; the schedule (which class when, how many requests, which
 rows a lookup reads) is the same for every seed, so every seed does the
 same work.
+
+Two optional keys of the configuration shape its accounts (PERF.md
+section 4): `account_flags`, a list of rules `{"flag": <AccountFlags name>,
+"accounts": <selector>}` (absent: the last id carries
+debits_must_not_exceed_credits), and `opening_balances`, `{"amount": <n>,
+"accounts": <selector>}`: each selected account is credited `amount` by
+one plain transfer from a funding account, id `accounts` + 1, which
+carries no flag. A selector is "last", "all", {"every": k} (the ids that k
+divides) or {"from": a, "to": b} (inclusive). A mix with a `limit_account`
+class sends its crossing lanes to the last id, so `Stream` refuses rules
+that leave that id without debits_must_not_exceed_credits.
 """
 
 from __future__ import annotations
@@ -26,6 +37,8 @@ from benchmarks.reference.wire_types import (
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ID_BASE = 1_000_000_000
 ID_STRIDE = 10_000  # id space one create request owns (> a full batch)
+OPENING_ID_BASE = ID_BASE // 2  # opening transfers' ids, below every create's
+DEFAULT_FLAGS = [{"flag": "debits_must_not_exceed_credits", "accounts": "last"}]
 
 
 def load_traffic(name: str) -> dict:
@@ -45,6 +58,25 @@ def load_traffic(name: str) -> dict:
     return mix
 
 
+def select_accounts(selector, n: int) -> np.ndarray:
+    """The account ids (1..n) a rule's `accounts` selector names."""
+    if selector == "last":
+        return np.array([n], dtype=np.uint64)
+    if selector == "all":
+        return np.arange(1, n + 1, dtype=np.uint64)
+    if isinstance(selector, dict) and set(selector) == {"every"}:
+        k = int(selector["every"])
+        if k < 1:
+            raise ValueError(f"account selector {selector}: every must be >= 1")
+        return np.arange(k, n + 1, k, dtype=np.uint64)
+    if isinstance(selector, dict) and set(selector) == {"from", "to"}:
+        a, b = int(selector["from"]), int(selector["to"])
+        if not 1 <= a <= b <= n:
+            raise ValueError(f"account selector {selector}: outside 1..{n}")
+        return np.arange(a, b + 1, dtype=np.uint64)
+    raise ValueError(f"unknown account selector {selector!r}")
+
+
 def rng_for(seed: int, stream: int) -> np.random.Generator:
     """Seeds go a little past 2**31; SeedSequence takes any non-negative
     integer, and the stream index keeps the draws of accounts, transfers
@@ -62,8 +94,21 @@ class Stream:
         self.reversed_ids = config.get("id_order", "reversed") == "reversed"
         self.seed = int(seed)
         self.rng = rng_for(seed, 1)
-        self.limit_id = self.n_accounts  # the one limit account, last id
+        self.limit_id = self.n_accounts  # the limit account of limit_account, last id
         self.plain_accounts = self.n_accounts - 1
+        self.flags = np.zeros(self.n_accounts + 1, dtype=np.uint16)  # by id
+        for rule in config.get("account_flags", DEFAULT_FLAGS):
+            self.flags[select_accounts(rule["accounts"], self.n_accounts)] |= int(
+                AccountFlags[rule["flag"]])
+        limit = int(AccountFlags.debits_must_not_exceed_credits)
+        if any(m["do"] == "limit_account" for steps in mix["classes"].values()
+               for m in steps) and not self.flags[self.limit_id] & limit:
+            raise ValueError("the mix's limit_account class needs the last id "
+                             "to carry debits_must_not_exceed_credits (account_flags)")
+        self.opening = config.get("opening_balances")
+        # the funding account of the opening balances, after the others
+        self.funding_id = self.n_accounts + 1 if self.opening else None
+        self.account_count = self.n_accounts + (1 if self.opening else 0)
         self.k = 0  # create requests made so far (schedule position)
         self.lookups_made = 0
         self.draw = mix.get("account_draw") or {"name": "uniform"}
@@ -82,9 +127,40 @@ class Stream:
             arr["ledger"] = 1
             arr["code"] = 1
             arr["user_data_64"] = rng.integers(0, 1 << 40, n, dtype=np.uint64)
+            arr["flags"] = self.flags[lo:lo + n]
             out.append(arr)
-        out[-1]["flags"][-1] = int(AccountFlags.debits_must_not_exceed_credits)
-        assert int(out[-1]["id_lo"][-1]) == self.limit_id
+        if self.funding_id is not None:
+            fund = np.zeros(1, dtype=ACCOUNT_DTYPE)
+            fund["id_lo"] = self.funding_id
+            fund["ledger"] = 1
+            fund["code"] = 1
+            if len(out[-1]) < self.batch:
+                out[-1] = np.concatenate([out[-1], fund])
+            else:
+                out.append(fund)
+        return out
+
+    def opening_batches(self) -> list[np.ndarray]:
+        """Full create_transfers batches that credit each account the
+        configuration's `opening_balances` selects, from the funding
+        account; none without the key. No rng: the creates' draws do not
+        move."""
+        if not self.opening:
+            return []
+        ids = select_accounts(self.opening["accounts"], self.n_accounts)
+        assert len(ids) < ID_BASE - OPENING_ID_BASE
+        out = []
+        for lo in range(0, len(ids), self.batch):
+            part = ids[lo:lo + self.batch]
+            arr = np.zeros(len(part), dtype=TRANSFER_DTYPE)
+            arr["id_lo"] = np.arange(OPENING_ID_BASE + lo,
+                                     OPENING_ID_BASE + lo + len(part), dtype=np.uint64)
+            arr["debit_account_id_lo"] = self.funding_id
+            arr["credit_account_id_lo"] = part
+            arr["amount_lo"] = int(self.opening["amount"])
+            arr["ledger"] = 1
+            arr["code"] = 1
+            out.append(arr)
         return out
 
     # -- create_transfers -------------------------------------------------

@@ -185,6 +185,17 @@ def run_cell(args, rehearse: dict | None = None, fault=None):
                                            events=len(arr)))
             if rec.reply != b"":
                 raise RuntimeError(f"account load failed: {rec.error or rec.reply[:64]}")
+        # the configuration's opening balances: records like any other, so
+        # the reference replays them and the comparison checks each reply
+        opening = stream.opening_batches()
+        for arr in opening:
+            rec = loadgen.call(load.Record(load.CREATE, arr.tobytes(), "setup",
+                                           "load", events=len(arr)))
+            if rec.reply is None:
+                raise RuntimeError(f"opening balances failed: {rec.error}")
+        if opening:
+            log(f"opening balances: {sum(len(a) for a in opening)} transfers in "
+                f"{len(opening)} batches")
 
         ctx: dict = {"cell": cell, "config": config, "mix": mix, "kind": kind,
                      "seconds": args.seconds, "device": dev}
@@ -415,10 +426,11 @@ def peak_bytes(final: dict):
 
 
 def readback_requests(stream, mix: dict, config: dict, seed: int) -> list:
-    """After the window: every account, and the ids of a sample of create
+    """After the window: every account (the funding account of opening
+    balances too), and the ids of a sample of create
     batches drawn from the seed, with the last one sent in it."""
     out = []
-    n, batch = int(config["accounts"]), int(config["batch_events"])
+    n, batch = stream.account_count, int(config["batch_events"])
     for lo in range(1, n + 1, batch):
         ids = np.zeros(2 * min(batch, n + 1 - lo), dtype=np.uint64)
         ids[0::2] = np.arange(lo, lo + len(ids) // 2, dtype=np.uint64)

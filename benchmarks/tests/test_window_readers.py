@@ -152,6 +152,10 @@ def test_every_new_metric_of_benchmark_json_has_its_file_and_its_cells():
         "idle_unnamed_share.sat": sat, "idle_unnamed_share.rate": rate,
     }
     entries = {m["name"]: m for m in bench["per_layer"]}
+    known = {w["name"] for w in bench["workloads"]}
     for name, cells in want.items():
-        assert entries[name]["workloads"] == cells, name
+        # later cells may join a metric; the first ones stay, and every
+        # cell it names is one of BENCHMARK.json's
+        listed = entries[name]["workloads"]
+        assert set(cells) <= set(listed) <= known, name
         assert callable(named("layer_metrics", name.rsplit(".", 1)[0]).read)

@@ -242,16 +242,36 @@ def test_all_classes_cycle_exercises_every_class_and_modifier():
     assert set(mix["cycle"]) == set(mix["classes"])
     # a resolve needs a pending batch before it, in every cycle
     pending = 0
+    behind = mix["classes"]["post_behind"][0]["behind"]
     for cls in mix["cycle"]:
         pending += cls == "pending"
         if cls == "post_void":
             pending -= 1
             assert pending >= 0
+        if cls == "post_behind":  # resolves the oldest, or is pending itself
+            pending += -1 if pending >= behind else 1
+
+
+def test_a_limit_account_class_needs_the_last_id_limited():
+    """limit_account sends its crossing lanes to the last id: rules that
+    leave it unlimited are refused, not run as a limit check of nothing."""
+    mix = traffic.load_traffic(ALL_CLASSES)
+    cfg = tiny_config()
+    traffic.Stream(mix, {**cfg, "account_flags": [
+        {"flag": "debits_must_not_exceed_credits", "accounts": {"every": 3}}]}, 1)
+    with pytest.raises(ValueError, match="limit_account"):
+        traffic.Stream(mix, {**cfg, "account_flags": [
+            {"flag": "debits_must_not_exceed_credits", "accounts": {"from": 1, "to": 10}}]}, 1)
+    with pytest.raises(ValueError, match="limit_account"):
+        traffic.Stream(mix, {**cfg, "account_flags": [
+            {"flag": "credits_must_not_exceed_debits", "accounts": "last"}]}, 1)
 
 
 # -- the reference: numpy path == scalar loop --------------------------------
 
 def test_reference_fast_path_equals_the_scalar_loop():
+    from test_reference_paths import assert_same_state
+
     mix = traffic.load_traffic(ALL_CLASSES)
     cfg = tiny_config()
     st = traffic.Stream(mix, cfg, 2**31 + 5)
@@ -261,34 +281,39 @@ def test_reference_fast_path_equals_the_scalar_loop():
         ts += len(a) + 3
         assert fast.execute(Operation.create_accounts, ts, a) == \
             slow.execute(Operation.create_accounts, ts, a)
+    classes, failed, failed_batches = [], 0, 0
     for _ in range(85):
-        _cls, arr = st.next_create()
+        cls, arr = st.next_create()
+        classes.append(cls)
         ts += len(arr) + 3
-        assert fast.execute(Operation.create_transfers, ts, arr) == \
-            slow.execute(Operation.create_transfers, ts, arr)
-    assert fast.fast_batches > 40 and fast.scalar_batches > 20
-    ids = sorted(slow.transfers)
-    assert fast.lookup_transfer_rows(ids) == b"".join(
-        t.to_np().tobytes() for t in slow.lookup_transfers(ids))
-    acct = sorted(slow.accounts)
-    assert fast.lookup_account_rows(acct) == b"".join(
-        a.to_np().tobytes() for a in slow.lookup_accounts(acct))
-    assert fast.commit_timestamp == slow.commit_timestamp
+        got = fast.execute(Operation.create_transfers, ts, arr)
+        assert got == slow.execute(Operation.create_transfers, ts, arr)
+        failed += len(got)
+        failed_batches += bool(got)
+    # the numpy paths apply only events that succeed, so every failure is
+    # the scalar loop's; each linked20 batch breaks 10 chains of 3
+    assert failed >= 30 * classes.count("linked20") > 0
+    assert fast.scalar_events >= failed
+    assert fast.scalar_batches >= failed_batches
+    assert fast.fast_batches > 40
+    assert all(n > 0 for n in fast.path_events.values()), fast.path_events
+    assert_same_state(fast, slow)
 
 
 # -- run.py end to end on the CPU, at a tiny geometry ------------------------
 
 def rehearse(workload, seconds=3.0, trace_flag=0, controls=(), fault=None,
-             warm_cycles=None, conflicted=False):
+             warm_cycles=None, conflicted=False, config=None):
     """One whole run of run.py on the CPU at the tiny geometry;
     `conflicted` swaps the cell's one plain class for the cycle of every
-    batch class (tests/all_classes.json)."""
+    batch class (tests/all_classes.json); `config` adds keys to the cell's
+    configuration."""
     from benchmarks import run
 
     args = argparse.Namespace(workload=workload, seed=2**31 + 77,
                               seconds=seconds, trace=trace_flag,
                               control=list(controls))
-    reh = {"config": dict(TINY["config"]), "mix": dict(TINY["mix"]),
+    reh = {"config": {**(config or {}), **TINY["config"]}, "mix": dict(TINY["mix"]),
            "rate": TINY["rate"]}
     if conflicted:
         every = traffic.load_traffic(ALL_CLASSES)
@@ -336,6 +361,43 @@ def test_the_controls_come_out_not_correct():
     limits = out["controls"]["ignore_limits"]
     assert limits["correct"] is False and limits["numbers"]["reply_mismatches"] > 0
     assert load.CREATE == int(Operation.create_transfers)
+
+
+def test_limited_funded_accounts_fail_the_ignore_limits_control():
+    """limits_funded.json: every customer account carries
+    debits_must_not_exceed_credits and is funded from the funding account
+    before warm-up; uniform traffic then crosses limits. The sound
+    reference comes out correct, the one that ignores limits does not."""
+    with open(os.path.join(os.path.dirname(ALL_CLASSES), "limits_funded.json")) as f:
+        cfg = json.load(f)
+    seen = {}
+
+    def grab(records, shadow):
+        seen["records"], seen["shadow"] = list(records), dict(shadow)
+
+    result, _code = rehearse(
+        "default_follower.plain_rate", fault=grab,
+        config={k: cfg[k] for k in ("account_flags", "opening_balances")})
+    assert result["correct"] is True
+    records = seen["records"]
+    setup = [r for r in records if r.phase == "load"]
+    accounts = TINY["config"]["accounts"]
+    batches = -(-accounts // TINY["config"]["batch_events"])
+    assert [r.operation for r in setup] == (
+        [int(Operation.create_accounts)] * batches + [int(Operation.create_transfers)] * batches)
+    lookups = [r for r in records if r.phase == "after"
+               and r.operation == int(Operation.lookup_accounts)]
+    assert sum(len(r.reply) for r in lookups) == 128 * (accounts + 1)  # the funding account too
+    out = check.compare(
+        records, True, seen["shadow"].get("fingerprint_device"),
+        {"exit_code": 0, "verified": True, "hash_log_ok": True, "error": None},
+        controls=("ignore_limits", "lost_ack"))
+    assert check.is_correct(out["numbers"])
+    assert out["detail"]["events_failed_by_design"] > 0  # limits were crossed
+    assert out["reference"]["path_events"]["limited"] > 0
+    for name in ("ignore_limits", "lost_ack"):
+        assert out["controls"][name]["correct"] is False, name
+    assert out["controls"]["ignore_limits"]["numbers"]["reply_mismatches"] > 0
 
 
 def _alter_reply(records, shadow):
