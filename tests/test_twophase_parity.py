@@ -530,10 +530,11 @@ def test_benchmark_json_names_the_new_cells_and_their_files():
     for name in ("plans_per_batch.sat", "plan_ms_per_batch.sat",
                  "pending_registry_rows.sat", "kernel_ms_pv_window.sat",
                  "twophase_kernels_roofline.sat"):
-        # the planner's two are read by the linked cell too (PR 37)
+        # the planner's two are read by the linked and the limits cells too
         shared = name in ("plans_per_batch.sat", "plan_ms_per_batch.sat")
         assert per_layer[name]["workloads"] == [two["name"]] + (
-            ["linked_onpath.linked3_sat16"] if shared else [])
+            ["linked_onpath.linked3_sat16", "limits_onpath.limited_sat16"]
+            if shared else [])
         assert per_layer[name]["moves"] == "committed_tps"
     assert two["name"] not in per_layer["commit_kernels_roofline.sat"]["workloads"]
     assert two["name"] not in per_layer["group_fill.sat"]["workloads"]

@@ -660,6 +660,10 @@ CATALOG = {
     "waves.chain_len_max": ("gauge", "waves", "deepest dependency chain wave-executed so far"),
     "waves.occupancy": ("gauge", "", "active-lane fraction per wave of the last scheduled batch"),
     "waves.residue_events": ("counter", "events", "events that fell to the serial residue"),
+    "waves.scan_steps": (
+        "counter", "steps", "wave stepper steps run: each batch's wave count padded to its bucket"
+    ),
+    "waves.live_steps": ("counter", "waves", "waves that held a lane, summed over wave batches"),
     "replica.quorum_wait_us": ("histogram", "us", "prepare broadcast -> replication quorum"),
     "replica.fuse_hold_us": ("histogram", "us", "group-commit fuse-window hold duration"),
     "replica.commit_dispatch_us": ("histogram", "us", "host time staging+launching one commit"),

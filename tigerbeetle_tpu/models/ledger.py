@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from concurrent.futures import Future
 from contextlib import contextmanager
 from time import perf_counter_ns, time  # vet: observability-only (compile sentinel)
 
@@ -260,17 +261,30 @@ def sentinel_jit(name: str, fn, **jit_kwargs):
 # ----------------------------------------------------------------------
 # Deepest dependency chain the wave path executes; lanes past the cap fall
 # to the serial residue (each wave costs a full-batch kernel pass, so past
-# ~this depth the exact scan is cheaper anyway).
+# ~this depth the exact scan is cheaper anyway). It also bounds the
+# planner's longest-path sweeps: WAVE_CAP sweeps settle every lane below
+# the cap (HazardTracker.plan).
 WAVE_CAP = 24
-# Longest-path propagation sweeps before the planner gives up and takes
-# the whole-batch serial escape hatch (multi-key entanglement deeper than
-# this is adversarial, not a workload).
-_WAVE_SWEEPS = 8
 # Compiled wave-count variants: a plan's wave count pads up to the next
 # bucket with all-false (no-op) masks so the scanned wave stepper compiles
 # a handful of shapes, not one per observed depth.
 _WAVE_BUCKETS = (2, 3, 4, 6, 8, 12, 16, WAVE_CAP)
+
+
+def wave_steps(waves: int) -> int:
+    """The steps a batch of `waves` waves scans: its bucket, or the one
+    masked pass a single wave takes."""
+    return 1 if waves == 1 else next(b for b in _WAVE_BUCKETS if b >= waves)
+
+
+# The buckets a full batch lands in when every touch of an account is
+# ordered (each account balance-limited: 10-15 waves at 8190 transfers over
+# 10,000 accounts). A served ledger that holds limit accounts compiles them
+# side by side at its first wave batch (DeviceLedger.warm_waves); a
+# shallower bucket compiles on demand.
+_WAVE_WARM = (8, 12, 16, WAVE_CAP)
 _WAVE_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
+_M64 = (1 << 64) - 1
 # Distinct multiplier for the order-sensitive ACCOUNT key namespace; a
 # cross-namespace hash collision with an id/pending-id key only ADDS a
 # conflict edge (conservative), never drops one.
@@ -1509,6 +1523,40 @@ def _next_pow2(n: int, floor: int = 8) -> int:
     return p
 
 
+def _wave_cache(kernels) -> dict:
+    """(W, n_pad, mode) -> the wave stepper's jit entry point, its compiled
+    program, or a Future of the program while a warm-up compiles it.
+    Cached on the SHARED kernels object."""
+    cache = getattr(kernels, "_wave_cache", None)
+    if cache is None:
+        cache = kernels._wave_cache = {}
+    return cache
+
+
+def _wave_jit(kernels, W: int, n_pad: int, mode: str):
+    """The jit entry point of W dependency-ordered waves over ONE uploaded
+    batch: a lax.scan over the wave masks traces the commit kernel ONCE
+    regardless of W (the _group_stepper lesson), so a multi-wave batch
+    pays a single launch, not one per wave. Each lane is active in
+    exactly one wave and inactive lanes return code 0, so the per-wave
+    results fold with an elementwise max."""
+
+    def step(state, rows, masks, n, timestamp):
+        def body(st, mask):
+            st, r = kernels._commit_transfers(
+                st, {"rows": rows, "mask": mask}, n, timestamp,
+                mode=mode,
+            )
+            return st, r.astype(jnp.uint32)
+
+        state, rs = jax.lax.scan(body, state, masks)
+        return state, jnp.max(rs, axis=0)
+
+    return sentinel_jit(
+        f"wave_stepper_{W}x{n_pad}_{mode}", step, donate_argnums=(0,)
+    )
+
+
 class WavePlan:
     """Deterministic per-batch conflict-wave layout: `wave_of[i]` is event
     i's wave index (-1 = serial residue). Waves dispatch in index order
@@ -1544,6 +1592,8 @@ class HazardTracker:
         # u64 limb columns so the hot-path membership test is vectorized.
         self.limit_account_ids: set[int] = set()
         self._limit_lo = np.empty(0, dtype=np.uint64)
+        self._index_of = None  # the _limit_lo that _limit_index was built for
+        self.last_sweeps = 0
         # Running sum of every transfer amount ever submitted. While this
         # exact upper bound on any balance stays < 2^127, no u128 balance sum
         # can overflow, so overflow codes 47-52 can only arise from per-event
@@ -1696,6 +1746,7 @@ class HazardTracker:
         # exact overflow bound, counted once per batch (see transfers_hazard)
         self.amount_sum += self._batch_amount_sum(arr)
         st = self.plan_stats
+        self.last_sweeps = 0  # longest-path sweeps of this call (span arg)
         if self.amount_sum >= (1 << 127):
             st["serial"] += 1
             return "serial", None
@@ -1844,6 +1895,10 @@ class HazardTracker:
                 break
             residue[lane_e[move]] = True
         else:
+            # the one whole-batch escape left besides the overflow bound
+            # and a batch of almost all residue: a closure still growing
+            # after 64 rounds. Sound as every serial decision is: the scan
+            # runs the whole batch event by event, in order
             st["serial"] += 1
             return "serial", None
 
@@ -1877,21 +1932,26 @@ class HazardTracker:
             lane_start[1:] = el_l[1:] != el_l[:-1]
             starts = np.nonzero(lane_start)[0]
             lanes_u = el_l[starts]
-            for _ in range(_WAVE_SWEEPS):
+            # Every lane's wave stays at most its depth d (the longest
+            # chain of edges ending at it) and, after s sweeps, at least
+            # min(d, s): a sweep carries each path one more edge. So
+            # WAVE_CAP sweeps leave every lane below the cap exact and
+            # every lane at or past it reading WAVE_CAP or more, converged
+            # or not: no batch needs the whole-batch serial scan for want
+            # of sweeps. A sweep that changes nothing is the fixpoint.
+            for sweeps in range(1, WAVE_CAP + 1):
                 w_k = wave[el_k]
                 w2 = rank + np.maximum.accumulate(w_k - rank + off) - off
                 red = np.maximum.reduceat(w2[lo_], starts)
                 if (red <= wave[lanes_u]).all():
                     break
                 wave[lanes_u] = np.maximum(wave[lanes_u], red)
-            else:
-                st["serial"] += 1  # adversarial entanglement: escape hatch
-                return "serial", None
+            self.last_sweeps = sweeps
             # depth cap: capped lanes fall to the residue. Sound without
-            # re-running the closure — wave numbers are monotone along
-            # every key chain, so any lane ordered AFTER a capped lane is
-            # itself capped (also residue, in original order), and lanes
-            # ordered before run in earlier waves, before the residue.
+            # re-running the closure: the capped lanes are exactly those of
+            # depth >= WAVE_CAP (above), so any lane ordered AFTER a capped
+            # lane is itself capped (also residue, in original order), and
+            # lanes ordered before run in earlier waves, before the residue.
             over = wl & (wave >= WAVE_CAP)
             if over.any():
                 residue |= over
@@ -1935,20 +1995,42 @@ class HazardTracker:
         dup[order] = dup_sorted
         return dup
 
+    def _limit_index(self) -> tuple[np.ndarray, np.ndarray]:
+        """The limit ids' (lo, hi) limbs sorted by (lo, hi), rebuilt from
+        `limit_account_ids` whenever `_limit_lo` is a new array (every
+        writer of the set replaces it: note_limit_accounts, a restore)."""
+        if self._index_of is not self._limit_lo:
+            ids = sorted(self.limit_account_ids,
+                         key=lambda x: (x & _M64, x >> 64))
+            self._index = (
+                np.array([x & _M64 for x in ids], dtype=np.uint64),
+                np.array([x >> 64 for x in ids], dtype=np.uint64),
+            )
+            self._index_of = self._limit_lo
+        return self._index
+
     def _touches_limit(self, arr: np.ndarray) -> np.ndarray:
-        lo2 = np.stack([arr["debit_account_id_lo"], arr["credit_account_id_lo"]])
-        hi2 = np.stack([arr["debit_account_id_hi"], arr["credit_account_id_hi"]])
-        pos = np.searchsorted(self._limit_lo, lo2)
-        pos_c = np.minimum(pos, len(self._limit_lo) - 1)
-        cand = self._limit_lo[pos_c] == lo2
-        out = np.zeros(arr.shape[0], dtype=bool)
-        if cand.any():
-            for side in range(2):
-                for i in np.nonzero(cand[side])[0]:
-                    key = int(lo2[side][i]) | (int(hi2[side][i]) << 64)
-                    if key in self.limit_account_ids:
-                        out[i] = True
-        return out
+        """Lanes whose debit or credit account is a balance-limit account,
+        exact over u128 ids and with no loop: searchsorted on the lo limb
+        finds each side's run of equal lo limbs in the sorted ids; a run of
+        one is a member iff its hi limb matches, and a longer run (limit
+        ids that share a lo limb) is searched by the whole id as a
+        big-endian 16-byte key."""
+        lo_s, hi_s = self._limit_index()
+        n = arr.shape[0]
+        if not len(lo_s):
+            return np.zeros(n, dtype=bool)
+        q_lo = np.concatenate([arr["debit_account_id_lo"], arr["credit_account_id_lo"]])
+        q_hi = np.concatenate([arr["debit_account_id_hi"], arr["credit_account_id_hi"]])
+        left = np.searchsorted(lo_s, q_lo, side="left")
+        run = np.searchsorted(lo_s, q_lo, side="right") - left
+        hit = (run == 1) & (hi_s[np.minimum(left, len(lo_s) - 1)] == q_hi)
+        many = run > 1
+        if many.any():
+            keys = np.sort(_id_keys(lo_s, hi_s))
+            q = _id_keys(q_lo[many], q_hi[many])
+            hit[many] = keys[np.minimum(np.searchsorted(keys, q), len(keys) - 1)] == q
+        return hit[:n] | hit[n:]
 
     def note_limit_accounts(self, arr: np.ndarray) -> None:
         limit_bits = validate.A_DR_LIMIT | validate.A_CR_LIMIT
@@ -1965,6 +2047,12 @@ class HazardTracker:
             self._limit_lo = np.sort(
                 np.concatenate([self._limit_lo, np.array(new_lo, dtype=np.uint64)])
             )
+
+
+def _id_keys(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """u128 ids as big-endian 16-byte strings: they order as the ids do."""
+    be = np.stack([hi, lo], axis=1).astype(">u8")
+    return np.ascontiguousarray(be).view("S16").ravel()
 
 
 class PendingLookup:
@@ -2450,6 +2538,13 @@ class DeviceLedger(HostLedgerBase):
         self._acct_limit = (1 << process.account_slots_log2) // 2
         self._xfer_limit = (1 << process.transfer_slots_log2) // 2
         self.hazards = HazardTracker()
+        # A serving process sets warm_waves: once it holds balance-limit
+        # accounts, its first wave batch compiles every wave program such
+        # batches need (_warm_wave_steppers) and waits for them, so none
+        # compiles later, behind the traffic. A ledger without limit
+        # accounts or without wave batches compiles nothing new.
+        self.warm_waves = False
+        self._waves_warmed = False
         # device-anatomy h2d seam: try_execute_group_async stamps the
         # upload-issued boundary here; the dual applier reads it to close
         # its h2d_stage sub-leg. Written and read on whichever thread
@@ -2503,19 +2598,29 @@ class DeviceLedger(HostLedgerBase):
             with self._h_plan.time():
                 tok = self.tracer.start("ledger.plan", batches=1)
                 try:
+                    sweeps = 0
                     if self.mode == "auto":
                         decision, wave_plan = self.hazards.plan(arr)
+                        sweeps = self.hazards.last_sweeps
                         self._c_plan_calls.add()
                     else:  # forced tier (parity tests); the amount bound is unused
                         decision, wave_plan = self.mode, None
                     self.hazards.note_pending(arr)
-                    self.tracer.annotate(tok, tier=decision)
+                    self.tracer.annotate(tok, tier=decision, sweeps=sweeps)
                 finally:
                     self.tracer.stop(tok)
             self._g_registry.set(len(self.hazards.pending_accounts))
             if decision == "waves":
+                if self.warm_waves and not self._waves_warmed and \
+                        self.hazards.limit_account_ids:
+                    self._waves_warmed = True
+                    self._warm_wave_steppers(n_pad)
                 t_launch = perf_counter_ns()  # the waves upload their own rows
-                with self._solo_dispatch(decision, t_launch):
+                with self._solo_dispatch(decision, t_launch), self.tracer.span(
+                    "ledger.waves", waves=wave_plan.n_waves,
+                    steps=wave_steps(wave_plan.n_waves),
+                    residue=wave_plan.residue_n,
+                ):
                     results = self._execute_waves(
                         arr, n, n_pad, nn, ts, timestamp, wave_plan
                     )
@@ -2560,36 +2665,64 @@ class DeviceLedger(HostLedgerBase):
         )
 
     def _wave_stepper(self, W: int, n_pad: int, mode: str):
-        """Jitted dispatch of W dependency-ordered waves over ONE uploaded
-        batch: a lax.scan over the wave masks traces the commit kernel
-        ONCE regardless of W (the _group_stepper lesson), so a multi-wave
-        batch pays a single launch, not one per wave. Each lane is active
-        in exactly one wave and inactive lanes return code 0, so the
-        per-wave results fold with an elementwise max. Cached on the
-        SHARED kernels object; W is bucketed by the caller
-        (_WAVE_BUCKETS) so only a handful of shapes ever compile."""
-        cache = getattr(self.kernels, "_wave_cache", None)
-        if cache is None:
-            cache = self.kernels._wave_cache = {}
-        fn = cache.get((W, n_pad, mode))
+        """The callable for W waves (bucketed by the caller, _WAVE_BUCKETS,
+        so only a handful of shapes ever compile): a program the warm-up
+        compiled, else the jit entry point, which compiles on first call.
+        While a warm-up is compiling, the first wave batch waits for ALL of
+        its programs: none of them then lands later, in the middle of the
+        traffic (a failed one compiles on demand instead)."""
+        cache = _wave_cache(self.kernels)
+        for key, fn in list(cache.items()):
+            if isinstance(fn, Future):
+                try:
+                    cache[key] = fn.result()
+                except Exception:
+                    del cache[key]
+        key = (W, n_pad, mode)
+        fn = cache.get(key)
         if fn is None:
-            kernels = self.kernels
-
-            def step(state, rows, masks, n, timestamp):
-                def body(st, mask):
-                    st, r = kernels._commit_transfers(
-                        st, {"rows": rows, "mask": mask}, n, timestamp,
-                        mode=mode,
-                    )
-                    return st, r.astype(jnp.uint32)
-
-                state, rs = jax.lax.scan(body, state, masks)
-                return state, jnp.max(rs, axis=0)
-
-            fn = cache[(W, n_pad, mode)] = sentinel_jit(
-                f"wave_stepper_{W}x{n_pad}_{mode}", step, donate_argnums=(0,)
-            )
+            fn = cache[key] = _wave_jit(self.kernels, W, n_pad, mode)
         return fn
+
+    def _warm_wave_steppers(self, n_pad: int) -> None:
+        """Compile the wave steppers of _WAVE_WARM at `n_pad`, each on a
+        thread of its own, so that XLA compiles them side by side. They
+        are compiled from the tables' shapes alone (ahead of time: nothing
+        runs, the state is not touched) and called directly, so no call
+        compiles again; each compile is booked with the compile sentinel.
+        The wave batch that started them waits for all of them
+        (_wave_stepper)."""
+        cache = _wave_cache(self.kernels)
+        state = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+            self.state,
+        )
+        sharding = jax.tree.leaves(state)[0].sharding
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        def compile_one(key, fut):
+            W = key[0]
+            try:
+                t0 = perf_counter_ns()
+                jit = _wave_jit(self.kernels, *key)
+                compiled = jit.fn.lower(
+                    state, sds((n_pad, ROW_WORDS), jnp.uint32),
+                    sds((W, n_pad), jnp.bool_), sds((), jnp.int32),
+                    sds((), jnp.uint64),
+                ).compile()
+                COMPILE_SENTINEL.note(jit.name, (perf_counter_ns() - t0) / 1e6)
+                fut.set_result(compiled)
+            except BaseException as e:  # noqa: BLE001 — the caller compiles on demand
+                fut.set_exception(e)
+
+        for W in _WAVE_WARM:
+            key = (W, n_pad, "fast")
+            if key not in cache:
+                fut = cache[key] = Future()
+                threading.Thread(target=compile_one, args=(key, fut),
+                                 name=f"wave-warmup-{W}", daemon=True).start()
 
     def _execute_waves(self, arr, n, n_pad, nn, ts, timestamp: int, plan):
         """Conflict-scheduled wave execution (the HazardTracker.plan
@@ -2607,6 +2740,8 @@ class DeviceLedger(HostLedgerBase):
         wl = wave_of[:n] >= 0
         m = self.metrics
         m.counter("waves.batches").add()
+        m.counter("waves.scan_steps").add(wave_steps(W))
+        m.counter("waves.live_steps").add(W)
         m.histogram("waves.per_batch").observe(W)
         g = m.gauge("waves.chain_len_max")
         g.set(max(g.value, W))
@@ -2621,7 +2756,7 @@ class DeviceLedger(HostLedgerBase):
                 nn, ts, mode=mode,
             )
         else:
-            Wp = next(b for b in _WAVE_BUCKETS if b >= W)
+            Wp = wave_steps(W)
             masks = np.zeros((Wp, n_pad), dtype=bool)  # pad waves: no-ops
             masks[wave_of[:n][wl], np.nonzero(wl)[0]] = True
             self.state, results = self._wave_stepper(Wp, n_pad, mode)(
