@@ -572,7 +572,7 @@ def judge_exit(out: dict, server: Server, rc, need_tiers: bool) -> bool:
     if need_tiers:
         per_fn = (stats.get("compile_sentinel") or {}).get("per_fn") or {}
         tiers = stats.get("split") or {}
-        wave = any(k.startswith("wave_stepper_") for k in per_fn) \
+        wave = "commit_wave_chunks" in per_fn \
             and tiers.get("waves", 0) > 0
         serial = tiers.get("serial", 0) > 0 \
             and tiers.get("residue_events", 0) > 0 \

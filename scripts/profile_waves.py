@@ -1,16 +1,20 @@
-"""The wave programs of the limits cell on the live device: what each
-compiled bucket costs to compile, what it needs beside the tables, and
-what one of its steps costs.
+"""The wave program of the limits cell on the live device: what one pass of
+the masked fast kernel costs at each pass width, and what a batch costs.
 
 Builds a DeviceLedger at the CLI's default geometry, loads the accounts
 and opening balances of `benchmarks/configs/limits_onpath.json` (every
-account debits_must_not_exceed_credits, 9,900 funded), compiles the wave
-steppers the served path warms (DeviceLedger._warm_wave_steppers), then
-commits batches of `benchmarks/traffic/limited_sat16.json` one at a time,
-each waited for. One JSON line per bucket and a summary line; the same
+account debits_must_not_exceed_credits, 9,900 funded), then for each pass
+width in turn (the lanes the wave executor packs a wave's live lanes into,
+`models/ledger.py` WAVE_CHUNK, set here for the sweep and put back at the
+end) commits batches of `benchmarks/traffic/limited_sat16.json` one at a
+time, each waited for; the first batch of a width compiles its program and
+is not timed. Fits ms a batch = passes x fixed + lanes x per-lane over
+every timed batch. One JSON line per width (passes and lanes run a batch,
+ms a batch and a pass, the compile) and a summary line (the fit, the width
+whose batches ran fastest); the same
 lines go to chiprun_out/profile_waves.json.
 
-Run from the repo root: python scripts/profile_waves.py [--batches 48] [--seed 7]
+Run from the repo root: python scripts/profile_waves.py [--batches 12] [--seed 7]
 Rehearse on the CPU at a tiny size (40 accounts, batches of 64):
 JAX_PLATFORMS=cpu python scripts/profile_waves.py --rehearse
 """
@@ -34,10 +38,13 @@ from tigerbeetle_tpu.constants import DEFAULT_PROCESS, ConfigProcess  # noqa: E4
 from tigerbeetle_tpu.models import ledger as L  # noqa: E402
 from tigerbeetle_tpu.types import Operation  # noqa: E402
 
+WIDTHS = (256, 512, 1024, 2048, 8192)
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--batches", type=int, default=48)
+    ap.add_argument("--batches", type=int, default=12,
+                    help="timed batches a width (one more compiles)")
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
@@ -46,11 +53,12 @@ def main(argv=None) -> int:
     device = announce_device()  # names the device; refuses an unasked CPU
     with open(os.path.join(REPO, "benchmarks", "configs", "limits_onpath.json")) as f:
         config = json.load(f)
-    process = DEFAULT_PROCESS
+    process, widths = DEFAULT_PROCESS, WIDTHS
     if args.rehearse:
         config.update(accounts=40, batch_events=64, opening_balances={
             "amount": 1000000, "accounts": {"from": 1, "to": 37}})
         process = ConfigProcess(account_slots_log2=10, transfer_slots_log2=16)
+        widths = (8, 16, 32, 64)
     stream = traffic.Stream(traffic.load_traffic("limited_sat16"), config, args.seed)
     led = L.DeviceLedger(process=process)
     ts = 1 << 40
@@ -61,67 +69,70 @@ def main(argv=None) -> int:
         ts += len(arr)
         assert led.execute(Operation.create_transfers, ts, arr) == []
 
-    n_pad = led._pad_for(int(config["batch_events"]))
-    t0 = time.perf_counter()
-    led._warm_wave_steppers(n_pad)
-    cache = L._wave_cache(led.kernels)
-    programs = {}
-    for W in L._WAVE_WARM:
-        compiled = cache[(W, n_pad, "fast")].result()
-        m = compiled.memory_analysis()
-        programs[W] = {"temp_bytes": m.temp_size_in_bytes,
-                       "argument_bytes": m.argument_size_in_bytes,
-                       "alias_bytes": m.alias_size_in_bytes}
-    warm_s = time.perf_counter() - t0
-    for e in L.COMPILE_SENTINEL.snapshot()["events"]:
-        for W in programs:
-            if e["fn"] == f"wave_stepper_{W}x{n_pad}_fast":
-                programs[W]["compile_ms"] = e["ms"]
-
     plan = led.hazards.plan
-    plan_ms: list[float] = []
+    plans: list = []
 
     def timed_plan(arr):
         t = time.perf_counter()
         out = plan(arr)
-        plan_ms.append(1e3 * (time.perf_counter() - t))
+        plans.append((1e3 * (time.perf_counter() - t), out))
         return out
 
     led.hazards.plan = timed_plan
-    rows = []
-    for _ in range(args.batches):
-        _cls, arr = stream.next_create()
-        ts += len(arr)
-        t = time.perf_counter()
-        pending = led.execute_async(Operation.create_transfers, ts, arr)
-        jax.block_until_ready(pending.results)
-        ms = 1e3 * (time.perf_counter() - t)
-        tier, waves = pending.plan
-        steps = L.wave_steps(waves)
-        failed = sum(1 for c in led.drain(pending) if c)
-        rows.append({"tier": tier, "waves": waves, "steps": steps,
-                     "ms": ms, "plan_ms": plan_ms[-1], "failed": failed})
-    led.check_fault()
-
-    out = []
-    # the first batch of each bucket pays the launch's first run: skip it
-    for W in sorted(programs):
-        seen = [r for r in rows if r["steps"] == W][1:]
-        exec_ms = [r["ms"] - r["plan_ms"] for r in seen]
-        out.append({"bucket": W, **programs[W], "batches": len(seen),
-                    "ms_per_batch": float(np.median(exec_ms)) if seen else None,
-                    "ms_per_step": float(np.median(exec_ms)) / W if seen else None})
-    waves = [r["waves"] for r in rows]
-    steps = [r["steps"] for r in rows]
+    chunk = L.WAVE_CHUNK
+    out, rows = [], []
+    try:
+        for width in widths:
+            L.WAVE_CHUNK = width
+            seen = []
+            for b in range(args.batches + 1):
+                _cls, arr = stream.next_create()
+                ts += len(arr)
+                n = len(arr)
+                t = time.perf_counter()
+                pending = led.execute_async(Operation.create_transfers, ts, arr)
+                jax.block_until_ready(pending.results)
+                ms = 1e3 * (time.perf_counter() - t)
+                plan_ms, (tier, wave_plan) = plans[-1]
+                failed = sum(1 for c in led.drain(pending) if c)
+                if tier != "waves":
+                    continue
+                idx, k = L.wave_chunks(wave_plan.wave_of, n, led._pad_for(n))
+                row = {"width": idx.shape[1], "waves": wave_plan.n_waves,
+                       "passes": k, "lanes": k * idx.shape[1],
+                       "live": n - wave_plan.residue_n, "ms": ms - plan_ms,
+                       "plan_ms": plan_ms, "failed": failed}
+                if b:  # the first batch of a width compiles its program
+                    seen.append(row)
+            compiles = [e["ms"] for e in L.COMPILE_SENTINEL.snapshot()["events"]
+                        if e["fn"] == "commit_wave_chunks"]
+            rows += seen
+            med = lambda key: float(np.median([r[key] for r in seen]))  # noqa: E731
+            out.append({
+                "width": width, "batches": len(seen),
+                "passes_per_batch": med("passes"), "lanes_per_batch": med("lanes"),
+                "live_per_batch": med("live"),
+                "lane_fill": float(sum(r["live"] for r in seen)
+                                   / sum(r["lanes"] for r in seen)),
+                "ms_per_batch": med("ms"),
+                "ms_per_pass": float(np.median([r["ms"] / r["passes"] for r in seen])),
+                "compile_ms": compiles[-1] if compiles else None,
+            })
+        led.check_fault()
+    finally:
+        L.WAVE_CHUNK = chunk
+    a = np.array([[r["passes"], r["lanes"]] for r in rows], dtype=float)
+    (fixed_ms, lane_ms), *_ = np.linalg.lstsq(a, np.array([r["ms"] for r in rows]),
+                                              rcond=None)
     out.append({
-        "device": device, "batches": len(rows), "seed": args.seed,
-        "tiers": {t: sum(1 for r in rows if r["tier"] == t)
-                  for t in sorted({r["tier"] for r in rows})},
-        "waves": {str(w): waves.count(w) for w in sorted(set(waves))},
-        "pad_share": 100.0 * (sum(steps) - sum(waves)) / sum(steps),
-        "plan_ms_median": float(np.median(plan_ms)),
+        "device": device, "seed": args.seed, "batches": len(rows),
+        "fit_fixed_ms_per_pass": float(fixed_ms),
+        "fit_us_per_lane": float(1e3 * lane_ms),
+        "fastest_width": min(out, key=lambda w: w["ms_per_batch"])["width"],
+        "waves": {str(w): sum(1 for r in rows if r["waves"] == w)
+                  for w in sorted({r["waves"] for r in rows})},
+        "plan_ms_median": float(np.median([r["plan_ms"] for r in rows])),
         "failed_per_batch": float(np.mean([r["failed"] for r in rows])),
-        "warm_s": warm_s,
         "peak_bytes_in_use": (jax.devices()[0].memory_stats() or {}).get(
             "peak_bytes_in_use"),
     })

@@ -19,11 +19,11 @@ Contracts under test:
   equals the benchmark's plain reference on the cell's own stream (result
   codes with `exceeds_credits`, every balance, every row), while the
   `ignore_limits` reference does not; it books `waves.scan_steps` /
-  `waves.live_steps`, a `ledger.waves` span a wave batch and the planner's
-  sweeps, and a served ledger compiles its wave programs side by side at
-  its first wave batch;
+  `waves.live_steps` / `waves.scan_lanes` / `waves.live_lanes`, a
+  `ledger.waves` span a wave batch and the planner's sweeps, and compiles
+  its one wave program at its first wave batch;
 - the algorithm's bytes of an all-committed and of an all-declined batch,
-  by hand, and the readers of the five new metrics;
+  by hand, and the readers of the cell's own metrics;
 - the cell rehearsed end to end on the CPU through benchmarks/run.py:
   `correct`, both controls not, no compile in the window.
 """
@@ -262,7 +262,6 @@ def driven():
     # shared with no other test's ledger
     led = L.DeviceLedger(process=ConfigProcess(account_slots_log2=10,
                                                transfer_slots_log2=13))
-    led.warm_waves = True
     m, tracer = Metrics(), JsonTracer()
     led.instrument(m, tracer)
     ref, control = ReferenceLedger(), ReferenceLedger(fault="ignore_limits")
@@ -278,7 +277,7 @@ def driven():
         assert led.execute(Operation.create_transfers, ts, arr) == \
             ref.execute(Operation.create_transfers, ts, arr) == []
         control.execute(Operation.create_transfers, ts, arr)
-    warm = dict(L._wave_cache(led.kernels))
+    warm = led.kernels.commit_wave_chunks.fn._cache_size()
     batches = []
     for _ in range(CREATES):
         _cls, arr = stream.next_create()
@@ -296,8 +295,8 @@ def driven():
              for ts, arr in batches]
     return SimpleNamespace(led=led, ref=ref, control=control, metrics=m,
                            tracer=tracer, batches=batches, got=got, want=want,
-                           wrong=wrong, warm=warm, cache=L._wave_cache(led.kernels),
-                           L=L)
+                           wrong=wrong, warm=warm,
+                           programs=led.kernels.commit_wave_chunks.fn._cache_size())
 
 
 def test_result_codes_equal_the_reference_and_the_empty_accounts_decline(driven):
@@ -329,29 +328,27 @@ def test_every_balance_and_row_equals_the_reference(driven):
 
 def test_the_wave_counters_spans_and_warm_programs(driven):
     counters = driven.metrics.snapshot()["counters"]
-    L = driven.L
     waves = [w for (_s, (_t, w)) in driven.got]
-    steps = [L.wave_steps(w) for w in waves]
+    # a batch of 64 pads to 64 lanes, one pass wide: a pass a wave
     assert counters["ledger.tier.waves"] == CREATES
-    assert counters["waves.live_steps"] == sum(waves)
-    assert counters["waves.scan_steps"] == sum(steps)
-    for name in ("waves.scan_steps", "waves.live_steps"):
+    assert counters["waves.scan_steps"] == counters["waves.live_steps"] == sum(waves)
+    assert counters["waves.scan_lanes"] == 64 * sum(waves)
+    assert counters["waves.live_lanes"] == 64 * CREATES
+    for name in ("waves.scan_steps", "waves.live_steps", "waves.scan_lanes",
+                 "waves.live_lanes"):
         assert name in CATALOG
     spans = [e for e in driven.tracer.events_ordered() if e["name"] == "ledger.waves"]
     assert [(e["args"]["waves"], e["args"]["steps"], e["args"]["residue"])
-            for e in spans] == [(w, s, 0) for w, s in zip(waves, steps)]
+            for e in spans] == [(w, w, 0) for w in waves]
     plans = [e for e in driven.tracer.events_ordered()
              if e["name"] == "ledger.plan" and e["args"].get("tier") == "waves"]
     assert len(plans) == CREATES
     assert all(1 <= e["args"]["sweeps"] <= WAVE_CAP for e in plans)
     # nothing compiled a wave program before the first wave batch; it
-    # compiled the warm buckets, and every wave batch ran a program they
-    # made
-    assert driven.warm == {}
-    assert set(driven.cache) == {(w, 64, "fast") for w in L._WAVE_WARM}
-    assert set(steps) <= set(L._WAVE_WARM)
-    for key in driven.cache:
-        assert not isinstance(driven.cache[key], (L._SentinelJit, L.Future))
+    # compiled ONE, which every later wave batch ran, whatever its waves
+    assert driven.warm == 0
+    assert driven.programs == 1
+    assert len(set(waves)) > 1
 
 
 # -- the roofline and the readers -----------------------------------------
@@ -373,7 +370,7 @@ def synthetic_ctx(records, module_s=0.5, collected_s=0.5, done=20, window_s=40.0
                        "transfer_slots_log2": 40},
             "stats0": stats(7.0, 1), "stats1": stats(7.0 + window_s, 1 + done),
             "trace": {"collected_s": collected_s,
-                      "modules": [["jit_wave_stepper_16x8192_fast", module_s, 1],
+                      "modules": [["jit_commit_wave_chunks", module_s, 1],
                                   ["jit__lookup_accounts", 9.0, 3]]}}
 
 
@@ -417,20 +414,25 @@ def test_the_readers_of_the_new_metrics():
     ctx = {"stats0": counters(**{"ledger.tier.fast": 2, "ledger.tier.fast_pv": 0,
                                  "ledger.tier.waves": 1, "ledger.tier.serial": 5,
                                  "waves.scan_steps": 12, "waves.live_steps": 11,
+                                 "waves.scan_lanes": 6144, "waves.live_lanes": 4096,
                                  "device.tier_busy_s.waves": 1.0,
                                  "device.tier_batches_done.waves": 1}),
            "stats1": counters(**{"ledger.tier.fast": 2, "ledger.tier.fast_pv": 0,
                                  "ledger.tier.waves": 31, "ledger.tier.serial": 15,
                                  "waves.scan_steps": 412, "waves.live_steps": 375,
+                                 "waves.scan_lanes": 216064, "waves.live_lanes": 180136,
                                  "device.tier_busy_s.waves": 25.0,
                                  "device.tier_batches_done.waves": 31})}
     ctx["stats1"]["t"] = 40.0
     assert named("layer_metrics", "waves_share").read(ctx) == 100 * 30 / 40
     assert named("layer_metrics", "wave_pad_share").read(ctx) == 100 * 36 / 400
     assert named("layer_metrics", "kernel_ms_waves_window").read(ctx) == 800.0
+    assert named("layer_metrics", "wave_lane_fill").read(ctx) == \
+        100 * (180136 - 4096) / (216064 - 6144)
     # a program without the new counters (the parent) reads nothing there
     bare = {"stats0": counters(), "stats1": dict(counters(), t=40.0)}
-    for name in ("waves_share", "wave_pad_share", "kernel_ms_waves_window"):
+    for name in ("waves_share", "wave_pad_share", "kernel_ms_waves_window",
+                 "wave_lane_fill"):
         assert named("layer_metrics", name).read(bare) is None
 
 
@@ -443,7 +445,8 @@ def test_the_cell_rehearsed_is_correct_and_its_controls_are_not(capfd):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     new = {"waves_share.sat", "wave_pad_share.sat", "kernel_ms_waves_window.sat",
-           "waves_kernels_roofline.sat", "limit_fail_share.sat"}
+           "waves_kernels_roofline.sat", "limit_fail_share.sat",
+           "wave_lane_fill.sat"}
     listed = {m["name"] for m in run.cell_metrics(bench, "per_layer", CELL)}
     assert new < listed
     assert {m["name"] for m in run.cell_metrics(bench, "end_to_end", CELL)} == \
@@ -466,6 +469,7 @@ def test_the_cell_rehearsed_is_correct_and_its_controls_are_not(capfd):
     assert metrics["waves_share.sat"] == 100.0
     assert metrics["window_compiles"] == 0.0
     assert metrics["limit_fail_share.sat"] > 0.0
-    assert 0.0 <= metrics["wave_pad_share.sat"] < 50.0
+    assert metrics["wave_pad_share.sat"] == 0.0  # no pass runs without a lane
+    assert 0.0 < metrics["wave_lane_fill.sat"] <= 100.0
     assert metrics["kernel_ms_waves_window.sat"] == pytest.approx(
         metrics["kernel_ms_window.sat"])

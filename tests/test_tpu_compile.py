@@ -108,11 +108,11 @@ def _lower_single(name, a):
             a.state, a.sds((16, N_PAD, ledger.ROW_WORDS), jnp.uint32),
             a.sds((16,), jnp.int32), a.sds((16,), jnp.uint64), a.n,
         )
-    if name == "wave_stepper_2_fast":
-        return ledger.DeviceLedger._wave_stepper(
-            stand_in, 2, N_PAD, "fast"
-        ).fn.lower(
-            a.state, a.rows, a.sds((2, N_PAD), jnp.bool_), a.n, a.ts
+    if name.startswith("commit_wave_chunks_"):
+        idx, _k = ledger.wave_chunks(np.zeros(N_PAD, np.int32), N_PAD, N_PAD)
+        return kernels.commit_wave_chunks.fn.lower(
+            a.state, a.rows, a.sds(idx.shape, jnp.int32), a.n, a.n, a.ts,
+            mode=name.removeprefix("commit_wave_chunks_"),
         )
     if name == "fold_group_ring_16x8192":
         return dual_ledger._fold_group_ring_fn(16, N_PAD).fn.lower(
@@ -125,7 +125,8 @@ def _lower_single(name, a):
 
 # (program, ceiling on argument + temp as a share of the chip's 16 GB).
 # Measured here for the described v5e (GiB): state argument 2.38; temp
-# 0.07-0.09 for the fast tiers and steppers, 8.5 for the serial scan.
+# 0.07-0.09 for the fast tiers and the group stepper, 0.01-0.02 for the
+# wave passes, 8.5 for the serial scan.
 SINGLE_CHIP = [
     ("commit_transfers_fast", 0.25),
     ("commit_transfers_fast_pv", 0.25),
@@ -133,7 +134,8 @@ SINGLE_CHIP = [
     ("commit_accounts_fast", 0.25),
     ("group_stepper_16x8192", 0.25),
     ("fold_group_ring_16x8192", 0.01),
-    ("wave_stepper_2_fast", 0.25),
+    ("commit_wave_chunks_fast", 0.25),
+    ("commit_wave_chunks_fast_pv", 0.25),
 ]
 
 
@@ -193,7 +195,10 @@ COMMIT_HLO_SHA256 = {
     "commit_accounts_fast": "a2117884997cded836fea0551fdc4a3aaf55b7ccf419ae435779a1c65009ffc7",
     "group_stepper_16x8192": "3defdf3856e947033731e84ce77f7b74f307deb5ac0d47dc4a5ed6114572853f",
     "fold_group_ring_16x8192": "acc083fa5756196e258d038acbe831c70b81a9877600a215d8473d06293a38e8",
-    "wave_stepper_2_fast": "ecc19a4bff057acf8d71c1b949e61c61d772f8b9bf353a02d9a0f01258bf0fe4",
+    # the wave programs as the wave executor's passes left them
+    "commit_wave_chunks_fast": "b8de9416a6b633d441863916fb0400ad3f5f3be77fa177011bb784d40355b539",
+    "commit_wave_chunks_fast_pv":
+        "5e3599588901617486ba6303876524c51f9a9185c8af5c401fe1c2e69f169194",
 }
 
 

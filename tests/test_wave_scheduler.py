@@ -1,6 +1,8 @@
 """Conflict-wave scheduler (HazardTracker.plan + _execute_waves):
 deterministic wave layout, bit-exact parity vs the scalar oracle on
-adversarial hot-account workloads, and the decision plumbing.
+adversarial hot-account workloads, the executor's passes (each wave's
+live lanes packed into passes of a fixed width, wave_chunks) against the
+serial scan and the oracle, and the decision plumbing.
 
 The determinism contract under test: the wave layout is a PURE FUNCTION
 of the batch bytes plus the tracker's committed-history state — no
@@ -10,15 +12,19 @@ whatever the layout, the committed result codes and the full state are
 bit-exact against the oracle's strictly-serial semantics.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from tigerbeetle_tpu.constants import TEST_PROCESS
 from tigerbeetle_tpu.metrics import CATALOG, Metrics
+from tigerbeetle_tpu.models import ledger as L
 from tigerbeetle_tpu.models.ledger import (
+    FAULT_CAPACITY,
     WAVE_CAP,
     DeviceLedger,
     HazardTracker,
+    wave_chunks,
 )
 from tigerbeetle_tpu.models.oracle import OracleStateMachine
 from tigerbeetle_tpu.types import (
@@ -29,6 +35,7 @@ from tigerbeetle_tpu.types import (
     TransferFlags,
     transfers_to_np,
 )
+from tigerbeetle_tpu.types import CreateTransferResult as R
 
 F_PENDING = int(TransferFlags.pending)
 F_POST = int(TransferFlags.post_pending_transfer)
@@ -36,11 +43,11 @@ F_VOID = int(TransferFlags.void_pending_transfer)
 F_LINKED = int(TransferFlags.linked)
 
 
-def _pair(n_accounts=24, limit_accounts=(), funded=200):
+def _pair(n_accounts=24, limit_accounts=(), funded=200, mode="auto"):
     """(oracle, device, ts): n accounts; `limit_accounts` get
     debits_must_not_exceed_credits and `funded` of credit headroom."""
     oracle = OracleStateMachine()
-    dev = DeviceLedger(process=TEST_PROCESS, mode="auto")
+    dev = DeviceLedger(process=TEST_PROCESS, mode=mode)
     ts = 10_000
     accounts = [
         Account(
@@ -381,6 +388,165 @@ def test_zipfian_hot_mix_randomized_parity(seed):
         ts = _check(oracle, dev, ts, tr)
         next_id += 1000
     assert dev.hazards.plan_stats["waves"] >= 3, dev.hazards.plan_stats
+
+
+# ----------------------------------------------------------------------
+# the executor's passes: each wave's live lanes packed into passes of a
+# fixed width, run in wave order by one program
+# ----------------------------------------------------------------------
+
+PASS = 8  # the pass width these tests run, so a 64-lane batch needs several
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_wave_chunks_packs_every_wave_lane_once_in_wave_then_lane_order(seed):
+    rng = np.random.default_rng(seed)
+    n, n_pad, width = 61, 64, min(L.WAVE_CHUNK, 64)
+    wave_of = rng.integers(-1, 5, size=n_pad).astype(np.int32)
+    wave_of[n:] = -1
+    wave_of[:5] = np.arange(5)  # every wave holds a lane
+    idx, k = wave_chunks(wave_of, n, n_pad)
+    assert idx.shape == (-(-n_pad // width) + WAVE_CAP, width)
+    counts = np.bincount(wave_of[:n][wave_of[:n] >= 0])
+    assert k == int((-(-counts // width)).sum())
+    assert (idx[k:] == n_pad).all()  # no pass past the k-th holds a lane
+    ran = idx[:k][idx[:k] < n_pad]
+    want = np.concatenate([np.flatnonzero(wave_of[:n] == w)
+                           for w in range(len(counts))])
+    assert (ran == want).all()  # each live lane once, waves in order
+    for row in idx[:k]:  # a pass holds one wave, its pads at the end
+        live = row[row < n_pad]
+        assert len(set(wave_of[live])) == 1
+        assert (row[len(live):] == n_pad).all()
+
+
+def _probe(dev) -> HazardTracker:
+    """A tracker that plans as `dev`'s does, without booking the plan."""
+    probe = HazardTracker()
+    probe.limit_account_ids = set(dev.hazards.limit_account_ids)
+    probe._limit_lo = dev.hazards._limit_lo.copy()
+    return probe
+
+
+def _trio(limit_accounts=(), funded=200, n_accounts=48):
+    """(oracle, the auto-planned ledger, the serial-scan ledger, ts)."""
+    oracle, dev, ts = _pair(n_accounts, limit_accounts, funded)
+    _oracle, serial, _ts = _pair(n_accounts, limit_accounts, funded, mode="serial")
+    return oracle, dev, serial, ts
+
+
+def _plain(n, first_id=20_000, accounts=range(20, 40)):
+    acc = list(accounts)
+    return [Transfer(id=first_id + i, debit_account_id=acc[i % len(acc)],
+                     credit_account_id=acc[(i + 7) % len(acc)], amount=1 + i % 3,
+                     ledger=1, code=1) for i in range(n)]
+
+
+def _touches(limited, times, first_id, amount=3):
+    """`times` debits of each limited account, to plain accounts 41-47."""
+    return [Transfer(id=first_id + 100 * t + j, debit_account_id=a,
+                     credit_account_id=41 + (j + t) % 7, amount=amount,
+                     ledger=1, code=1)
+            for t in range(times) for j, a in enumerate(limited)]
+
+
+def _case(name):
+    """(limited accounts, funding, the batch, waves, lanes a wave, residue)."""
+    if name == "a_wave_wider_than_a_pass":
+        # wave 0: 40 plain lanes + the first touch (6 passes); waves 1-2: 1 lane
+        return (5,), 200, _touches([5], 3, 7000)[:1] + _plain(40) + \
+            _touches([5], 3, 7000)[1:], 3, [41, 1, 1], 0
+    if name == "a_wave_of_exactly_a_pass_and_one_of_one_lane":
+        lim = list(range(5, 5 + PASS))
+        tr = _touches(lim, 2, 7000) + _touches([5], 3, 7500)[2:] + _plain(20)
+        return tuple(lim), 200, tr, 3, [PASS + 20, PASS, 1], 0
+    if name == "one_wave_and_a_residue":
+        chain = [Transfer(id=7900, debit_account_id=2, credit_account_id=3,
+                          amount=2, ledger=1, code=1, flags=F_LINKED),
+                 Transfer(id=7901, debit_account_id=2, credit_account_id=3,
+                          amount=2, ledger=1, code=1)]
+        return (5,), 200, _touches([5], 1, 7000) + chain + _plain(40), \
+            1, [41], 2
+    if name == "waves_at_the_cap_and_a_residue":
+        return (5,), 3 * (WAVE_CAP + 3), _touches([5], WAVE_CAP + 3, 7000, 2) + \
+            _plain(20), WAVE_CAP, [21] + [1] * (WAVE_CAP - 1), 3
+    if name == "a_pending_and_its_post_in_one_batch":
+        pend = [Transfer(id=7000 + i, debit_account_id=1 + i % 10,
+                         credit_account_id=11 + i % 8, amount=10 + i, ledger=1,
+                         code=1, flags=F_PENDING) for i in range(14)]
+        post = [Transfer(id=7100 + i, pending_id=7000 + i,
+                         amount=0 if i % 3 else 4 + i,
+                         flags=F_VOID if i % 4 == 3 else F_POST) for i in range(14)]
+        return (), 0, pend + _plain(10) + post, 2, [24, 14], 0
+    if name == "declines_on_empty_limited_accounts":
+        # 5 of 3 each side: the second touch of every account declines
+        lim = list(range(5, 15))
+        return tuple(lim), 5, _touches(lim, 2, 7000) + _plain(20), 2, \
+            [30, 10], 0
+    raise AssertionError(name)
+
+
+CASES = ["a_wave_wider_than_a_pass",
+         "a_wave_of_exactly_a_pass_and_one_of_one_lane",
+         "one_wave_and_a_residue", "waves_at_the_cap_and_a_residue",
+         "a_pending_and_its_post_in_one_batch",
+         "declines_on_empty_limited_accounts"]
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_the_waves_in_passes_equal_the_serial_scan_and_the_oracle(name, monkeypatch):
+    """Codes, every account, every transfer row, the posted set and the
+    commit timestamp of the passes equal the whole-batch serial scan's and
+    the oracle's."""
+    monkeypatch.setattr(L, "WAVE_CHUNK", PASS)
+    limited, funded, tr, waves, lanes, residue = _case(name)
+    oracle, dev, serial, ts = _trio(limited, funded)
+    arr = transfers_to_np(tr)
+    decision, plan = _probe(dev).plan(arr)
+    assert decision == "waves" and plan.n_waves == waves
+    assert plan.residue_n == residue
+    assert np.bincount(plan.wave_of[plan.wave_of >= 0]).tolist() == lanes
+    idx, k = wave_chunks(plan.wave_of, len(tr), 64)
+    assert k == sum(-(-c // PASS) for c in lanes) and idx.shape[1] == PASS
+    ts += len(tr)
+    dense_o = oracle.execute_dense(Operation.create_transfers, ts, tr)
+    dense_s = serial.execute_dense(Operation.create_transfers, ts, tr)
+    dense_d = dev.execute_dense(Operation.create_transfers, ts, tr)
+    assert dense_d == dense_s == dense_o
+    if name == "declines_on_empty_limited_accounts":
+        assert dense_d.count(int(R.exceeds_credits)) == 10
+    assert dev.extract() == serial.extract() == \
+        (oracle.accounts, oracle.transfers, oracle.posted)
+    assert dev.commit_timestamp == serial.commit_timestamp == oracle.commit_timestamp
+    assert dev.hazards.plan_stats["waves"] == 1
+
+
+def test_a_fault_in_a_pass_stops_every_later_pass(monkeypatch):
+    """The device's load guard trips in the second pass of a wave: the first
+    pass stays applied (its rows with their original timestamps), the
+    faulting pass and every later one apply nothing, and the host raises."""
+    monkeypatch.setattr(L, "WAVE_CHUNK", PASS)
+    _oracle, dev, ts = _pair(48, (5,), 200)
+    tr = _touches([5], 3, 7000)[:1] + _plain(40) + _touches([5], 3, 7000)[1:]
+    arr = transfers_to_np(tr)
+    _decision, plan = _probe(dev).plan(arr)
+    idx, k = wave_chunks(plan.wave_of, len(tr), 64)
+    assert k == 8
+    limit = (1 << TEST_PROCESS.transfer_slots_log2) // 2
+    # room for exactly one pass more
+    dev.state = {**dev.state, "xfer_used_slots": jnp.uint64(limit - PASS)}
+    ts += len(tr)
+    pending = dev.execute_async(Operation.create_transfers, ts, arr)
+    assert pending.plan == ("waves", 3)
+    with pytest.raises(RuntimeError, match="capacity"):
+        dev.check_fault()
+    assert int(np.asarray(dev.state["fault"])) & FAULT_CAPACITY
+    assert int(np.asarray(dev.state["xfer_used_slots"])) == limit
+    _accounts, transfers, _posted = dev.extract()
+    first = [tr[i] for i in idx[0]]
+    assert set(transfers) - {900_005} == {t.id for t in first}  # and the funding
+    for i in idx[0]:
+        assert transfers[tr[i].id].timestamp == ts - len(tr) + int(i) + 1
 
 
 # ----------------------------------------------------------------------

@@ -595,10 +595,6 @@ def cmd_start(args) -> int:
         tracer=tracer,
     )
     boot("replica constructed (device state allocated)")
-    if args.backend == "device":
-        # a ledger of limit accounts compiles every wave program side by
-        # side at its first wave batch (DeviceLedger.warm_waves)
-        replica.ledger.warm_waves = True
     # latency anatomy (the bus holds it since the replica's attach):
     # sampling knob, then egress (the bus finishes a sampled record at
     # the flush that writes its reply frame, so the reply_egress leg

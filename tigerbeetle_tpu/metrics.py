@@ -658,12 +658,14 @@ CATALOG = {
     "waves.batches": ("counter", "", "batches executed through the wave scheduler"),
     "waves.per_batch": ("histogram", "waves", "dependency-ordered waves per scheduled batch"),
     "waves.chain_len_max": ("gauge", "waves", "deepest dependency chain wave-executed so far"),
-    "waves.occupancy": ("gauge", "", "active-lane fraction per wave of the last scheduled batch"),
-    "waves.residue_events": ("counter", "events", "events that fell to the serial residue"),
-    "waves.scan_steps": (
-        "counter", "steps", "wave stepper steps run: each batch's wave count padded to its bucket"
+    "waves.occupancy": (
+        "gauge", "", "live lanes over the lanes the wave passes ran, last scheduled batch"
     ),
-    "waves.live_steps": ("counter", "waves", "waves that held a lane, summed over wave batches"),
+    "waves.residue_events": ("counter", "events", "events that fell to the serial residue"),
+    "waves.scan_steps": ("counter", "passes", "wave passes run, summed over wave batches"),
+    "waves.live_steps": ("counter", "passes", "wave passes that held a live lane"),
+    "waves.scan_lanes": ("counter", "lanes", "lanes the wave passes ran, live or padding"),
+    "waves.live_lanes": ("counter", "lanes", "live lanes the wave passes ran"),
     "replica.quorum_wait_us": ("histogram", "us", "prepare broadcast -> replication quorum"),
     "replica.fuse_hold_us": ("histogram", "us", "group-commit fuse-window hold duration"),
     "replica.commit_dispatch_us": ("histogram", "us", "host time staging+launching one commit"),

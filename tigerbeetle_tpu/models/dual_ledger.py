@@ -384,9 +384,9 @@ class DualLedger:
         ts += n
         scratch.execute_async(Operation.create_transfers, ts, post)
         # conflict-wave scheduler: a same-batch pend->post batch compiles
-        # the scanned 2-wave stepper (the smallest _WAVE_BUCKETS shape) so
-        # a dependent-transfer burst doesn't stall the apply loop on a
-        # compile; deeper buckets compile on demand behind the queue
+        # the wave program (commit_wave_chunks, fast_pv: one program for
+        # any wave count) so a dependent-transfer burst doesn't stall the
+        # apply loop on a compile
         half = n // 2
         if half >= 2:
             wav = simple(5_000_000)

@@ -37,13 +37,14 @@ passes in — never a value read from the tables):
 Between the two sits **conflict-wave scheduling** (HazardTracker.plan +
 DeviceLedger._execute_waves): a batch with TRUE dependencies — duplicate
 ids, post/voids of same-batch pendings, touches of balance-limit
-accounts — is partitioned into dependency-ordered waves, each a masked
-fast/fast_pv pass over the same uploaded batch, dispatched in one scanned
-launch; only lanes the masked kernels cannot express (linked chains,
-balancing, unresolvable pending refs against order-sensitive accounts,
-chains deeper than WAVE_CAP) fall to a compacted serial residue. The wave
-layout is a deterministic pure function of the batch bytes + tracker
-state, so replicas and the simulator plan identically.
+accounts — is partitioned into dependency-ordered waves; each wave's
+live lanes are packed into narrow masked fast/fast_pv passes over the same
+uploaded batch, all dispatched in one launch; only lanes the masked
+kernels cannot express (linked chains, balancing, unresolvable pending
+refs against order-sensitive accounts, chains deeper than WAVE_CAP) fall
+to a compacted serial residue. The wave layout is a deterministic pure
+function of the batch bytes + tracker state, so replicas and the
+simulator plan identically.
 
 Both tiers call the same validation ladders (models/validate.py), so result
 codes are bit-exact against the oracle (models/oracle.py) on every path.
@@ -70,7 +71,6 @@ from __future__ import annotations
 
 import threading
 from collections import deque
-from concurrent.futures import Future
 from contextlib import contextmanager
 from time import perf_counter_ns, time  # vet: observability-only (compile sentinel)
 
@@ -260,29 +260,42 @@ def sentinel_jit(name: str, fn, **jit_kwargs):
 # conflict-wave scheduling (HazardTracker.plan / DeviceLedger._execute_waves)
 # ----------------------------------------------------------------------
 # Deepest dependency chain the wave path executes; lanes past the cap fall
-# to the serial residue (each wave costs a full-batch kernel pass, so past
-# ~this depth the exact scan is cheaper anyway). It also bounds the
-# planner's longest-path sweeps: WAVE_CAP sweeps settle every lane below
-# the cap (HazardTracker.plan).
+# to the serial residue. It also bounds the planner's longest-path sweeps:
+# WAVE_CAP sweeps settle every lane below the cap (HazardTracker.plan), and
+# the passes a wave batch can need (wave_chunks).
 WAVE_CAP = 24
-# Compiled wave-count variants: a plan's wave count pads up to the next
-# bucket with all-false (no-op) masks so the scanned wave stepper compiles
-# a handful of shapes, not one per observed depth.
-_WAVE_BUCKETS = (2, 3, 4, 6, 8, 12, 16, WAVE_CAP)
+# Lanes a wave pass runs: each wave's live lanes are packed into passes of
+# this width (the last one of a wave padded) and ONE program runs a batch's
+# passes in wave order (LedgerKernels._wave_chunks). A pass costs the chip
+# ~8.5-8.9 us a lane (the probe-window gathers of every lane) and a fixed
+# part under 0.1 ms, so the narrowest width swept (256-8192) ran the limits
+# cell's batches fastest: 89 ms a batch at 256, 102 at 512, 141 at 1024,
+# 816 at 8192 (TPU v5e, scripts/profile_waves.py; PERF.md section 5).
+WAVE_CHUNK = 256
 
 
-def wave_steps(waves: int) -> int:
-    """The steps a batch of `waves` waves scans: its bucket, or the one
-    masked pass a single wave takes."""
-    return 1 if waves == 1 else next(b for b in _WAVE_BUCKETS if b >= waves)
+def wave_chunks(wave_of: np.ndarray, n: int, n_pad: int) -> tuple[np.ndarray, int]:
+    """The passes of a wave batch: row k of `idx` holds the original lanes
+    the batch's k-th pass runs, the lanes of wave 0 first, each wave's in
+    lane order, every wave starting a pass of its own; a slot past a wave's
+    last lane holds `n_pad` (out of range: a masked lane whose result is
+    dropped). `idx` has a row for the most passes a batch can need, one
+    shape a batch size; returns (idx, the passes this batch needs)."""
+    width = min(WAVE_CHUNK, n_pad)
+    w = wave_of[:n]
+    lanes = np.flatnonzero(w >= 0)
+    order = np.argsort(w[lanes], kind="stable")
+    lanes = lanes[order]
+    waves = w[lanes]
+    counts = np.bincount(waves)
+    passes = -(-counts // width)
+    first_pass = np.cumsum(passes) - passes
+    pos = np.arange(len(lanes)) - (np.cumsum(counts) - counts)[waves]
+    idx = np.full((-(-n_pad // width) + WAVE_CAP, width), n_pad, dtype=np.int32)
+    idx[first_pass[waves] + pos // width, pos % width] = lanes
+    return idx, int(passes.sum())
 
 
-# The buckets a full batch lands in when every touch of an account is
-# ordered (each account balance-limited: 10-15 waves at 8190 transfers over
-# 10,000 accounts). A served ledger that holds limit accounts compiles them
-# side by side at its first wave batch (DeviceLedger.warm_waves); a
-# shallower bucket compiles on demand.
-_WAVE_WARM = (8, 12, 16, WAVE_CAP)
 _WAVE_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
 _M64 = (1 << 64) - 1
 # Distinct multiplier for the order-sensitive ACCOUNT key namespace; a
@@ -790,6 +803,12 @@ class LedgerKernels:
             ),
             donate_argnums=(0,),
         )
+        # The wave executor's program: a batch's waves as passes of the
+        # masked fast / fast_pv kernel over their live lanes (wave_chunks).
+        self.commit_wave_chunks = sentinel_jit(
+            "commit_wave_chunks", self._wave_chunks,
+            static_argnames=("mode",), donate_argnums=(0,),
+        )
         self.merge_results = sentinel_jit(
             "merge_results",
             lambda r_fast, r_res, idx: r_fast.at[idx].set(r_res, mode="drop"),
@@ -873,9 +892,12 @@ class LedgerKernels:
         e = unpack_transfer(rows_b)
         lane = jnp.arange(B, dtype=I32)
         valid = lane < n
-        if "mask" in ev:  # wave executor: only this wave's lanes are live
+        if "mask" in ev:  # a wave pass: only its live lanes
             valid = valid & ev["mask"]
-        ts_vec = timestamp - n.astype(U64) + lane.astype(U64) + jnp.uint64(1)
+        if "ts" in ev:  # a wave pass: its lanes' timestamps in the whole batch
+            ts_vec = ev["ts"]
+        else:
+            ts_vec = timestamp - n.astype(U64) + lane.astype(U64) + jnp.uint64(1)
         e_a = {**e, "ts": ts_vec}
 
         acct_rows = state["acct_rows"]
@@ -1042,6 +1064,33 @@ class LedgerKernels:
             + jnp.where(proceed, ok_n, jnp.uint64(0)),
             "fault": fault,
         }, r
+
+    def _wave_chunks(self, state, rows, idx, k, n, timestamp, mode: str):
+        """The first `k` passes of `idx` (wave_chunks), in order, each the
+        masked kernel over the lanes it names: a pass gathers its rows from
+        the uploaded batch, runs its lanes with their ORIGINAL timestamps,
+        and scatters its codes back to their lanes. A trip count that is an
+        operand: one program whatever the waves, and no pass runs empty.
+        Lanes of one wave share no ordering key (HazardTracker.plan), so the
+        passes of a wave may run one after another; a later wave's passes
+        see every earlier wave's rows."""
+        n_pad = rows.shape[0]
+        first_ts = timestamp - n.astype(U64) + jnp.uint64(1)
+
+        def one_pass(i, carry):
+            st, res = carry
+            lanes = idx[i]
+            st, r = self._commit_transfers(
+                st,
+                {"rows": rows[jnp.minimum(lanes, n_pad - 1)],
+                 "mask": lanes < n, "ts": first_ts + lanes.astype(U64)},
+                jnp.int32(lanes.shape[0]), timestamp, mode=mode,
+            )
+            return st, res.at[lanes].set(r, mode="drop")
+
+        return jax.lax.fori_loop(
+            0, k, one_pass, (state, jnp.zeros(n_pad, dtype=U32))
+        )
 
     # -- exact serial tier --
 
@@ -1521,40 +1570,6 @@ def _next_pow2(n: int, floor: int = 8) -> int:
     while p < n:
         p <<= 1
     return p
-
-
-def _wave_cache(kernels) -> dict:
-    """(W, n_pad, mode) -> the wave stepper's jit entry point, its compiled
-    program, or a Future of the program while a warm-up compiles it.
-    Cached on the SHARED kernels object."""
-    cache = getattr(kernels, "_wave_cache", None)
-    if cache is None:
-        cache = kernels._wave_cache = {}
-    return cache
-
-
-def _wave_jit(kernels, W: int, n_pad: int, mode: str):
-    """The jit entry point of W dependency-ordered waves over ONE uploaded
-    batch: a lax.scan over the wave masks traces the commit kernel ONCE
-    regardless of W (the _group_stepper lesson), so a multi-wave batch
-    pays a single launch, not one per wave. Each lane is active in
-    exactly one wave and inactive lanes return code 0, so the per-wave
-    results fold with an elementwise max."""
-
-    def step(state, rows, masks, n, timestamp):
-        def body(st, mask):
-            st, r = kernels._commit_transfers(
-                st, {"rows": rows, "mask": mask}, n, timestamp,
-                mode=mode,
-            )
-            return st, r.astype(jnp.uint32)
-
-        state, rs = jax.lax.scan(body, state, masks)
-        return state, jnp.max(rs, axis=0)
-
-    return sentinel_jit(
-        f"wave_stepper_{W}x{n_pad}_{mode}", step, donate_argnums=(0,)
-    )
 
 
 class WavePlan:
@@ -2538,13 +2553,6 @@ class DeviceLedger(HostLedgerBase):
         self._acct_limit = (1 << process.account_slots_log2) // 2
         self._xfer_limit = (1 << process.transfer_slots_log2) // 2
         self.hazards = HazardTracker()
-        # A serving process sets warm_waves: once it holds balance-limit
-        # accounts, its first wave batch compiles every wave program such
-        # batches need (_warm_wave_steppers) and waits for them, so none
-        # compiles later, behind the traffic. A ledger without limit
-        # accounts or without wave batches compiles nothing new.
-        self.warm_waves = False
-        self._waves_warmed = False
         # device-anatomy h2d seam: try_execute_group_async stamps the
         # upload-issued boundary here; the dual applier reads it to close
         # its h2d_stage sub-leg. Written and read on whichever thread
@@ -2611,18 +2619,14 @@ class DeviceLedger(HostLedgerBase):
                     self.tracer.stop(tok)
             self._g_registry.set(len(self.hazards.pending_accounts))
             if decision == "waves":
-                if self.warm_waves and not self._waves_warmed and \
-                        self.hazards.limit_account_ids:
-                    self._waves_warmed = True
-                    self._warm_wave_steppers(n_pad)
+                idx, k = wave_chunks(wave_plan.wave_of, n, n_pad)
                 t_launch = perf_counter_ns()  # the waves upload their own rows
                 with self._solo_dispatch(decision, t_launch), self.tracer.span(
-                    "ledger.waves", waves=wave_plan.n_waves,
-                    steps=wave_steps(wave_plan.n_waves),
+                    "ledger.waves", waves=wave_plan.n_waves, steps=k,
                     residue=wave_plan.residue_n,
                 ):
                     results = self._execute_waves(
-                        arr, n, n_pad, nn, ts, timestamp, wave_plan
+                        arr, n, n_pad, nn, ts, timestamp, wave_plan, idx, k
                     )
             else:
                 batch = transfers_to_batch(arr, n_pad)
@@ -2664,121 +2668,50 @@ class DeviceLedger(HostLedgerBase):
             epoch=self._occupancy_epoch, summary=summary, plan=plan_info,
         )
 
-    def _wave_stepper(self, W: int, n_pad: int, mode: str):
-        """The callable for W waves (bucketed by the caller, _WAVE_BUCKETS,
-        so only a handful of shapes ever compile): a program the warm-up
-        compiled, else the jit entry point, which compiles on first call.
-        While a warm-up is compiling, the first wave batch waits for ALL of
-        its programs: none of them then lands later, in the middle of the
-        traffic (a failed one compiles on demand instead)."""
-        cache = _wave_cache(self.kernels)
-        for key, fn in list(cache.items()):
-            if isinstance(fn, Future):
-                try:
-                    cache[key] = fn.result()
-                except Exception:
-                    del cache[key]
-        key = (W, n_pad, mode)
-        fn = cache.get(key)
-        if fn is None:
-            fn = cache[key] = _wave_jit(self.kernels, W, n_pad, mode)
-        return fn
-
-    def _warm_wave_steppers(self, n_pad: int) -> None:
-        """Compile the wave steppers of _WAVE_WARM at `n_pad`, each on a
-        thread of its own, so that XLA compiles them side by side. They
-        are compiled from the tables' shapes alone (ahead of time: nothing
-        runs, the state is not touched) and called directly, so no call
-        compiles again; each compile is booked with the compile sentinel.
-        The wave batch that started them waits for all of them
-        (_wave_stepper)."""
-        cache = _wave_cache(self.kernels)
-        state = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
-            self.state,
-        )
-        sharding = jax.tree.leaves(state)[0].sharding
-
-        def sds(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-        def compile_one(key, fut):
-            W = key[0]
-            try:
-                t0 = perf_counter_ns()
-                jit = _wave_jit(self.kernels, *key)
-                compiled = jit.fn.lower(
-                    state, sds((n_pad, ROW_WORDS), jnp.uint32),
-                    sds((W, n_pad), jnp.bool_), sds((), jnp.int32),
-                    sds((), jnp.uint64),
-                ).compile()
-                COMPILE_SENTINEL.note(jit.name, (perf_counter_ns() - t0) / 1e6)
-                fut.set_result(compiled)
-            except BaseException as e:  # noqa: BLE001 — the caller compiles on demand
-                fut.set_exception(e)
-
-        for W in _WAVE_WARM:
-            key = (W, n_pad, "fast")
-            if key not in cache:
-                fut = cache[key] = Future()
-                threading.Thread(target=compile_one, args=(key, fut),
-                                 name=f"wave-warmup-{W}", daemon=True).start()
-
-    def _execute_waves(self, arr, n, n_pad, nn, ts, timestamp: int, plan):
+    def _execute_waves(self, arr, n, n_pad, nn, ts, timestamp: int, plan,
+                       idx: np.ndarray, k: int):
         """Conflict-scheduled wave execution (the HazardTracker.plan
-        layout): the batch uploads ONCE, then the waves dispatch in
-        dependency order through the masked fast/fast_pv kernel — wave
-        w+1's table lookups see wave w's applied rows, the exact ordering
-        the plan's conflict edges require — and the serial residue (if
-        any) runs the exact scan COMPACTED (cost scales with residue
-        size, not batch size) with its events' ORIGINAL timestamps;
-        results scatter back to original lanes."""
-        wave_of = plan.wave_of
+        layout): the batch uploads ONCE, then its waves run in dependency
+        order as the `k` passes of `idx` (wave_chunks) through the masked
+        fast/fast_pv kernel, in one launch — wave w+1's table lookups see
+        wave w's applied rows, the exact ordering the plan's conflict edges
+        require — and the serial residue (if any) runs the exact scan
+        COMPACTED (cost scales with residue size, not batch size) with its
+        events' ORIGINAL timestamps; results scatter back to original
+        lanes."""
         W = plan.n_waves
-        mode = "fast_pv" if plan.has_pv else "fast"
-        rows_dev = jnp.asarray(_to_rows_np(arr, n_pad))
-        wl = wave_of[:n] >= 0
+        live = n - plan.residue_n
         m = self.metrics
         m.counter("waves.batches").add()
-        m.counter("waves.scan_steps").add(wave_steps(W))
-        m.counter("waves.live_steps").add(W)
+        m.counter("waves.scan_steps").add(k)
+        m.counter("waves.live_steps").add(int((idx[:k] < n).any(axis=1).sum()))
+        m.counter("waves.scan_lanes").add(k * idx.shape[1])
+        m.counter("waves.live_lanes").add(live)
         m.histogram("waves.per_batch").observe(W)
         g = m.gauge("waves.chain_len_max")
         g.set(max(g.value, W))
-        m.gauge("waves.occupancy").set(
-            round(float(wl.sum()) / max(1, W * n), 4)
+        m.gauge("waves.occupancy").set(round(live / (k * idx.shape[1]), 4))
+        self.state, results = self.kernels.commit_wave_chunks(
+            self.state, jnp.asarray(_to_rows_np(arr, n_pad)), jnp.asarray(idx),
+            jnp.int32(k), nn, ts, mode="fast_pv" if plan.has_pv else "fast",
         )
-        if W == 1:
-            mask_np = np.zeros(n_pad, dtype=bool)
-            mask_np[:n] = wl
-            self.state, results = self.kernels.commit_transfers(
-                self.state, {"rows": rows_dev, "mask": jnp.asarray(mask_np)},
-                nn, ts, mode=mode,
-            )
-        else:
-            Wp = wave_steps(W)
-            masks = np.zeros((Wp, n_pad), dtype=bool)  # pad waves: no-ops
-            masks[wave_of[:n][wl], np.nonzero(wl)[0]] = True
-            self.state, results = self._wave_stepper(Wp, n_pad, mode)(
-                self.state, rows_dev, jnp.asarray(masks), nn, ts
-            )
         if plan.residue_n:
             m.counter("waves.residue_events").add(plan.residue_n)
-            idx = np.nonzero(~wl)[0]
-            n2 = len(idx)
+            res_idx = np.flatnonzero(plan.wave_of[:n] < 0)
+            n2 = len(res_idx)
             pad2 = _next_pow2(n2)
             rows2 = np.zeros((pad2, ROW_WORDS), dtype=np.uint32)
-            rows2[:n2] = arr.view(np.uint32).reshape(len(arr), ROW_WORDS)[idx]
+            rows2[:n2] = arr.view(np.uint32).reshape(len(arr), ROW_WORDS)[res_idx]
             ts2 = np.zeros(pad2, dtype=np.uint64)
             base = timestamp - n + 1  # first event's ts (host int: no sync)
-            ts2[:n2] = np.uint64(base) + idx.astype(np.uint64)
+            ts2[:n2] = np.uint64(base) + res_idx.astype(np.uint64)
             self.state, r_res = self.kernels.commit_transfers_residue(
                 self.state,
                 {"rows": jnp.asarray(rows2), "ts": jnp.asarray(ts2)},
                 jnp.int32(n2),
             )
             idx_pad = np.full(pad2, n_pad, dtype=np.int32)  # OOB -> dropped
-            idx_pad[:n2] = idx
+            idx_pad[:n2] = res_idx
             results = self.kernels.merge_results(
                 results, r_res, jnp.asarray(idx_pad)
             )
